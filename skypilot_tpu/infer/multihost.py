@@ -217,13 +217,9 @@ def run_selftest_gang(nprocs: int, devices_per_proc: int, out_path: str,
 
 def main(argv=None) -> None:
     import argparse
-    import os
 
-    # This image's TPU platform plugin wins over the env var; honor an
-    # explicit JAX_PLATFORMS (same dance as infer/server.py main).
-    if os.environ.get('JAX_PLATFORMS'):
-        import jax
-        jax.config.update('jax_platforms', os.environ['JAX_PLATFORMS'])
+    from skypilot_tpu.utils import compile_cache
+    compile_cache.configure()
 
     parser = argparse.ArgumentParser()
     parser.add_argument('--selftest-port', type=int, required=True)

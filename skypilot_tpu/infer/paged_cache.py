@@ -9,7 +9,7 @@ equivalent: a page POOL
 
 plus a per-slot block table mapping logical token positions to pages.
 HBM scales with tokens actually reserved, so at equal HBM the engine
-holds more concurrent requests (VERDICT r2 missing #1).
+holds more concurrent requests.
 
 Allocation policy: a request reserves ceil((prompt + max_new)/P) pages
 at ADMISSION — the worst case it can ever touch, knowable up front
@@ -121,8 +121,12 @@ class PagePool:
 
     def __init__(self, cfg: PagedConfig, n_layers: int, kv_heads: int,
                  head_dim: int, num_slots: int, dtype,
-                 device_put=None, kv_dtype: str = 'auto',
-                 scale_device_put=None) -> None:
+                 sharding=None, kv_dtype: str = 'auto',
+                 scale_sharding=None) -> None:
+        """sharding / scale_sharding: where the k/v pools and the int8
+        scale pools live (None: the default device). The pools are
+        created in that layout — a pool sharded over a mesh is never
+        whole on one device first."""
         self.cfg = cfg
         self.num_slots = num_slots
         if kv_dtype not in KV_DTYPES:
@@ -136,19 +140,19 @@ class PagePool:
         # block — grid (slots, pages), not (slots, heads, pages); per-
         # invocation and DMA-issue overhead dominate at decode sizes.
         shape = (n_layers, cfg.n_pages, kv_heads, cfg.page_size, head_dim)
-        put = device_put or (lambda x: x)
         pool_dtype = jnp.int8 if self.quantized else dtype
         self.pools: Dict[str, jax.Array] = {
-            'k': put(jnp.zeros(shape, pool_dtype)),
-            'v': put(jnp.zeros(shape, pool_dtype))}
+            'k': jnp.zeros(shape, pool_dtype, device=sharding),
+            'v': jnp.zeros(shape, pool_dtype, device=sharding)}
         if self.quantized:
             # Per-token, per-head scales (see module docstring). Scale
             # of the never-written dummy page stays 0 -> dequantizes
             # to exact zeros, like the fp pool's zero init.
             sshape = shape[:-1]
-            sput = scale_device_put or (lambda x: x)
-            self.pools['k_scale'] = sput(jnp.zeros(sshape, jnp.float32))
-            self.pools['v_scale'] = sput(jnp.zeros(sshape, jnp.float32))
+            self.pools['k_scale'] = jnp.zeros(sshape, jnp.float32,
+                                              device=scale_sharding)
+            self.pools['v_scale'] = jnp.zeros(sshape, jnp.float32,
+                                              device=scale_sharding)
         # Page 0 is the dummy; never allocated.
         self._free: List[int] = list(range(1, cfg.n_pages))
         self._owned: List[List[int]] = [[] for _ in range(num_slots)]
@@ -409,14 +413,27 @@ class PagePool:
             tables, jnp.clip(lengths // p, 0, mp - 1)[:, None],
             axis=1)[:, 0]                                    # [slots]
         off = lengths % p                                    # [slots]
-        # This scatter IS the production append (both decode paths).
-        # The layout fight it provokes at the jit boundary (XLA would
-        # pick a transposed pool output layout and pay full-pool
-        # transpose copies per chunk) is resolved by the engine pinning
-        # the pool's boundary layout (engine._pin_paged_layouts).
-        # Advanced indices (page, off) separated by the ':' head slice
-        # land first in the result: [slots, H, d].
-        return pool.at[page, :, off].set(new_kv.astype(pool.dtype))
+        return PagePool._set_rows(pool, page, off,
+                                  new_kv.astype(pool.dtype))
+
+    @staticmethod
+    def _set_rows(pool, page, off, rows):
+        """pool[page[i], :, off[i]] = rows[i] for every i — the append
+        scatter of all four append_* variants (rows [n, H, d] into a
+        k/v pool, or [n, H] into a scale pool).
+
+        The heads ride the scatter INDEX, so the scatter's window is
+        the head-dim vector alone. With the [H, d] slab as the window
+        (``pool.at[page, :, off]``), XLA:TPU lays the scattered
+        page/offset dims major of H — a [pages, P, H, d] pool — and
+        transposes the whole layer pool to that layout and back around
+        the Pallas kernel, per layer, on every decode step, plus once
+        at each end of the chunk. This form keeps the pool in the
+        kernel's row-major layout throughout (tests_tpu reads the
+        compiled decode step to check it)."""
+        h = pool.shape[1]
+        return pool.at[page[:, None], jnp.arange(h)[None, :],
+                       off[:, None]].set(rows)
 
     @staticmethod
     def append_tokens_layer(pool, new_kv, tables, start):
@@ -436,7 +453,8 @@ class PagePool:
         page = jnp.take_along_axis(
             tables, jnp.clip(pos // p, 0, mp - 1), axis=1)  # [slots, s]
         off = pos % p
-        return pool.at[page.reshape(-1), :, off.reshape(-1)].set(
+        return PagePool._set_rows(
+            pool, page.reshape(-1), off.reshape(-1),
             new_kv.reshape(slots * s, h, d).astype(pool.dtype))
 
     # ------------------------------------------- int8-quantized kernels
@@ -481,8 +499,8 @@ class PagePool:
             axis=1)[:, 0]                                    # [slots]
         off = lengths % p
         q, s = quantize_kv(new_kv)             # [slots, H, d], [slots, H]
-        return (pool.at[page, :, off].set(q),
-                scale_pool.at[page, :, off].set(s))
+        return (PagePool._set_rows(pool, page, off, q),
+                PagePool._set_rows(scale_pool, page, off, s))
 
     @staticmethod
     def append_tokens_layer_q(pool, scale_pool, new_kv, tables, start):
@@ -496,9 +514,9 @@ class PagePool:
             tables, jnp.clip(pos // p, 0, mp - 1), axis=1)  # [slots, s]
         off = pos % p
         q, s = quantize_kv(new_kv.reshape(slots * s_run, h, d))
-        return (pool.at[page.reshape(-1), :, off.reshape(-1)].set(q),
-                scale_pool.at[page.reshape(-1), :,
-                              off.reshape(-1)].set(s))
+        page, off = page.reshape(-1), off.reshape(-1)
+        return (PagePool._set_rows(pool, page, off, q),
+                PagePool._set_rows(scale_pool, page, off, s))
 
     @staticmethod
     def gather_view_q(pool, scale_pool, tables, dtype):

@@ -1952,11 +1952,15 @@ def build_engine(model_name: Optional[str] = None,
                 model.init(k, sample),
                 mode=quantize))(jax.random.PRNGKey(0))
             already_quantized = True
+        elif mesh is not None:
+            # Initialise straight into the sharded layout: a preset
+            # that only fits spread over the mesh must never be whole
+            # on device 0 first.
+            from skypilot_tpu.models import weights as weights_lib
+            params = weights_lib.init_sharded_params(
+                model, cfg, mesh, jax.random.PRNGKey(0), sample)
         else:
             params = jax.jit(model.init)(jax.random.PRNGKey(0), sample)
-        if mesh is not None:
-            from skypilot_tpu.models import weights as weights_lib
-            params = weights_lib.shard_params(params, model, cfg, mesh)
     if quantize in ('int8', 'int4'):
         # Weight-only quantization: halve (int8) or quarter (int4) the
         # HBM bytes every decode step streams (models/quant.py). int8
@@ -2039,13 +2043,8 @@ def build_engine(model_name: Optional[str] = None,
 
 
 def main(argv=None) -> None:
-    import os
-
-    # Some TPU images pin a platform plugin that wins over the env var;
-    # honor an explicit JAX_PLATFORMS (same dance as train/sft.py).
-    if os.environ.get('JAX_PLATFORMS'):
-        import jax
-        jax.config.update('jax_platforms', os.environ['JAX_PLATFORMS'])
+    from skypilot_tpu.utils import compile_cache
+    compile_cache.configure()
 
     parser = argparse.ArgumentParser()
     parser.add_argument('--model', default='debug',
@@ -2201,8 +2200,7 @@ def main(argv=None) -> None:
     engine.start()
     logger.info('warming up (compiling prefill buckets + decode)...')
     engine.warmup()
-    import os as _os
-    model_id = (_os.path.basename(args.checkpoint.rstrip('/'))
+    model_id = (os.path.basename(args.checkpoint.rstrip('/'))
                 if args.checkpoint else args.model)
     server = InferenceServer(engine, tokenizer, model_id=model_id,
                              lora_names=lora_names,
@@ -2212,7 +2210,13 @@ def main(argv=None) -> None:
     logger.info('inference server: model=%s ckpt=%s tp=%d port=%d '
                 'slots=%d', args.model, args.checkpoint, args.tp,
                 args.port, args.num_slots)
-    web.run_app(server.make_app(), port=args.port, print=None)
+    try:
+        web.run_app(server.make_app(), port=args.port, print=None)
+    finally:
+        # The loop thread is a daemon: caught inside a device call
+        # when the interpreter tears the TPU client down, it aborts
+        # the process (SIGABRT and a core dump on every SIGTERM).
+        engine.stop()
 
 
 if __name__ == '__main__':

@@ -38,7 +38,6 @@ import jax
 
 from skypilot_tpu.utils import env
 from skypilot_tpu.utils import faults
-from skypilot_tpu.utils import jax_compat
 from skypilot_tpu.utils import log_utils
 from skypilot_tpu.utils import metrics as metrics_lib
 
@@ -71,8 +70,8 @@ def validate_tree(live, new) -> None:
     one in structure, per-leaf shape, or dtype. Raises WeightSwapError
     naming the first offending path — the swap must abort BEFORE any
     device state changes."""
-    live_leaves = jax_compat.tree_leaves_with_path(live)
-    new_leaves = jax_compat.tree_leaves_with_path(new)
+    live_leaves = jax.tree.leaves_with_path(live)
+    new_leaves = jax.tree.leaves_with_path(new)
     live_map = {_path_str(p): leaf for p, leaf in live_leaves}
     new_map = {_path_str(p): leaf for p, leaf in new_leaves}
     missing = sorted(set(live_map) - set(new_map))

@@ -200,7 +200,7 @@ class JobsController:
             # CLUSTER is unreachable. A programming error (TypeError,
             # KeyError, ...) propagating here fails the controller loudly
             # instead of masquerading as a preemption and triggering a
-            # spurious teardown+recovery (VERDICT r2, weak #6).
+            # spurious teardown+recovery.
             return None
         return job['status'] if job else None
 

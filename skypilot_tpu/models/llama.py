@@ -472,6 +472,25 @@ class LlamaAttention(nn.Module):
                         v_pool = PagePool.append_tokens_layer(
                             v_pool, v, tables, pos)
                 from skypilot_tpu.ops import dispatch
+                from skypilot_tpu.parallel.sharding import per_shard
+
+                def _paged_kernel(kernel, kernel_q, qx, qx_axes):
+                    """The paged Pallas kernel (its int8 twin on a
+                    quantized pool) on each device's own heads: the
+                    pool is sharded on kv_heads under tp, like q; the
+                    slots, tables and lengths stay whole."""
+                    pool_axes = (None, 'act_kv_heads', None, None)
+                    scale_axes = (None, 'act_kv_heads', None)
+                    if quantized:
+                        return per_shard(
+                            kernel_q,
+                            (qx_axes, pool_axes, pool_axes, scale_axes,
+                             scale_axes, (), ()), qx_axes)(
+                                 qx, k_pool, v_pool, k_scale, v_scale,
+                                 tables, pos)
+                    return per_shard(
+                        kernel, (qx_axes, pool_axes, pool_axes, (), ()),
+                        qx_axes)(qx, k_pool, v_pool, tables, pos)
 
                 def _xla_gather():
                     # Gather view + masked XLA reference: the
@@ -505,9 +524,9 @@ class LlamaAttention(nn.Module):
                             'SKYT_PAGED_ATTN', 'pallas') == 'pallas':
                     # Pallas kernel DMAs each slot's pages directly
                     # (no materialized contiguous view; escape hatch:
-                    # SKYT_PAGED_ATTN=xla). The engine pins the pool's
-                    # jit-boundary layout so the scatter above and this
-                    # kernel agree (engine._pin_paged_layouts). Routed
+                    # SKYT_PAGED_ATTN=xla). The append scatter above
+                    # leaves the pool in the row-major layout this
+                    # kernel reads (PagePool._set_rows). Routed
                     # through the dispatch ladder: a trace-time kernel
                     # failure (or an armed ops.lowering fault) degrades
                     # to the gather view instead of killing the serve
@@ -516,14 +535,10 @@ class LlamaAttention(nn.Module):
                     from skypilot_tpu.ops import paged_attention
 
                     def _pallas_sq():
-                        if quantized:
-                            return \
-                                paged_attention.paged_decode_attention_q(
-                                    q[:, 0], k_pool, v_pool, k_scale,
-                                    v_scale, tables, pos)[:, None]
-                        return paged_attention.paged_decode_attention(
-                            q[:, 0], k_pool, v_pool, tables,
-                            pos)[:, None]
+                        return _paged_kernel(
+                            paged_attention.paged_decode_attention,
+                            paged_attention.paged_decode_attention_q,
+                            q[:, 0], (None, 'act_heads', None))[:, None]
                     out = dispatch.run_ladder(op_sq, [
                         ('pallas', _pallas_sq),
                         ('xla', _xla_gather),
@@ -534,23 +549,18 @@ class LlamaAttention(nn.Module):
                             'pallas') == 'pallas':
                     # Multi-query kernel for the speculative verify
                     # step: DMAs only each slot's owned pages instead
-                    # of gathering the max_pages*P view. Default since
-                    # the on-chip gate proved the Mosaic lowering +
-                    # engine parity on a real v5e
-                    # (tools/onchip_r05/attempt2,
-                    # tests_tpu test_spec_mq_kernel_lowers); escape
-                    # hatch: SKYT_SPEC_PAGED_ATTN=xla. Same ladder as
-                    # the single-query path.
+                    # of gathering the max_pages*P view (lowering and
+                    # engine parity: tests_tpu
+                    # test_spec_mq_kernel_lowers); escape hatch:
+                    # SKYT_SPEC_PAGED_ATTN=xla. Same ladder as the
+                    # single-query path.
                     from skypilot_tpu.ops import paged_attention
 
                     def _pallas_mq():
-                        if quantized:
-                            return paged_attention.\
-                                paged_decode_attention_mq_q(
-                                    q, k_pool, v_pool, k_scale,
-                                    v_scale, tables, pos)
-                        return paged_attention.paged_decode_attention_mq(
-                            q, k_pool, v_pool, tables, pos)
+                        return _paged_kernel(
+                            paged_attention.paged_decode_attention_mq,
+                            paged_attention.paged_decode_attention_mq_q,
+                            q, (None, None, 'act_heads', None))
                     out = dispatch.run_ladder(op_mq, [
                         ('pallas', _pallas_mq),
                         ('xla', _xla_gather),

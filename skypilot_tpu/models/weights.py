@@ -695,6 +695,22 @@ def param_shardings(model, cfg, mesh, rules=sharding_lib.DEFAULT_RULES):
     return nn.logical_to_mesh_sharding(logical, mesh, list(rules))['params']
 
 
+def init_sharded_params(model, cfg, mesh, rng, sample,
+                        rules=sharding_lib.DEFAULT_RULES) -> Dict[str, Any]:
+    """Randomly initialise {'params': ...} straight into its sharded
+    layout (the serving twin of trainer.create_sharded_state): each
+    device only ever holds its own shard, so a preset larger than one
+    chip's memory initialises on a mesh it fits."""
+    import flax.linen as nn
+
+    shardings = param_shardings(model, cfg, mesh, rules)
+
+    def init(key):
+        return nn.meta.unbox(model.init(key, sample)['params'])
+    with mesh, nn.logical_axis_rules(list(rules)):
+        return {'params': jax.jit(init, out_shardings=shardings)(rng)}
+
+
 def shard_params(variables: Dict[str, Any], model, cfg, mesh,
                  rules=sharding_lib.DEFAULT_RULES) -> Dict[str, Any]:
     """Re-place an existing params tree onto `mesh` per the logical

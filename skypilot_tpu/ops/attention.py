@@ -12,6 +12,7 @@ import jax
 import jax.numpy as jnp
 
 from skypilot_tpu.ops import dispatch
+from skypilot_tpu.parallel import sharding as sharding_lib
 from skypilot_tpu.utils import env
 
 NEG_INF = -1e9  # logits are f32 until softmax, so -1e9 never overflows
@@ -177,10 +178,21 @@ def _attention(q: jax.Array, k: jax.Array, v: jax.Array,
         sq, sk = q.shape[1], k.shape[1]
         has_seg = segment_ids is not None
 
+        # Each device runs the kernel on its own batch rows and heads
+        # (the seq dims stay whole: every query needs every key).
+        q_axes = ('act_batch', None, 'act_heads', None)
+        kv_axes = ('act_batch', None, 'act_kv_heads', None)
+        in_axes = (q_axes, kv_axes, kv_axes) + \
+            ((('act_batch', None),) if has_seg else ())
+        operands = (q, k, v) + ((segment_ids,) if has_seg else ())
+
         def rung(bq, bk):
-            return lambda: flash_lib.flash_attention(
-                q, k, v, causal=causal, segment_ids=segment_ids,
-                block_q=bq, block_k=bk, window=window)
+            def kernel(q, k, v, seg=None):
+                return flash_lib.flash_attention(
+                    q, k, v, causal=causal, segment_ids=seg,
+                    block_q=bq, block_k=bk, window=window)
+            return lambda: sharding_lib.per_shard(
+                kernel, in_axes, q_axes)(*operands)
 
         rungs = []
         tuned = autotune.lookup_flash(q.shape, k.shape, q.dtype,
@@ -200,8 +212,8 @@ def _attention(q: jax.Array, k: jax.Array, v: jax.Array,
     # 'xla_native': XLA is the CORRECT path for this op (softcap /
     # scale / traced window / auto-resolved shape), not ladder
     # degradation — keep it distinguishable from the 'xla' floor so
-    # operators (and tpu_validation's scrape) don't learn to ignore
-    # the real degradation signal.
+    # operators (and chip_smoke.py's /stats check) don't learn to
+    # ignore the real degradation signal.
     return dispatch.run_ladder('attention', [('xla_native', xla)])
 
 
@@ -227,12 +239,9 @@ def _flash_ok(q: jax.Array, k: jax.Array, has_seg: bool = False) -> bool:
     blocks must be 128-aligned or full-array, so a seq that clamps to
     a full-array block can blow the VMEM guard that a seg-less probe
     would pass."""
-    try:
-        on_tpu = jax.devices()[0].platform == 'tpu'
-    except Exception:  # pylint: disable=broad-except
-        on_tpu = False
     sq, sk, d = q.shape[1], k.shape[1], q.shape[3]
-    if not (on_tpu and sq % 8 == 0 and sk % 8 == 0 and
+    if not (not dispatch.interpret_mode() and
+            sq % 8 == 0 and sk % 8 == 0 and
             d % 64 == 0 and d <= 512):
         return False
     from skypilot_tpu.ops import flash_attention as flash_lib

@@ -33,11 +33,6 @@ from jax.experimental.pallas import tpu as pltpu
 from skypilot_tpu.ops import dispatch
 from skypilot_tpu.utils import env
 
-# jax renamed TPUCompilerParams -> CompilerParams (~0.5); support both
-# so the kernels work on whichever jax the image ships.
-_CompilerParams = getattr(pltpu, 'CompilerParams',
-                          getattr(pltpu, 'TPUCompilerParams', None))
-
 NEG_INF = -1e30
 
 # Row statistics (lse, delta) are carried as [..., seq, LANES] arrays with
@@ -48,7 +43,7 @@ NEG_INF = -1e30
 LANES = 128
 
 
-def _bwd_impl_choice() -> str:
+def bwd_impl_choice() -> str:
     """'pallas' (default) or 'xla' — SKYT_FLASH_BWD overrides. The XLA
     path recomputes reference attention under custom_vjp (the round-1
     behavior); the escape hatch exists so a pathological kernel compile
@@ -57,14 +52,6 @@ def _bwd_impl_choice() -> str:
 
 DEFAULT_BLOCK_Q = 256
 DEFAULT_BLOCK_K = 256
-
-
-def _interpret_mode() -> bool:
-    """Pallas interpret mode off-TPU (CPU tests exercise kernel logic)."""
-    try:
-        return jax.devices()[0].platform != 'tpu'
-    except Exception:
-        return True
 
 
 def _block_mask(s, qi, ki, block_q, block_k, causal, window,
@@ -321,8 +308,8 @@ def _shape_checks(q, k, block_q, block_k, has_seg=False):
     """Shape-robust block selection (docs/kernels.md): requested
     blocks are CLAMPED through the divisibility-safe selector — to a
     tile-aligned divisor of the seq dim, or to the full dim (always
-    legal) — so any legal input shape lowers; decode shapes like the
-    BENCH_r02 (4, 32, 8, 256) no longer raise. A block pair whose
+    legal) — so any legal input shape lowers, decode shapes like
+    (4, 32, 8, 256) included. A block pair whose
     VMEM working set cannot fit is refused at TRACE time (a
     ValueError the dispatch ladder catches), because the Mosaic
     compile error it would become is not catchable."""
@@ -333,7 +320,7 @@ def _shape_checks(q, k, block_q, block_k, has_seg=False):
             f'q heads ({hq}) must be a multiple of kv heads ({hkv})')
     block_q, block_k = dispatch.flash_blocks(sq, sk, block_q, block_k,
                                              q.dtype, has_seg)
-    if not _interpret_mode() and not dispatch.flash_vmem_ok(
+    if not dispatch.interpret_mode() and not dispatch.flash_vmem_ok(
             block_q, block_k, d, jnp.dtype(q.dtype).itemsize):
         raise ValueError(
             f'flash blocks ({block_q}, {block_k}) x d={d} exceed the '
@@ -403,10 +390,10 @@ def _flash_fwd_impl(q, k, v, segment_ids, causal, block_q, block_k,
             pltpu.VMEM((block_q, 1), jnp.float32),   # running sum
             pltpu.VMEM((block_q, d), jnp.float32),   # output accumulator
         ],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=('parallel', 'parallel', 'parallel',
                                  'arbitrary')),
-        interpret=_interpret_mode(),
+        interpret=dispatch.interpret_mode(),
     )(*operands)
     return out.transpose(0, 2, 1, 3), lse
 
@@ -419,7 +406,7 @@ def _fwd_rule(q, k, v, segment_ids, causal, block_q, block_k, window):
 
 def _bwd_rule(causal, block_q, block_k, window, res, g):
     q, k, v, segment_ids, out, lse = res
-    if _bwd_impl_choice() == 'xla':
+    if bwd_impl_choice() == 'xla':
         from skypilot_tpu.ops import attention as attention_ops
         _, vjp = jax.vjp(
             lambda q_, k_, v_: attention_ops.mha_reference(
@@ -479,10 +466,10 @@ def _bwd_rule(causal, block_q, block_k, window, res, g):
         out_specs=pl.BlockSpec((1, 1, block_q, d), qkv_spec),
         out_shape=jax.ShapeDtypeStruct((b, hq, sq, d), q.dtype),
         scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=('parallel', 'parallel', 'parallel',
                                  'arbitrary')),
-        interpret=_interpret_mode(),
+        interpret=dispatch.interpret_mode(),
     )(*operands)
 
     # dk/dv per *query* head: the kernel walks q blocks innermost for a
@@ -533,10 +520,10 @@ def _bwd_rule(causal, block_q, block_k, window, res, g):
             pltpu.VMEM((block_k, d), jnp.float32),
             pltpu.VMEM((block_k, d), jnp.float32),
         ],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=('parallel', 'parallel', 'parallel',
                                  'arbitrary')),
-        interpret=_interpret_mode(),
+        interpret=dispatch.interpret_mode(),
     )(*operands)
 
     if group > 1:

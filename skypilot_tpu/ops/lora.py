@@ -33,7 +33,7 @@ ride ops/dispatch.py; block sizes are swept through the generic
 ``autotune.sweep`` helper.
 """
 import functools
-from typing import Optional, Tuple
+from typing import Tuple
 
 import jax
 import jax.numpy as jnp
@@ -42,9 +42,7 @@ from jax.experimental.pallas import tpu as pltpu
 
 from skypilot_tpu.ops import autotune
 from skypilot_tpu.ops import dispatch
-
-_CompilerParams = getattr(pltpu, 'CompilerParams',
-                          getattr(pltpu, 'TPUCompilerParams', None))
+from skypilot_tpu.parallel import mesh as mesh_lib
 
 OP = 'lora_grouped'
 
@@ -53,22 +51,23 @@ _CANDIDATE_BLOCKS = (128, 256, 512)
 _DEFAULT_BLOCK = 256
 
 
-def _interpret_mode() -> bool:
-    try:
-        return jax.devices()[0].platform != 'tpu'
-    except Exception:  # pylint: disable=broad-except
-        return True
-
-
 # ------------------------------------------------------------ kernels
+def _dot(x, w):
+    """x @ w accumulated in f32 and rounded once to x's dtype: Mosaic
+    refuses a matmul whose accumulator is narrower than 32 bits, and
+    this is the rounding the XLA floor's einsum performs."""
+    return jnp.dot(x, w.astype(x.dtype),
+                   preferred_element_type=jnp.float32).astype(x.dtype)
+
+
 def _gather_kernel(ids_ref, x_ref, a_ref, b_ref, o_ref):
     """Per-sequence ids: one grid step = one (sequence, seq-block);
     the A/B blocks arriving here were already selected by ids[b] in
     the BlockSpec index maps — the gather happened in the DMA."""
     del ids_ref  # consumed by the index maps
     x = x_ref[0]                               # [bs, in]
-    t = jnp.dot(x, a_ref[0].astype(x.dtype))   # [bs, r]
-    o_ref[0] = jnp.dot(t, b_ref[0].astype(x.dtype))
+    t = _dot(x, a_ref[0])                      # [bs, r]
+    o_ref[0] = _dot(t, b_ref[0])
 
 
 def _grouped_kernel(x_ref, ids_ref, a_ref, b_ref, o_ref):
@@ -84,14 +83,14 @@ def _grouped_kernel(x_ref, ids_ref, a_ref, b_ref, o_ref):
 
     x = x_ref[:]                                   # [bt, in]
     mask = (ids_ref[:] == k).astype(x.dtype)       # [bt, 1]
-    t = jnp.dot(x * mask, a_ref[0].astype(x.dtype))
-    o_ref[:] += jnp.dot(t, b_ref[0].astype(x.dtype))
+    t = _dot(x * mask, a_ref[0])
+    o_ref[:] += _dot(t, b_ref[0])
 
 
 # ----------------------------------------------------- pallas wrappers
-@functools.partial(jax.jit, static_argnames=('block_s', 'interpret'))
-def _pallas_gather(x, a, b, lora_ids, lora_scale, block_s: int,
-                   interpret: Optional[bool] = None) -> jax.Array:
+@functools.partial(jax.jit, static_argnames=('block_s',))
+def _pallas_gather(x, a, b, lora_ids, lora_scale,
+                   block_s: int) -> jax.Array:
     bsz, seq, din = x.shape
     r = a.shape[-1]
     dout = b.shape[-1]
@@ -110,16 +109,16 @@ def _pallas_gather(x, a, b, lora_ids, lora_scale, block_s: int,
         _gather_kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((bsz, seq, dout), x.dtype),
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=('parallel', 'parallel')),
-        interpret=_interpret_mode() if interpret is None else interpret,
+        interpret=dispatch.interpret_mode(),
     )(lora_ids.astype(jnp.int32), x, a, b)
     return d * lora_scale[:, None, None].astype(x.dtype)
 
 
-@functools.partial(jax.jit, static_argnames=('block_t', 'interpret'))
-def _pallas_grouped(x, a, b, lora_ids, lora_scale, block_t: int,
-                    interpret: Optional[bool] = None) -> jax.Array:
+@functools.partial(jax.jit, static_argnames=('block_t',))
+def _pallas_grouped(x, a, b, lora_ids, lora_scale,
+                    block_t: int) -> jax.Array:
     bsz, seq, din = x.shape
     n, _, r = a.shape
     dout = b.shape[-1]
@@ -141,9 +140,9 @@ def _pallas_grouped(x, a, b, lora_ids, lora_scale, block_t: int,
         _grouped_kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((tok, dout), x.dtype),
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=('parallel', 'arbitrary')),
-        interpret=_interpret_mode() if interpret is None else interpret,
+        interpret=dispatch.interpret_mode(),
     )(xt, ids, a, b)
     return d.reshape(bsz, seq, dout) * \
         lora_scale[..., None].astype(x.dtype)
@@ -274,6 +273,17 @@ def grouped_lora_delta(x, a, b, lora_ids, lora_scale) -> jax.Array:
     itemsize = jnp.dtype(x.dtype).itemsize
     mult = dispatch.sublane_multiple(x.dtype)
     tokens = bsz * seq
+
+    # Mosaic kernels cannot be partitioned by GSPMD, and the
+    # projections these deltas join are sharded on their in or out
+    # features by the model's rules: under a multi-device mesh the
+    # einsum floor, which GSPMD partitions like the base matmul, is
+    # the required path ('xla_native'), not a descent.
+    mesh = mesh_lib.current_mesh()
+    if mesh is not None and mesh.size > 1:
+        floor = _xla_grouped if per_token else _xla_gather
+        return dispatch.run_ladder(OP, [('xla_native', functools.partial(
+            floor, x, a, b, lora_ids, lora_scale))])
 
     rungs = []
     if per_token:

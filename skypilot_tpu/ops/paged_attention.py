@@ -24,27 +24,16 @@ Reference counterpart: none (the reference delegates to vLLM's CUDA
 paged attention).
 """
 import functools
-from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# jax renamed TPUCompilerParams -> CompilerParams (~0.5); support both
-# so the paged path works on whichever jax the image ships.
-_CompilerParams = getattr(pltpu, 'CompilerParams',
-                          getattr(pltpu, 'TPUCompilerParams', None))
+from skypilot_tpu.ops import dispatch
 
 NEG_INF = -1e30
 LANES = 128
-
-
-def _interpret_mode() -> bool:
-    try:
-        return jax.devices()[0].platform != 'tpu'
-    except Exception:  # pylint: disable=broad-except
-        return True
 
 
 def _kernel(tables_ref, lens_ref, q_ref, k_ref, v_ref, o_ref,
@@ -261,13 +250,11 @@ def _kernel_mq_q(tables_ref, lens_ref, q_ref, k_ref, v_ref, ks_ref,
         o_ref[0] = (acc_scr[:] / safe_l).astype(o_ref.dtype)
 
 
-@functools.partial(jax.jit, static_argnames=('interpret',))
+@jax.jit
 def paged_decode_attention_q(q: jax.Array, k_pool: jax.Array,
                              v_pool: jax.Array, k_scale: jax.Array,
                              v_scale: jax.Array, tables: jax.Array,
-                             lengths: jax.Array,
-                             interpret: Optional[bool] = None
-                             ) -> jax.Array:
+                             lengths: jax.Array) -> jax.Array:
     """int8-KV single-query paged decode: same contract as
     paged_decode_attention plus the scale pools [n_pages, Hkv, P]
     (one layer). Scale blocks ride their own scalar-prefetched
@@ -309,21 +296,19 @@ def paged_decode_attention_q(q: jax.Array, k_pool: jax.Array,
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((s_slots, hkv, g, d), q.dtype),
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=('parallel', 'arbitrary')),
-        interpret=_interpret_mode() if interpret is None else interpret,
+        interpret=dispatch.interpret_mode(),
     )(tables.astype(jnp.int32), lengths.astype(jnp.int32), qg, k_pool,
       v_pool, k_scale, v_scale)
     return out.reshape(s_slots, hq, d)
 
 
-@functools.partial(jax.jit, static_argnames=('interpret',))
+@jax.jit
 def paged_decode_attention_mq_q(q: jax.Array, k_pool: jax.Array,
                                 v_pool: jax.Array, k_scale: jax.Array,
                                 v_scale: jax.Array, tables: jax.Array,
-                                lengths: jax.Array,
-                                interpret: Optional[bool] = None
-                                ) -> jax.Array:
+                                lengths: jax.Array) -> jax.Array:
     """int8-KV multi-query paged decode (speculative verify): same
     contract as paged_decode_attention_mq plus the scale pools."""
     s_slots, t, hq, d = q.shape
@@ -365,21 +350,19 @@ def paged_decode_attention_mq_q(q: jax.Array, k_pool: jax.Array,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((s_slots, hkv, t * g, d),
                                        q.dtype),
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=('parallel', 'arbitrary')),
-        interpret=_interpret_mode() if interpret is None else interpret,
+        interpret=dispatch.interpret_mode(),
     )(tables.astype(jnp.int32), lengths.astype(jnp.int32), qg, k_pool,
       v_pool, k_scale, v_scale)
     return out.reshape(s_slots, hkv, t, g, d).transpose(0, 2, 1, 3, 4) \
               .reshape(s_slots, t, hq, d)
 
 
-@functools.partial(jax.jit, static_argnames=('interpret',))
+@jax.jit
 def paged_decode_attention_mq(q: jax.Array, k_pool: jax.Array,
                               v_pool: jax.Array, tables: jax.Array,
-                              lengths: jax.Array,
-                              interpret: Optional[bool] = None
-                              ) -> jax.Array:
+                              lengths: jax.Array) -> jax.Array:
     """Multi-query paged decode (speculative verify): q [S, T, Hq, d] —
     T consecutive tokens per slot, token t at position lengths[s] + t
     (all T tokens' KV already appended). Returns [S, T, Hq, d].
@@ -421,20 +404,19 @@ def paged_decode_attention_mq(q: jax.Array, k_pool: jax.Array,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((s_slots, hkv, t * g, d),
                                        q.dtype),
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=('parallel', 'arbitrary')),
-        interpret=_interpret_mode() if interpret is None else interpret,
+        interpret=dispatch.interpret_mode(),
     )(tables.astype(jnp.int32), lengths.astype(jnp.int32), qg, k_pool,
       v_pool)
     return out.reshape(s_slots, hkv, t, g, d).transpose(0, 2, 1, 3, 4) \
               .reshape(s_slots, t, hq, d)
 
 
-@functools.partial(jax.jit, static_argnames=('interpret',))
+@jax.jit
 def paged_decode_attention(q: jax.Array, k_pool: jax.Array,
                            v_pool: jax.Array, tables: jax.Array,
-                           lengths: jax.Array,
-                           interpret: Optional[bool] = None) -> jax.Array:
+                           lengths: jax.Array) -> jax.Array:
     """q: [S, Hq, d] (one token per slot); k_pool/v_pool:
     [n_pages, Hkv, P, d] (one layer, page-major); tables: [S, mp] int32;
     lengths: [S] int32 — the position each slot's query token sits at
@@ -475,9 +457,9 @@ def paged_decode_attention(q: jax.Array, k_pool: jax.Array,
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((s_slots, hkv, g, d), q.dtype),
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=('parallel', 'arbitrary')),
-        interpret=_interpret_mode() if interpret is None else interpret,
+        interpret=dispatch.interpret_mode(),
     )(tables.astype(jnp.int32), lengths.astype(jnp.int32), qg, k_pool,
       v_pool)
     return out.reshape(s_slots, hq, d)

@@ -238,19 +238,24 @@ def _census_hlo(text: str, mesh) -> List[CensusEntry]:
         if m is None:
             continue
         op = m.group(2).replace('-', '_')
-        # Operand types sit inside the call parens: 'f32[4,64]{1,0} %x'.
-        call = line[m.end():]
-        operand_toks = re.findall(r'([a-z0-9]+\[[0-9,]*\])\{', call)
-        if not operand_toks:   # layouts may be elided in some dumps
-            operand_toks = re.findall(r'([a-z0-9]+\[[0-9,]*\])\s*%',
-                                      call)
-        operand_bytes = sum(_hlo_shape_bytes(t) for t in operand_toks)
+        # The dump names operands without their types
+        # ('all-reduce(%fusion.1)'), so the payload comes from the
+        # result type: equal to the operand for all-reduce and
+        # collective-permute, the gathered whole for all-gather, and
+        # one rank's share of the operand for reduce-scatter.
         result_toks = re.findall(r'([a-z0-9]+\[[0-9,]*\])',
                                  m.group(1))
+        if m.group(3) and op in ('all_gather', 'collective_permute'):
+            # Their '-start' results are (operands..., results...)
+            # tuples, plus scalar context words for the permute.
+            result_toks = [t for t in result_toks
+                           if not t.endswith('[]')]
+            result_toks = result_toks[len(result_toks) // 2:]
         result_bytes = sum(_hlo_shape_bytes(t) for t in result_toks)
         groups = _parse_hlo_groups(line)
         axes, ranks = _attribute(groups, mesh)
-        payload = result_bytes if op == 'all_gather' else operand_bytes
+        payload = result_bytes * ranks if op == 'reduce_scatter' \
+            else result_bytes
         if payload <= 0 or ranks < 2:
             continue
         out.append(CensusEntry(op=op, axes=axes, ranks=ranks,

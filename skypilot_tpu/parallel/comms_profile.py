@@ -344,7 +344,6 @@ def _probe_dcn_pairs(mesh, axis: str, n_slices: int,
     import jax.numpy as jnp
     from jax.sharding import NamedSharding, PartitionSpec as P
 
-    from skypilot_tpu.parallel import mesh as mesh_lib
     n = mesh.shape[axis]
     f = n // n_slices
     out: Dict[str, Dict[str, float]] = {}
@@ -364,9 +363,9 @@ def _probe_dcn_pairs(mesh, axis: str, n_slices: int,
                     y = jax.lax.ppermute(xs, axis, [(a, b), (b, a)])
                     return jax.lax.psum(jnp.sum(y[..., :1]), axis)
 
-                fn = jax.jit(mesh_lib.shard_map(
-                    _pair, mesh, in_specs=P(axis), out_specs=P(),
-                    check_rep=False))
+                fn = jax.jit(jax.shard_map(
+                    _pair, mesh=mesh, in_specs=P(axis), out_specs=P(),
+                    check_vma=False))
                 fn(x).block_until_ready()
                 t0 = clock()
                 for _ in range(iters):

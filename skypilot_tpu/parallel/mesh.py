@@ -55,20 +55,6 @@ class MeshSpec:
         return 'MeshSpec(' + (', '.join(active) or '1 device') + ')'
 
 
-def shard_map(fn, mesh: Mesh, in_specs, out_specs, **kwargs):
-    """Version-compat shard_map: jax.shard_map (>=0.8) with fallback to
-    jax.experimental.shard_map. One shim for the whole package. The old
-    `check_rep` kwarg maps to the new API's `check_vma`."""
-    if hasattr(jax, 'shard_map'):
-        if 'check_rep' in kwargs:
-            kwargs['check_vma'] = kwargs.pop('check_rep')
-        return jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, **kwargs)
-    from jax.experimental.shard_map import shard_map as _sm
-    return _sm(fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-               **kwargs)
-
-
 def current_mesh() -> Optional[Mesh]:
     """The ambient mesh from an enclosing `with mesh:` block, or None.
 

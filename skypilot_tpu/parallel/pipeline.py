@@ -26,8 +26,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
-from skypilot_tpu.parallel import mesh as mesh_lib
-
 
 def pipeline_apply(
     stage_fn: Callable[[Any, jax.Array], jax.Array],
@@ -65,12 +63,7 @@ def pipeline_apply(
         # body produces pp-varying values (ppermute / stage-dependent
         # writes), and scan requires carry types to be invariant.
         def _vary(x):
-            if hasattr(jax.lax, 'pcast'):  # jax >= 0.9
-                return jax.lax.pcast(x, ('pp',), to='varying')
-            try:
-                return jax.lax.pvary(x, ('pp',))
-            except AttributeError:  # older jax: no varying-axis types
-                return x
+            return jax.lax.pcast(x, ('pp',), to='varying')
         out_buf = _vary(jnp.zeros_like(xs))
         # Carry: activation entering this stage at the current tick.
         state = _vary(jnp.zeros_like(xs[0]))
@@ -106,8 +99,8 @@ def pipeline_apply(
         return jax.lax.psum(out_buf, 'pp')
 
     in_specs = (jax.tree.map(lambda _: P('pp'), stacked_params), P())
-    return mesh_lib.shard_map(_pipelined, mesh, in_specs=in_specs,
-                              out_specs=P())(stacked_params, microbatches)
+    return jax.shard_map(_pipelined, mesh=mesh, in_specs=in_specs,
+                         out_specs=P())(stacked_params, microbatches)
 
 
 def stack_stage_params(per_stage_params) -> Any:

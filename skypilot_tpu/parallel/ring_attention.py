@@ -204,9 +204,8 @@ def ring_attention_sharded(q, k, v, mesh: Mesh, causal: bool = True,
     q, k, v: [B, S, H, D]; S is split over `axis_name` (GSPMD inserts the
     reshard if the inputs arrive with a different layout).
     """
-    from skypilot_tpu.parallel import mesh as mesh_lib
     spec = P(None, axis_name, None, None)
     fn = functools.partial(ring_attention, axis_name=axis_name,
                            causal=causal)
-    return mesh_lib.shard_map(fn, mesh, in_specs=(spec, spec, spec),
-                              out_specs=spec, check_rep=False)(q, k, v)
+    return jax.shard_map(fn, mesh=mesh, in_specs=(spec, spec, spec),
+                         out_specs=spec, check_vma=False)(q, k, v)

@@ -85,6 +85,30 @@ def constrain(x: jax.Array, mesh: Mesh,
         x, named_sharding(mesh, logical_axes, rules))
 
 
+def per_shard(fn, in_axes, out_axes):
+    """`fn` run on each device's own shard of its operands, under the
+    ambient mesh: how a Pallas kernel is called inside a GSPMD jit.
+
+    Mosaic refuses to be partitioned automatically ("wrap the call in
+    a shard_map"), so a kernel whose operands are head- or
+    batch-sharded has to sit in a shard_map over exactly those axes.
+    in_axes / out_axes give one tuple of LOGICAL axis names per operand
+    / result, resolved by the ambient flax rules — the same resolution
+    as the nn.with_logical_constraint calls around the call site, so
+    entering the shard_map moves no data. Off-mesh, or on a one-device
+    mesh, `fn` is returned unchanged. A dim its mesh axes do not
+    divide raises at trace time (the dispatch ladder's next rung)."""
+    from skypilot_tpu.parallel import mesh as mesh_lib
+    mesh = mesh_lib.current_mesh()
+    if mesh is None or mesh.size == 1:
+        return fn
+    import flax.linen as nn
+    return jax.shard_map(
+        fn, mesh=mesh,
+        in_specs=tuple(nn.logical_to_mesh_axes(a) for a in in_axes),
+        out_specs=nn.logical_to_mesh_axes(out_axes), check_vma=False)
+
+
 def tree_shardings(mesh: Mesh, logical_tree,
                    rules: AxisRules = DEFAULT_RULES):
     """Map a pytree of logical-axis tuples to NamedShardings."""

@@ -192,7 +192,7 @@ def add_service(name: str, spec: Any, task_yaml: str,
     A per-service bearer token is minted here; the controller's admin
     API (/controller/*) requires it, so reaching the controller port is
     not enough to terminate or roll the service (the reference gets the
-    same property from SSH-tunneled codegen; VERDICT r4 weak #3).
+    same property from SSH-tunneled codegen).
     """
     db = _get_db()
     with _DB_LOCK:

@@ -15,7 +15,6 @@ Run on every node (the gang does this for `num_nodes: 2` tasks):
     python -m skypilot_tpu.train.examples.cnn_distributed --steps 60
 """
 import argparse
-import os
 import time
 
 import jax
@@ -81,11 +80,6 @@ def synthetic_batch(rng: np.random.Generator, n: int, num_classes: int):
 
 
 def main(argv=None) -> None:
-    # Honor an explicit JAX_PLATFORMS before backend init (same dance
-    # as infer/server.py: this image pins a TPU platform plugin).
-    if os.environ.get('JAX_PLATFORMS'):
-        jax.config.update('jax_platforms', os.environ['JAX_PLATFORMS'])
-
     parser = argparse.ArgumentParser()
     parser.add_argument('--steps', type=int, default=60)
     parser.add_argument('--global-batch', type=int, default=64)

@@ -55,9 +55,6 @@ def main(argv=None) -> None:
     parser.add_argument('--lr', type=float, default=1e-3)
     args = parser.parse_args(argv)
 
-    import os
-    if os.environ.get('JAX_PLATFORMS'):
-        jax.config.update('jax_platforms', os.environ['JAX_PLATFORMS'])
     devices = jax.devices()
     mesh = Mesh(np.array(devices), ('data',))
     repl = NamedSharding(mesh, P())

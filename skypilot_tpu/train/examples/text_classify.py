@@ -16,7 +16,6 @@ lexicon, labels follow the majority lexicon.
 """
 import argparse
 import dataclasses
-import os
 import time
 
 import jax
@@ -48,9 +47,6 @@ def synthetic_batch(rng, n: int, seq: int):
 
 
 def main(argv=None) -> None:
-    if os.environ.get('JAX_PLATFORMS'):
-        jax.config.update('jax_platforms', os.environ['JAX_PLATFORMS'])
-
     parser = argparse.ArgumentParser()
     parser.add_argument('--steps', type=int, default=80)
     parser.add_argument('--batch', type=int, default=32)

@@ -1,8 +1,7 @@
 """Per-rank training heartbeats: the training plane's liveness signal.
 
 SPMD gangs fail by *hanging* — one stalled rank blocks every collective
-and the job looks RUNNING forever (the blindness behind the
-`device_hang` statuses in BENCH_r03–r05). The fix starts with a cheap,
+and the job looks RUNNING forever. The fix starts with a cheap,
 always-on progress record: every rank writes, at most once per
 ``SKYT_HEARTBEAT_INTERVAL_S``, a small JSON heartbeat (step, rolling
 step-time EWMA, tokens/s, host timestamp, phase) to a local file the

@@ -9,7 +9,6 @@ synthetic data.
 """
 import argparse
 import json
-import os
 import time
 from typing import Dict, Iterator, Optional
 
@@ -17,6 +16,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from skypilot_tpu.utils import compile_cache
 from skypilot_tpu.utils import faults
 from skypilot_tpu.utils import log_utils
 from skypilot_tpu.utils import env
@@ -129,6 +129,7 @@ def jsonl_batches(path: str, vocab_size: int, batch: int, seq: int,
 
 
 def main(argv=None) -> None:
+    compile_cache.configure()
     parser = argparse.ArgumentParser()
     parser.add_argument('--model', default='llama3-1b')
     parser.add_argument('--mesh', default='auto',
@@ -177,11 +178,6 @@ def main(argv=None) -> None:
                              '(docs/performance.md). 0 disables.')
     args = parser.parse_args(argv)
 
-    # Some TPU images pin a platform plugin that wins over the env var;
-    # honor an explicit JAX_PLATFORMS the way tests/conftest.py does.
-    if os.environ.get('JAX_PLATFORMS'):
-        jax.config.update('jax_platforms', os.environ['JAX_PLATFORMS'])
-
     # Multi-host: join via the gang env contract (runtime/gang.py
     # exports the JAX coordinator triplet; this jax's argless
     # initialize would not read it).
@@ -190,6 +186,8 @@ def main(argv=None) -> None:
     logger.info('process %d/%d, %d local / %d global devices',
                 jax.process_index(), jax.process_count(),
                 jax.local_device_count(), jax.device_count())
+    from skypilot_tpu.ops import dispatch as ops_dispatch
+    logger.info('device: %s', json.dumps(ops_dispatch.device_info()))
 
     # Training-plane observability (docs/observability.md "Training
     # plane"): per-step heartbeats to SKYT_HEARTBEAT_FILE (relayed by
@@ -436,10 +434,15 @@ def main(argv=None) -> None:
                     # kernel ladder rung each op landed on, so a run
                     # silently degraded to the XLA reference (e.g. an
                     # un-lowerable shape) is visible in the job log.
-                    from skypilot_tpu.ops import dispatch as ops_dispatch
                     paths = ops_dispatch.snapshot()
                     if paths:
-                        logger.info('kernel dispatch paths: %s', paths)
+                        from skypilot_tpu.ops import flash_attention
+                        logger.info(
+                            'kernel dispatch paths: %s (pallas %s, '
+                            'flash backward %s)', paths,
+                            'interpreted' if ops_dispatch.interpret_mode()
+                            else 'compiled',
+                            flash_attention.bwd_impl_choice())
                 tokens_seen += args.batch * args.seq * jax.process_count()
                 if hb is not None:
                     live_state['step'] = step
@@ -485,7 +488,10 @@ def main(argv=None) -> None:
                     if not first_boundary_done:
                         first_boundary_done = True
                         from skypilot_tpu.parallel import comms_census
-                        mfu_on = env.get_bool('SKYT_TRAIN_MFU', True)
+                        # MFU is a device metric: off the TPU there
+                        # is no peak to divide by, so none is published.
+                        mfu_on = env.get_bool('SKYT_TRAIN_MFU', True) \
+                            and jax.default_backend() == 'tpu'
                         census_on = comms_census.census_mode() != 'off'
                         # One lowering feeds BOTH the MFU cost
                         # analysis and the comms census (same stage,
@@ -558,6 +564,10 @@ def main(argv=None) -> None:
             if ckpt.latest_step() != args.steps:
                 ckpt.save(args.steps, state, force=True)
             ckpt.close()
+        logger.info('device at exit: %s',
+                    json.dumps(ops_dispatch.device_info()))
+        logger.info('compile cache: %s',
+                    json.dumps(compile_cache.snapshot()))
         logger.info('done: %d steps', args.steps - start_step)
     finally:
         # In-process callers (tests) outlive main(): give them

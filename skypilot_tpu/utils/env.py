@@ -204,8 +204,7 @@ _var('SKYT_COMMS_PROBE_ITERS', 'int', 5,
      'Timed iterations per comms probe measurement.')
 _var('SKYT_COMMS_PROBE_TIMEOUT_S', 'float', 120.0,
      'Soft wall-clock budget of one comms probe sweep (checked '
-     'between measurements), and the backend-init bound of the '
-     'collectives CLI.')
+     'between measurements).')
 _var('SKYT_COMMS_CACHE', 'str',
      '~/.cache/skypilot_tpu/comms_profile.json',
      'Persistent comms-profile cache path (probe results + placement '

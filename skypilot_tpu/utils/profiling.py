@@ -54,13 +54,16 @@ PEAK_FLOPS = {
 
 
 def peak_flops(device) -> float:
-    """Peak bf16 FLOPs of one device; 1e12 nominal for unknown/CPU
-    (MFU against it is a smoke number, not a claim)."""
-    kind = getattr(device, 'device_kind', '')
+    """Peak bf16 FLOPs of one device. A device that is not in the
+    table is an error, not a default: a utilization against a made-up
+    peak is not a measurement."""
+    kind = device.device_kind
     for prefix, flops in PEAK_FLOPS.items():
         if kind.startswith(prefix):
             return flops
-    return 1e12
+    raise ValueError(
+        f'no peak FLOP/s on record for device kind {kind!r} '
+        f'(known: {sorted(PEAK_FLOPS)})')
 
 
 class ProfilerBusy(RuntimeError):
@@ -119,12 +122,9 @@ def capture_trace(duration_ms: float,
 def cost_analysis_flops(stage) -> Optional[float]:
     """FLOPs from a jax stage's ``cost_analysis()`` (a ``Lowered`` or
     a compiled executable), or None when the backend does not report
-    them (some platforms return nothing, older jax returns a
-    per-device list)."""
+    them."""
     try:
         ca = stage.cost_analysis()
-        if isinstance(ca, (list, tuple)):
-            ca = ca[0] if ca else None
         if not isinstance(ca, dict):
             return None
         flops = float(ca.get('flops', 0.0) or 0.0)

@@ -2114,12 +2114,6 @@ def test_sft_preemption_checkpoint_and_resume(tmp_path):
     from skypilot_tpu.runtime.job_lib import EXIT_CODE_PREEMPTED
     ckpt_dir = tmp_path / 'ckpt'
     env = dict(os.environ, JAX_PLATFORMS='cpu')
-    # The persistent XLA compile cache (conftest exports it) wedges or
-    # heap-corrupts the RESUME subprocess on this jax 0.4.37 CPU image
-    # (cpu_aot_loader deserialization; reproduced outside pytest with
-    # the cache on, never with it off). Pay the ~10s recompile instead.
-    env.pop('JAX_COMPILATION_CACHE_DIR', None)
-    env.pop('JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS', None)
     args = [sys.executable, '-m', 'skypilot_tpu.train.sft',
             '--model', 'debug', '--steps', '100000',
             '--batch', '1', '--seq', '16',
@@ -2250,12 +2244,6 @@ def test_chaos_gang_hang_watchdog_recovery(tmp_path, tmp_state_dir,
     monkeypatch.setenv('SKYT_WATCHDOG_INTERVAL_S', '0.5')
     monkeypatch.setenv('SKYT_WATCHDOG_POLL_S', '0.3')
     monkeypatch.setenv('SKYT_HEARTBEAT_INTERVAL_S', '0.1')
-    # The persistent XLA compile cache wedges sft RESUME subprocesses
-    # on this jax 0.4.37 CPU image (documented since PR 4) — the
-    # relaunched ranks pay the recompile instead.
-    monkeypatch.delenv('JAX_COMPILATION_CACHE_DIR', raising=False)
-    monkeypatch.delenv('JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS',
-                       raising=False)
     state.reset_db_for_testing()
     jobs_state.reset_db_for_testing()
 

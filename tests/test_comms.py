@@ -314,6 +314,30 @@ class TestCensusParsers:
         assert e.op == 'all_reduce' and e.axes == ('tp',)
         assert e.ranks == 2 and e.payload_bytes == 4 * 64 * 4
 
+    def test_hlo_untyped_operands(self):
+        """The dump this JAX emits names operands without their types
+        ('all-reduce(%fusion.1)'): payloads come from the result type —
+        every tuple element of a variadic all-reduce, the result half
+        of an async all-gather's (operand, result) tuple, and ranks x
+        the result for a reduce-scatter."""
+        mesh = fake_mesh((4,), ('fsdp',))
+        text = (
+            '  %all-reduce.67 = (f32[8,64,32]{2,1,0}, f32[8,64,64]'
+            '{2,1,0}) all-reduce(%bitcast.5, %bitcast.8), channel_id=2'
+            ', replica_groups=[1,4]<=[4], use_global_device_ids=true, '
+            'to_apply=%add.clone\n'
+            '  %ags = (f32[4,8]{1,0}, f32[16,8]{1,0}) all-gather-start('
+            '%p), channel_id=3, replica_groups=[1,4]<=[4], '
+            'dimensions={0}\n'
+            '  %rs = f32[2,8]{1,0} reduce-scatter(%x), channel_id=4, '
+            'replica_groups=[1,4]<=[4], dimensions={0}, '
+            'to_apply=%add\n')
+        got = {e.op: e.payload_bytes
+               for e in comms_census._census_hlo(text, mesh)}
+        assert got == {'all_reduce': (8 * 64 * 32 + 8 * 64 * 64) * 4,
+                       'all_gather': 16 * 8 * 4,
+                       'reduce_scatter': 2 * 8 * 4 * 4}
+
     def test_hlo_done_ops_skipped(self):
         mesh = fake_mesh((2,), ('dp',))
         text = ('  %ag = f32[8]{0} all-gather-start(f32[4]{0} %x), '
@@ -476,9 +500,8 @@ class TestCensusReal:
             return (jnp.sum(y) + jnp.sum(z) + jnp.sum(w) +
                     jnp.sum(s[..., :1]))
 
-        fn = jax.jit(mesh_lib.shard_map(f, mesh, in_specs=P('dp'),
-                                        out_specs=P(),
-                                        check_rep=False))
+        fn = jax.jit(jax.shard_map(f, mesh=mesh, in_specs=P('dp'),
+                                   out_specs=P(), check_vma=False))
         x = jnp.ones((8, 4))
         entries, source = comms_census.census_step(fn, x, mesh=mesh)
         assert source == 'stablehlo_lowered'

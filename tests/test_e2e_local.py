@@ -6,7 +6,6 @@ coverage is cloud smoke tests (tests/test_smoke.py), which need real VMs.
 """
 import time
 
-import jax
 import pytest
 from click.testing import CliRunner
 
@@ -190,16 +189,6 @@ def _examples_dir():
 
 
 @pytest.mark.integration
-@pytest.mark.skipif(
-    jax.__version__.startswith('0.4.'),
-    reason='jax 0.4.x CPU backend cannot run cross-process '
-           'computations: every collective in the 2-node DP step dies '
-           'with XlaRuntimeError "Multiprocess computations aren\'t '
-           'implemented on the CPU backend" (root-caused from the '
-           'rank logs, PR 7; the gang plumbing itself works — both '
-           'ranks join the coordinator and print the mesh line). '
-           'Re-enable when the image ships jax>=0.5 (CPU cross-host '
-           'collectives) or when running with real accelerators.')
 def test_cnn_distributed_yaml_two_nodes(local_env, capsys):
     """examples/cnn_distributed.yaml (the resnet_distributed_torch
     analog) runs 2-node data-parallel under skyt launch on the local

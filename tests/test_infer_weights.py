@@ -17,7 +17,6 @@ import pytest
 from skypilot_tpu.infer import engine as engine_lib
 from skypilot_tpu.infer import tokenizer as tokenizer_lib
 from skypilot_tpu.models import llama, weights
-from skypilot_tpu.utils import jax_compat
 from skypilot_tpu.parallel import mesh as mesh_lib
 
 # Compile-heavy (JAX jit on the 1-core CPU host) or subprocess-driven:
@@ -255,8 +254,8 @@ def test_checkpoint_int8_stream_load_matches_post_quantize(debug_ckpt):
     want = quant.quantize_params(
         weights.load_llama_params(cfg, ckpt_dir))
     got = weights.load_llama_params(cfg, ckpt_dir, quantize='int8')
-    la = jax_compat.tree_leaves_with_path(want)
-    lb = jax_compat.tree_leaves_with_path(got)
+    la = jax.tree.leaves_with_path(want)
+    lb = jax.tree.leaves_with_path(got)
     assert [p for p, _ in la] == [p for p, _ in lb]
     for (path, a), (_, b) in zip(la, lb):
         a, b = np.asarray(a), np.asarray(b)
@@ -394,8 +393,8 @@ def test_mixtral_int8_stream_load_matches_post_quantize(mixtral_ckpt):
         weights.load_mixtral_params(cfg, moe_cfg, ckpt_dir))
     got = weights.load_mixtral_params(cfg, moe_cfg, ckpt_dir,
                                       quantize='int8')
-    la = jax_compat.tree_leaves_with_path(want)
-    lb = jax_compat.tree_leaves_with_path(got)
+    la = jax.tree.leaves_with_path(want)
+    lb = jax.tree.leaves_with_path(got)
     assert [p for p, _ in la] == [p for p, _ in lb]
     n_int8 = 0
     for (path, a), (_, b) in zip(la, lb):
@@ -560,8 +559,8 @@ def test_qwen2_int8_stream_load_matches_post_quantize(tmp_path):
     want = quant.quantize_params(
         weights.load_llama_params(cfg, str(ckpt)))
     got = weights.load_llama_params(cfg, str(ckpt), quantize='int8')
-    la = jax_compat.tree_leaves_with_path(want)
-    lb = jax_compat.tree_leaves_with_path(got)
+    la = jax.tree.leaves_with_path(want)
+    lb = jax.tree.leaves_with_path(got)
     assert [p for p, _ in la] == [p for p, _ in lb]
     n_int8 = 0
     for (path, a), (_, b) in zip(la, lb):

@@ -1,7 +1,7 @@
 """Serving memory plans: the 70B-on-v5e recipes are pinned here.
 
-These tests are the feasibility proof for examples/llama_70b_serve.yaml
-(VERDICT r4 item 4): the plan reproduces the engine's real placement
+These tests are the feasibility proof for examples/llama_70b_serve.yaml:
+the plan reproduces the engine's real placement
 arithmetic, so a passing assertion means the engine's arrays fit.
 """
 import dataclasses

@@ -11,7 +11,6 @@ stand-in for a serving replica spanning a multi-host TPU slice
 (reference: TP across a whole replica cluster, llm/vllm/serve.yaml
 --tensor-parallel-size over $SKYPILOT_NUM_GPUS_PER_NODE).
 """
-import jax
 import pytest
 
 from skypilot_tpu.infer import multihost
@@ -20,15 +19,6 @@ pytestmark = pytest.mark.heavy
 
 
 @pytest.mark.integration
-@pytest.mark.skipif(
-    jax.__version__.startswith('0.4.'),
-    reason='jax 0.4.x CPU backend cannot run cross-process '
-           'computations (XlaRuntimeError "Multiprocess computations '
-           'aren\'t implemented on the CPU backend"), so the 2-process '
-           'half of this selftest can never lower — documented red '
-           'since PR 1, now an explicit skip. Re-enable when the image '
-           'ships jax>=0.5 or on real multi-host accelerators '
-           '(tests_tpu/ covers the on-chip path).')
 def test_two_process_lockstep_matches_single_process(tmp_path):
     # Reference: ONE process, 2 local devices, same tp=2 mesh.
     ref = multihost.run_selftest_gang(

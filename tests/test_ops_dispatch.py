@@ -76,12 +76,13 @@ class TestBlockSelection:
 
 
 # ------------------------------------- shape grid over the public entry
-# Adversarial shapes: (b, sq, sk, hq, hkv, d). Includes the exact
-# BENCH_r02 decode shape (4, 32, 8, 256) in BOTH layout readings —
-# [B,Sq,Hq,D] and the [B,Hq,Sq,D] kernel layout it was logged in.
+# Adversarial shapes: (b, sq, sk, hq, hkv, d). Includes the decode
+# shape (4, 32, 8, 256) whose 256-row block once crashed the Mosaic
+# lowering, in BOTH layout readings — [B,Sq,Hq,D] and the [B,Hq,Sq,D]
+# kernel layout it was logged in.
 SHAPE_GRID = [
-    (4, 32, 32, 8, 8, 256),     # BENCH_r02, API layout
-    (4, 8, 8, 32, 32, 256),     # BENCH_r02, kernel-layout reading
+    (4, 32, 32, 8, 8, 256),     # that shape, API layout
+    (4, 8, 8, 32, 32, 256),     # that shape, kernel-layout reading
     (2, 1, 1, 4, 2, 64),        # decode: single query token
     (1, 300, 300, 2, 2, 64),    # non-pow2, non-8-divisible seq
     (1, 48, 48, 4, 4, 64),      # tiny batch, sub-block seq

@@ -14,7 +14,6 @@ import jax
 import jax.numpy as jnp
 
 from skypilot_tpu.models import llama, quant
-from skypilot_tpu.utils import jax_compat
 
 # Compile-heavy (JAX jit on the 1-core CPU host) or subprocess-driven:
 pytestmark = pytest.mark.heavy
@@ -54,9 +53,9 @@ def test_quantized_tree_matches_quant_model_structure():
     b = jax.tree.structure(qinit)
     assert a == b, (a, b)
     import flax.linen as nn
-    flat_a = jax_compat.tree_leaves_with_path(
+    flat_a = jax.tree.leaves_with_path(
         qparams, is_leaf=lambda x: isinstance(x, nn.meta.AxisMetadata))
-    flat_b = jax_compat.tree_leaves_with_path(
+    flat_b = jax.tree.leaves_with_path(
         qinit, is_leaf=lambda x: isinstance(x, nn.meta.AxisMetadata))
     for (pa, x), (pb, y) in zip(flat_a, flat_b):
         assert pa == pb
@@ -202,8 +201,8 @@ def test_fused_init_quantize_matches_sequential():
         jax.jit(model.init)(jax.random.PRNGKey(0), sample))
     fused = jax.jit(lambda k: quant.quantize_params(
         model.init(k, sample)))(jax.random.PRNGKey(0))
-    la = jax_compat.tree_leaves_with_path(seq)
-    lb = jax_compat.tree_leaves_with_path(fused)
+    la = jax.tree.leaves_with_path(seq)
+    lb = jax.tree.leaves_with_path(fused)
     assert len(la) == len(lb)
     for (pa, a), (pb, b) in zip(la, lb):
         assert pa == pb and a.dtype == b.dtype and a.shape == b.shape

@@ -224,8 +224,8 @@ def test_failed_add_service_releases_write_lock(serve_env):
 def test_controller_auth_rejects_unauthenticated(serve_env):
     """Admin endpoints require the per-service bearer token minted at
     add_service: no token / wrong token => 401 before the handler runs;
-    the right token passes (VERDICT r4 weak #3 — the reference gets
-    this property from SSH-tunneled codegen instead)."""
+    the right token passes (the reference gets this property from
+    SSH-tunneled codegen instead)."""
     import asyncio
 
     import aiohttp

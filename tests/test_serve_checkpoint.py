@@ -4,8 +4,8 @@ continuations through the HTTP path match transformers.
 The reference's serving story is `--model <hf id>` into vLLM
 (llm/vllm/serve.yaml); ours is `--checkpoint <dir>` into the TPU-native
 engine. This test drives the full served path — safetensors from disk →
-server subprocess → HTTP /generate — not just the loader (VERDICT r2
-missing #5). The checkpoint is written by save_hf_checkpoint (HF layout:
+server subprocess → HTTP /generate — not just the loader. The checkpoint
+is written by save_hf_checkpoint (HF layout:
 config.json + model.safetensors), the same format released Llama weights
 ship in; swap the dir for a downloaded snapshot and nothing changes.
 """

@@ -514,8 +514,7 @@ def test_debug_profile_endpoint(monkeypatch):
 
 def test_fleet_routes_profile_proxy(monkeypatch):
     """/fleet/* HTTP surface via add_fleet_routes: metrics text, slo
-    JSON, and the profile proxy's 400/404 paths (the 200 path is
-    covered end-to-end by tpu_validation.sh step 11)."""
+    JSON, and the profile proxy's 400/404 paths."""
     import asyncio
 
     from aiohttp import web

@@ -225,8 +225,8 @@ def count_files(root: Path,
 
 
 def render_json(violations: List[Violation], files_checked: int) -> str:
-    """Stable JSON artifact (tpu_validation.sh archives it alongside
-    probe.json; tests/test_analysis.py goldens the schema)."""
+    """Stable JSON artifact (tests/test_analysis.py goldens the
+    schema)."""
     payload = {
         'schema': 1,
         'tool': 'skyanalyze',

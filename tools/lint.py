@@ -7,8 +7,7 @@ always used; exit 0 = clean.
     python tools/lint.py                    full tree, human output
     python tools/lint.py path [path ...]    file passes on those paths
     python tools/lint.py --json OUT.json    also write the JSON
-                                            artifact (tpu_validation.sh
-                                            archives it with probe.json)
+                                            artifact
     python tools/lint.py --write-env-docs   regenerate docs/env_vars.md
                                             from the env registry
 
