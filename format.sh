@@ -13,6 +13,9 @@
 #   ./format.sh --full  everything: adds the compile-heavy JAX suites
 #                       and subprocess integration tests — run before
 #                       snapshots/releases.
+# The markers `heavy` and `integration` choose this script's fast tier and
+# nothing else; the driver's tier-1 (`-m 'not slow'`, ROADMAP D10) reads only
+# `slow`, and what carries `slow` still runs here under --full.
 set -e
 cd "$(dirname "$0")"
 

@@ -103,22 +103,25 @@ def update_result(benchmark: str, cluster: str,
 
 def get_benchmarks() -> List[Dict[str, Any]]:
     db = _get_db()
-    rows = db.execute('SELECT * FROM benchmarks ORDER BY name').fetchall()
+    with _DB_LOCK:
+        rows = db.execute('SELECT * FROM benchmarks ORDER BY name').fetchall()
     return [dict(r) for r in rows]
 
 
 def get_benchmark(name: str) -> Optional[Dict[str, Any]]:
     db = _get_db()
-    row = db.execute('SELECT * FROM benchmarks WHERE name=?',
-                     (name,)).fetchone()
+    with _DB_LOCK:
+        row = db.execute('SELECT * FROM benchmarks WHERE name=?',
+                         (name,)).fetchone()
     return dict(row) if row else None
 
 
 def get_results(benchmark: str) -> List[Dict[str, Any]]:
     db = _get_db()
-    rows = db.execute(
-        'SELECT * FROM benchmark_results WHERE benchmark=? '
-        'ORDER BY cluster', (benchmark,)).fetchall()
+    with _DB_LOCK:
+        rows = db.execute(
+            'SELECT * FROM benchmark_results WHERE benchmark=? '
+            'ORDER BY cluster', (benchmark,)).fetchall()
     out = []
     for r in rows:
         d = dict(r)

@@ -176,15 +176,17 @@ def bump_recovery_count(job_id: int) -> None:
 
 def get_job(job_id: int) -> Optional[Dict[str, Any]]:
     db = _get_db()
-    row = db.execute('SELECT * FROM managed_jobs WHERE job_id=?',
-                     (job_id,)).fetchone()
+    with _DB_LOCK:
+        row = db.execute('SELECT * FROM managed_jobs WHERE job_id=?',
+                         (job_id,)).fetchone()
     return _row_to_dict(row) if row is not None else None
 
 
 def get_jobs(skip_finished: bool = False) -> List[Dict[str, Any]]:
     db = _get_db()
-    rows = db.execute(
-        'SELECT * FROM managed_jobs ORDER BY job_id').fetchall()
+    with _DB_LOCK:
+        rows = db.execute(
+            'SELECT * FROM managed_jobs ORDER BY job_id').fetchall()
     jobs = [_row_to_dict(r) for r in rows]
     if skip_finished:
         jobs = [j for j in jobs if not j['status'].is_terminal()]

@@ -172,15 +172,17 @@ def set_status(job_id: int, status: JobStatus) -> None:
 
 def get_job(job_id: int) -> Optional[Dict[str, Any]]:
     db = _get_db()
-    row = db.execute('SELECT * FROM jobs WHERE job_id=?',
-                     (job_id,)).fetchone()
+    with _DB_LOCK:
+        row = db.execute('SELECT * FROM jobs WHERE job_id=?',
+                         (job_id,)).fetchone()
     return _row_to_job(row) if row else None
 
 
 def get_latest_job_id() -> Optional[int]:
     db = _get_db()
-    row = db.execute(
-        'SELECT job_id FROM jobs ORDER BY job_id DESC LIMIT 1').fetchone()
+    with _DB_LOCK:
+        row = db.execute(
+            'SELECT job_id FROM jobs ORDER BY job_id DESC LIMIT 1').fetchone()
     return row['job_id'] if row else None
 
 
@@ -189,12 +191,14 @@ def get_jobs(statuses: Optional[List[JobStatus]] = None) -> List[Dict[str,
     db = _get_db()
     if statuses:
         marks = ','.join('?' * len(statuses))
-        rows = db.execute(
-            f'SELECT * FROM jobs WHERE status IN ({marks}) '
-            'ORDER BY job_id DESC', [s.value for s in statuses]).fetchall()
+        with _DB_LOCK:
+            rows = db.execute(
+                f'SELECT * FROM jobs WHERE status IN ({marks}) '
+                'ORDER BY job_id DESC', [s.value for s in statuses]).fetchall()
     else:
-        rows = db.execute(
-            'SELECT * FROM jobs ORDER BY job_id DESC').fetchall()
+        with _DB_LOCK:
+            rows = db.execute(
+                'SELECT * FROM jobs ORDER BY job_id DESC').fetchall()
     return [_row_to_job(r) for r in rows]
 
 
@@ -222,17 +226,19 @@ def is_cluster_idle(threshold_statuses=(JobStatus.INIT, JobStatus.PENDING,
     """No nonterminal jobs (reference: job_lib.py:641)."""
     db = _get_db()
     marks = ','.join('?' * len(threshold_statuses))
-    row = db.execute(
-        f'SELECT COUNT(*) AS n FROM jobs WHERE status IN ({marks})',
-        [s.value for s in threshold_statuses]).fetchone()
+    with _DB_LOCK:
+        row = db.execute(
+            f'SELECT COUNT(*) AS n FROM jobs WHERE status IN ({marks})',
+            [s.value for s in threshold_statuses]).fetchone()
     return row['n'] == 0
 
 
 def last_activity_time() -> float:
     """Most recent job end/submit time; agent start if no jobs ever."""
     db = _get_db()
-    row = db.execute('SELECT MAX(COALESCE(end_at, submitted_at)) AS t '
-                     'FROM jobs').fetchone()
+    with _DB_LOCK:
+        row = db.execute('SELECT MAX(COALESCE(end_at, submitted_at)) AS t '
+                         'FROM jobs').fetchone()
     if row['t'] is not None:
         return row['t']
     return float(get_kv('agent_start_time') or time.time())
@@ -241,9 +247,10 @@ def last_activity_time() -> float:
 # ----------------------------------------------------------------- gang state
 def gang_records(job_id: int) -> List[Dict[str, Any]]:
     db = _get_db()
-    rows = db.execute(
-        'SELECT * FROM gang WHERE job_id=? ORDER BY rank',
-        (job_id,)).fetchall()
+    with _DB_LOCK:
+        rows = db.execute(
+            'SELECT * FROM gang WHERE job_id=? ORDER BY rank',
+            (job_id,)).fetchall()
     return [dict(r) for r in rows]
 
 
@@ -344,5 +351,6 @@ def set_kv(key: str, value: str) -> None:
 
 def get_kv(key: str) -> Optional[str]:
     db = _get_db()
-    row = db.execute('SELECT value FROM kv WHERE key=?', (key,)).fetchone()
+    with _DB_LOCK:
+        row = db.execute('SELECT value FROM kv WHERE key=?', (key,)).fetchone()
     return row['value'] if row else None

@@ -480,7 +480,7 @@ class ReplicaManager:
         from skypilot_tpu.runtime import reaper
         try:
             # Chaos hook: an injected error forces this row down the
-            # reap path (tests/test_chaos.py, SKYT_FAULTS
+            # reap path (tests/test_chaos_*.py, SKYT_FAULTS
             # replica.orphan=error[,where=replica:<id>]).
             faults.inject('replica.orphan', replica=info.replica_id)
         except faults.FaultError:

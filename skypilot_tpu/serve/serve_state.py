@@ -264,8 +264,9 @@ def get_rollout(name: str) -> Optional[Dict[str, Any]]:
     an unreadable blob — which is logged, not raised: a torn rollout
     row must not wedge a restarting controller)."""
     db = _get_db()
-    row = db.execute('SELECT rollout FROM services WHERE name=?',
-                     (name,)).fetchone()
+    with _DB_LOCK:
+        row = db.execute('SELECT rollout FROM services WHERE name=?',
+                         (name,)).fetchone()
     if row is None or row['rollout'] is None:
         return None
     import json
@@ -282,14 +283,16 @@ def get_rollout(name: str) -> Optional[Dict[str, Any]]:
 
 def get_service(name: str) -> Optional[Dict[str, Any]]:
     db = _get_db()
-    row = db.execute('SELECT * FROM services WHERE name=?',
-                     (name,)).fetchone()
+    with _DB_LOCK:
+        row = db.execute('SELECT * FROM services WHERE name=?',
+                         (name,)).fetchone()
     return _service_row(row) if row else None
 
 
 def get_services() -> List[Dict[str, Any]]:
     db = _get_db()
-    rows = db.execute('SELECT * FROM services ORDER BY name').fetchall()
+    with _DB_LOCK:
+        rows = db.execute('SELECT * FROM services ORDER BY name').fetchall()
     return [_service_row(r) for r in rows]
 
 
@@ -339,9 +342,10 @@ def get_replicas(service_name: str) -> List[Any]:
     status` until someone hand-edits the DB. The controller's
     prune_terminal_replicas sweep deletes such rows."""
     db = _get_db()
-    rows = db.execute(
-        'SELECT replica_id, info FROM replicas WHERE service_name=? '
-        'ORDER BY replica_id', (service_name,)).fetchall()
+    with _DB_LOCK:
+        rows = db.execute(
+            'SELECT replica_id, info FROM replicas WHERE service_name=? '
+            'ORDER BY replica_id', (service_name,)).fetchall()
     out = []
     for r in rows:
         try:
@@ -366,8 +370,9 @@ def update_row_gauges() -> Dict[str, int]:
     db = _get_db()
     counts = {}
     for table in ('services', 'replicas'):
-        counts[table] = db.execute(
-            f'SELECT COUNT(*) FROM {table}').fetchone()[0]
+        with _DB_LOCK:
+            counts[table] = db.execute(
+                f'SELECT COUNT(*) FROM {table}').fetchone()[0]
         _rows_gauge().labels(table).set(counts[table])
     return counts
 

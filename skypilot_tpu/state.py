@@ -141,8 +141,9 @@ def _history_cmd() -> str:
 
 def _get_hash(name: str) -> Optional[str]:
     db = _get_db()
-    row = db.execute('SELECT cluster_hash FROM clusters WHERE name=?',
-                     (name,)).fetchone()
+    with _DB_LOCK:
+        row = db.execute('SELECT cluster_hash FROM clusters WHERE name=?',
+                         (name,)).fetchone()
     return row['cluster_hash'] if row else None
 
 
@@ -193,14 +194,16 @@ def set_cluster_autostop(name: str, idle_minutes: int, to_down: bool) -> None:
 
 def get_cluster(name: str) -> Optional[Dict[str, Any]]:
     db = _get_db()
-    row = db.execute('SELECT * FROM clusters WHERE name=?', (name,)).fetchone()
+    with _DB_LOCK:
+        row = db.execute('SELECT * FROM clusters WHERE name=?', (name,)).fetchone()
     return _cluster_row_to_dict(row) if row else None
 
 
 def get_clusters() -> List[Dict[str, Any]]:
     db = _get_db()
-    rows = db.execute(
-        'SELECT * FROM clusters ORDER BY launched_at DESC').fetchall()
+    with _DB_LOCK:
+        rows = db.execute(
+            'SELECT * FROM clusters ORDER BY launched_at DESC').fetchall()
     return [_cluster_row_to_dict(r) for r in rows]
 
 
@@ -243,7 +246,8 @@ def remove_cluster(name: str) -> None:
 
 def get_cluster_history() -> List[Dict[str, Any]]:
     db = _get_db()
-    rows = db.execute('SELECT * FROM cluster_history').fetchall()
+    with _DB_LOCK:
+        rows = db.execute('SELECT * FROM cluster_history').fetchall()
     out = []
     for r in rows:
         out.append({
@@ -271,7 +275,8 @@ def set_config(key: str, value: Any) -> None:
 
 def get_config(key: str, default: Any = None) -> Any:
     db = _get_db()
-    row = db.execute('SELECT value FROM config WHERE key=?', (key,)).fetchone()
+    with _DB_LOCK:
+        row = db.execute('SELECT value FROM config WHERE key=?', (key,)).fetchone()
     return json.loads(row['value']) if row else default
 
 
@@ -301,7 +306,8 @@ def add_or_update_storage(name: str, handle: Any,
 
 def get_storage(name: str) -> Optional[Dict[str, Any]]:
     db = _get_db()
-    row = db.execute('SELECT * FROM storage WHERE name=?', (name,)).fetchone()
+    with _DB_LOCK:
+        row = db.execute('SELECT * FROM storage WHERE name=?', (name,)).fetchone()
     if row is None:
         return None
     return {'name': row['name'], 'launched_at': row['launched_at'],
@@ -311,7 +317,8 @@ def get_storage(name: str) -> Optional[Dict[str, Any]]:
 
 def get_storages() -> List[Dict[str, Any]]:
     db = _get_db()
-    rows = db.execute('SELECT name FROM storage').fetchall()
+    with _DB_LOCK:
+        rows = db.execute('SELECT name FROM storage').fetchall()
     return [get_storage(r['name']) for r in rows]
 
 

@@ -5,8 +5,6 @@ sky/utils/schemas.py (914 LoC). Validated with `jsonschema`.
 """
 from typing import Any, Dict
 
-import jsonschema
-
 from skypilot_tpu import exceptions
 
 _RESOURCES_SCHEMA = {
@@ -162,6 +160,10 @@ CONFIG_SCHEMA = {
 
 def _validate(config: Dict[str, Any], schema: Dict[str, Any],
               what: str) -> None:
+    # Imported at first use: jsonschema's format checkers cost 1.5 s to
+    # import, and every agent, controller and CLI process imports this
+    # module through the package root though few of them validate.
+    import jsonschema
     try:
         jsonschema.validate(instance=config, schema=schema)
     except jsonschema.ValidationError as e:

@@ -401,7 +401,7 @@ class TestFleetTransfer:
         import requests
 
         from skypilot_tpu.infer import server as server_lib
-        from tests.test_chaos import _free_port, _wait_http
+        from chaos_helpers import _free_port, _wait_http
 
         # Donor replica: engine + real HTTP surface.
         donor, _ = _make_engine(kv_setup, monkeypatch, tier='host')

@@ -118,6 +118,7 @@ def test_num_lora_params_small(base):
     assert n_lora < 0.2 * n_base
 
 
+@pytest.mark.usefixtures('one_device_children')
 def test_finetune_export_serve_loop(tmp_path):
     """The full reference-recipe loop on debug shapes: real base
     checkpoint -> sft --lora-rank -> export_lora merge -> the merged

@@ -18,6 +18,10 @@ from skypilot_tpu.infer import multihost
 pytestmark = pytest.mark.heavy
 
 
+# slow: 25 s in a six-worker run: two processes that each import JAX,
+# join one jax.distributed runtime and compile the engine in lockstep,
+# then a third engine in this process to compare with.
+@pytest.mark.slow
 @pytest.mark.integration
 def test_two_process_lockstep_matches_single_process(tmp_path):
     # Reference: ONE process, 2 local devices, same tp=2 mesh.

@@ -34,6 +34,13 @@ def _base(max_seq_len=64):
     return cfg, model, params
 
 
+@pytest.fixture(scope='module')
+def lora_base():
+    """The debug config, model and unboxed parameters, once for the
+    module."""
+    return _base()
+
+
 def _rand_adapter(params, rank, alpha, seed):
     """A trained-looking adapter: random A AND B (init's B=0 would make
     the delta vanish and the test vacuous)."""
@@ -46,8 +53,8 @@ def _rand_adapter(params, rank, alpha, seed):
     return tree, lcfg
 
 
-def test_model_level_parity_and_id0():
-    cfg, model, params = _base()
+def test_model_level_parity_and_id0(lora_base):
+    cfg, model, params = lora_base
     tree, lcfg = _rand_adapter(params, rank=4, alpha=8.0, seed=1)
     stack = slora.build_stack([(tree, lcfg.alpha)], dtype='float32')
     tokens = jnp.asarray(
@@ -80,11 +87,11 @@ def _engine(model, params, stack=None, **kw):
                                       lora_stack=stack, **kw)
 
 
-def test_mixed_batch_matches_merged_engines():
+def test_mixed_batch_matches_merged_engines(lora_base):
     """Three concurrent requests — adapter A, adapter B (different
     rank!), and base — decode in the same continuous batch and each
     matches its own merged-model engine token-for-token."""
-    cfg, model, params = _base()
+    cfg, model, params = lora_base
     tree_a, cfg_a = _rand_adapter(params, rank=4, alpha=8.0, seed=3)
     tree_b, cfg_b = _rand_adapter(params, rank=2, alpha=4.0, seed=4)
     stack = slora.build_stack([(tree_a, cfg_a.alpha),
@@ -130,12 +137,12 @@ def test_mixed_batch_matches_merged_engines():
     assert got == want
 
 
-def test_paged_prefix_cache_isolated_per_adapter():
+def test_paged_prefix_cache_isolated_per_adapter(lora_base):
     """Same prompt under two adapters with prefix caching ON: the
     second request must NOT reuse the first adapter's KV pages (K/V
     depend on the adapter's wk/wv) — outputs match per-adapter merged
     engines."""
-    cfg, model, params = _base()
+    cfg, model, params = lora_base
     tree_a, cfg_a = _rand_adapter(params, rank=4, alpha=8.0, seed=5)
     stack = slora.build_stack([(tree_a, cfg_a.alpha)], dtype='float32')
     prompt = list(range(1, 33))   # two full 16-token pages
@@ -163,11 +170,11 @@ def test_paged_prefix_cache_isolated_per_adapter():
         assert got == want, f'lora_id={lid}'
 
 
-def test_spec_decode_with_adapter_stays_exact():
+def test_spec_decode_with_adapter_stays_exact(lora_base):
     """n-gram speculative decoding verifies against the ADAPTER model
     (the lora collection rides into the verify step), so outputs equal
     the merged engine's plain decode."""
-    cfg, model, params = _base()
+    cfg, model, params = lora_base
     tree_a, cfg_a = _rand_adapter(params, rank=4, alpha=8.0, seed=6)
     stack = slora.build_stack([(tree_a, cfg_a.alpha)], dtype='float32')
     prompt = [5, 6, 5, 6, 5, 6, 5, 6]   # repetitive: n-gram drafts fire
@@ -190,8 +197,8 @@ def test_spec_decode_with_adapter_stays_exact():
     assert got == want
 
 
-def test_out_of_range_lora_id_rejected():
-    cfg, model, params = _base()
+def test_out_of_range_lora_id_rejected(lora_base):
+    cfg, model, params = lora_base
     eng = _engine(model, params)   # no stack
     with pytest.raises(ValueError, match='lora_id 1 out of range'):
         eng.submit([1, 2, 3], engine_lib.SamplingParams(lora_id=1))
@@ -202,13 +209,13 @@ def test_out_of_range_lora_id_rejected():
         eng.submit([1, 2, 3], engine_lib.SamplingParams(lora_id=2))
 
 
-def test_adapter_roundtrip_through_orbax(tmp_path):
+def test_adapter_roundtrip_through_orbax(lora_base, tmp_path):
     """load_adapter_dir reads what an sft LoRA run writes (Orbax
     TrainStateS), and build_stack_from_specs maps names to ids."""
     from skypilot_tpu.train import checkpoint as ckpt_lib
     from skypilot_tpu.train import trainer
 
-    cfg, model, params = _base()
+    cfg, model, params = lora_base
     tree, lcfg = _rand_adapter(params, rank=2, alpha=4.0, seed=8)
     tx = trainer.make_optimizer(trainer.TrainerConfig())
     state = trainer.TrainStateS(step=jnp.zeros((), jnp.int32),
@@ -230,12 +237,12 @@ def test_adapter_roundtrip_through_orbax(tmp_path):
                                    rtol=1e-6, atol=1e-7)
 
 
-def test_server_model_routing():
+def test_server_model_routing(lora_base):
     """OpenAI 'model' field routes: base id -> 0, adapter name -> its
     id, unknown -> model_not_found."""
     from skypilot_tpu.infer import server as server_lib
 
-    cfg, model, params = _base()
+    cfg, model, params = lora_base
     eng = _engine(model, params)
     srv = server_lib.InferenceServer(eng, model_id='base',
                                      lora_names={'ft-a': 1})
@@ -258,13 +265,13 @@ def test_parse_lora_flag():
         slora.parse_lora_flag(['a=/x', 'a=/y'])
 
 
-def test_multilora_tp_sharded_matches_tp1():
+def test_multilora_tp_sharded_matches_tp1(lora_base):
     """tp=2 over the CPU mesh: adapter stack replicates, outputs match
     the tp=1 multi-LoRA engine token-for-token."""
     from skypilot_tpu.models import weights
     from skypilot_tpu.parallel import mesh as mesh_lib
 
-    cfg, model, params = _base()
+    cfg, model, params = lora_base
     tree_a, cfg_a = _rand_adapter(params, rank=4, alpha=8.0, seed=9)
     stack = slora.build_stack([(tree_a, cfg_a.alpha)], dtype='float32')
     prompt = [5, 17, 3, 99, 42]
@@ -286,10 +293,10 @@ def test_multilora_tp_sharded_matches_tp1():
     assert got == want
 
 
-def test_stack_layout_mismatch_rejected():
+def test_stack_layout_mismatch_rejected(lora_base):
     """An adapter trained under a different layer layout must fail
     loudly at engine build, not silently serve base-model outputs."""
-    cfg, model, params = _base()
+    cfg, model, params = lora_base
     cfg_ns = dataclasses.replace(cfg, scan_layers=False)
     model_ns = llama.LlamaModel(cfg_ns)
     params_ns = nn.meta.unbox(
@@ -303,19 +310,19 @@ def test_stack_layout_mismatch_rejected():
         _engine(model, params, stack=stack_ns)
 
 
-def test_adapter_name_collides_with_model_id():
+def test_adapter_name_collides_with_model_id(lora_base):
     from skypilot_tpu.infer import server as server_lib
-    cfg, model, params = _base()
+    cfg, model, params = lora_base
     eng = _engine(model, params)
     with pytest.raises(ValueError, match='collides'):
         server_lib.InferenceServer(eng, model_id='sql-ft',
                                    lora_names={'sql-ft': 1})
 
 
-def test_stats_report_ttft_percentiles():
+def test_stats_report_ttft_percentiles(lora_base):
     """/stats surfaces TTFT p50/p90/p99 from the rolling window (the
     reference reads these off vLLM's metrics endpoint)."""
-    cfg, model, params = _base()
+    cfg, model, params = lora_base
     eng = _engine(model, params)
     eng.start()
     try:
@@ -333,8 +340,8 @@ def test_stats_report_ttft_percentiles():
 # OpenAI logit_bias (vLLM serves it too): device-side scatter-add on
 # the decode path, host-side add on the admission (first-token) path.
 
-def test_logit_bias_forces_and_bans_tokens():
-    cfg, model, params = _base()
+def test_logit_bias_forces_and_bans_tokens(lora_base):
+    cfg, model, params = lora_base
     eng = _engine(model, params)
     eng.start()
     try:
@@ -351,10 +358,10 @@ def test_logit_bias_forces_and_bans_tokens():
         eng.stop()
 
 
-def test_logit_bias_sampling_path():
+def test_logit_bias_sampling_path(lora_base):
     """temperature > 0 with a dominating bias still lands on the
     biased token (the bias applies before temperature/top-k)."""
-    cfg, model, params = _base()
+    cfg, model, params = lora_base
     eng = _engine(model, params)
     eng.start()
     try:
@@ -366,10 +373,10 @@ def test_logit_bias_sampling_path():
         eng.stop()
 
 
-def test_logit_bias_spec_decode_falls_back_exact():
+def test_logit_bias_spec_decode_falls_back_exact(lora_base):
     """Spec decoding falls back to the plain path for biased requests;
     outputs equal the non-spec engine's."""
-    cfg, model, params = _base()
+    cfg, model, params = lora_base
     prompt = [5, 6, 5, 6, 5, 6]
 
     def run(spec):
@@ -384,8 +391,8 @@ def test_logit_bias_spec_decode_falls_back_exact():
     assert run(2) == run(0)
 
 
-def test_logit_bias_validation():
-    cfg, model, params = _base()
+def test_logit_bias_validation(lora_base):
+    cfg, model, params = lora_base
     eng = _engine(model, params)
     with pytest.raises(ValueError, match='at most 64'):
         engine_lib.SamplingParams(

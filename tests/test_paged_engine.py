@@ -25,6 +25,22 @@ def _model_and_params(scan_layers=True):
     return model, params
 
 
+@pytest.fixture(scope='module')
+def debug_model():
+    """The debug model and its parameters, once for the module."""
+    return _model_and_params()
+
+
+@pytest.fixture(scope='module')
+def dense_2x64(debug_model):
+    """The dense reference at the shape most tests compare against
+    (2 slots, 64 tokens), once for the module: it is restartable and
+    keeps nothing of one run in the next."""
+    model, params = debug_model
+    return engine_lib.InferenceEngine(model, params, num_slots=2,
+                                      max_seq_len=64, cache_mode='dense')
+
+
 def _run(engine, prompts, max_new=8):
     engine.start()
     try:
@@ -50,8 +66,10 @@ def _prompts(vocab, lens, seed=0):
 
 
 @pytest.mark.parametrize('scan_layers', [True, False])
-def test_paged_matches_dense(scan_layers):
-    model, params = _model_and_params(scan_layers)
+def test_paged_matches_dense(scan_layers, debug_model):
+    # The unrolled layout is this test's alone, so it builds its own.
+    model, params = (debug_model if scan_layers
+                     else _model_and_params(scan_layers=False))
     vocab = model.cfg.vocab_size
     prompts = _prompts(vocab, [5, 17, 33, 9])
     dense = engine_lib.InferenceEngine(model, params, num_slots=2,
@@ -66,11 +84,11 @@ def test_paged_matches_dense(scan_layers):
     assert all(len(o) == 8 for o in out_p)
 
 
-def test_paged_holds_more_requests_at_equal_hbm():
+def test_paged_holds_more_requests_at_equal_hbm(debug_model):
     """Pool sized to the DENSE equivalent of 2 slots serves 4 concurrent
     requests (2x request depth at equal cache HBM) because reservations
     track prompt+max_new, not max_seq."""
-    model, params = _model_and_params()
+    model, params = debug_model
     vocab = model.cfg.vocab_size
     max_seq, p = 64, 16
     paged = engine_lib.InferenceEngine(
@@ -92,10 +110,10 @@ def test_paged_holds_more_requests_at_equal_hbm():
     assert _run(dense, prompts) == outs
 
 
-def test_pool_exhaustion_defers_not_drops():
+def test_pool_exhaustion_defers_not_drops(debug_model, dense_2x64):
     """A pool that fits only one request at a time still completes a
     burst of three, in order, with correct outputs."""
-    model, params = _model_and_params()
+    model, params = debug_model
     vocab = model.cfg.vocab_size
     paged = engine_lib.InferenceEngine(
         model, params, num_slots=2, max_seq_len=64,
@@ -104,25 +122,20 @@ def test_pool_exhaustion_defers_not_drops():
     prompts = _prompts(vocab, [17, 17, 17])
     outs = _run(paged, prompts)
     assert all(len(o) == 8 for o in outs)
-    dense = engine_lib.InferenceEngine(model, params, num_slots=2,
-                                       max_seq_len=64,
-                                       cache_mode='dense')
-    assert _run(dense, prompts) == outs
+    assert _run(dense_2x64, prompts) == outs
     # All pages returned to the free list after the burst.
     assert paged.pool.free_pages() == paged.pool.cfg.n_pages - 1
 
 
-def test_slot_reuse_no_corruption():
+def test_slot_reuse_no_corruption(debug_model, dense_2x64):
     """Sequential waves re-admit into released slots/pages; later waves
     must not see earlier waves' KV."""
-    model, params = _model_and_params()
+    model, params = debug_model
     vocab = model.cfg.vocab_size
     paged = engine_lib.InferenceEngine(model, params, num_slots=2,
                                        max_seq_len=64,
                                        cache_mode='paged', page_size=16)
-    dense = engine_lib.InferenceEngine(model, params, num_slots=2,
-                                       max_seq_len=64,
-                                       cache_mode='dense')
+    dense = dense_2x64
     w1 = _prompts(vocab, [9, 21], seed=1)
     w2 = _prompts(vocab, [33, 5], seed=2)
     paged.start()
@@ -178,12 +191,12 @@ def test_moe_paged_matches_dense():
                                                    max_new=6)
 
 
-def test_prefix_cache_matches_dense():
+def test_prefix_cache_matches_dense(debug_model):
     """Requests sharing a long system-prompt prefix: the paged engine
     with prefix caching must produce dense-engine outputs token-for-
     token while actually hitting the prefix cache (vLLM automatic
     prefix caching analog, llm/vllm/serve.yaml)."""
-    model, params = _model_and_params()
+    model, params = debug_model
     vocab = model.cfg.vocab_size
     rng = np.random.default_rng(7)
     system = rng.integers(1, vocab, 40).tolist()   # 2.5 pages of 16
@@ -204,10 +217,10 @@ def test_prefix_cache_matches_dense():
     assert paged.pool.prefix_stats['hit_pages'] >= 2
 
 
-def test_prefix_cache_sequential_repeat():
+def test_prefix_cache_sequential_repeat(debug_model):
     """The same prompt served twice: the second admission reuses every
     full page except the last-token page and still matches."""
-    model, params = _model_and_params()
+    model, params = debug_model
     vocab = model.cfg.vocab_size
     prompt = _prompts(vocab, [50], seed=3)[0]
     paged = engine_lib.InferenceEngine(model, params, num_slots=1,
@@ -221,8 +234,8 @@ def test_prefix_cache_sequential_repeat():
     assert paged.pool.prefix_stats['hit_pages'] - hits0 == 3
 
 
-def test_prefix_caching_off():
-    model, params = _model_and_params()
+def test_prefix_caching_off(debug_model):
+    model, params = debug_model
     vocab = model.cfg.vocab_size
     prompt = _prompts(vocab, [40], seed=4)[0]
     paged = engine_lib.InferenceEngine(model, params, num_slots=1,
@@ -238,12 +251,12 @@ def test_prefix_caching_off():
     assert _run(dense, [prompt]) == out
 
 
-def test_prefix_cache_suffix_bucket_overflow_falls_back():
+def test_prefix_cache_suffix_bucket_overflow_falls_back(debug_model):
     """A cached prefix whose suffix bucket would spill past the per-slot
     view must fall back to a full prefill (not corrupt the cache):
     max_seq 64, pages of 16 -> view span 64; prompt 50 with 16 cached
     leaves a 34-token suffix that buckets to 64 -> 16+64 > 64."""
-    model, params = _model_and_params()
+    model, params = debug_model
     vocab = model.cfg.vocab_size
     rng = np.random.default_rng(11)
     head = rng.integers(1, vocab, 16).tolist()
@@ -262,12 +275,12 @@ def test_prefix_cache_suffix_bucket_overflow_falls_back():
 
 
 @pytest.mark.parametrize('prefix_caching', [True, False])
-def test_chunked_prefill_matches(prefix_caching):
+def test_chunked_prefill_matches(debug_model, prefix_caching):
     """Chunked prefill (vLLM analog): a long prompt prefilled in
     page-aligned chunks interleaved with the engine loop must produce
     EXACTLY the non-chunked engine's outputs, long and short requests
     alike."""
-    model, params = _model_and_params()
+    model, params = debug_model
     vocab = model.cfg.vocab_size
     rng = np.random.default_rng(13)
     long_p = rng.integers(1, vocab, 100).tolist()
@@ -288,10 +301,10 @@ def test_chunked_prefill_matches(prefix_caching):
     assert chunked.perf['prefill_chunks'] >= 100 // 32 + 70 // 32
 
 
-def test_chunked_prefill_with_prefix_reuse():
+def test_chunked_prefill_with_prefix_reuse(debug_model):
     """A chunked admission sharing a published prefix starts its chunks
     AFTER the cached span and still matches."""
-    model, params = _model_and_params()
+    model, params = debug_model
     vocab = model.cfg.vocab_size
     rng = np.random.default_rng(17)
     base = rng.integers(1, vocab, 96).tolist()
@@ -308,12 +321,12 @@ def test_chunked_prefill_with_prefix_reuse():
     assert chunked.pool.prefix_stats['hit_pages'] > 0
 
 
-def test_bucket_smaller_than_page():
+def test_bucket_smaller_than_page(debug_model):
     """Prompt bucket (32) smaller than a page (64): the insert pads the
     prefill KV up to the page span. Regression: the pad length was read
     off the wrong pool axis after the page-major relayout, crashing
     every admission at the server's default page size."""
-    model, params = _model_and_params()
+    model, params = debug_model
     vocab = model.cfg.vocab_size
     prompts = _prompts(vocab, [5, 9])
     paged = engine_lib.InferenceEngine(model, params, num_slots=2,

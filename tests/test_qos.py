@@ -681,8 +681,15 @@ def test_engine_reserved_slots_gate_batch(monkeypatch):
         while time.time() < deadline and \
                 eng.stats()['active_slots'] == 0:
             time.sleep(0.02)
-        time.sleep(0.3)     # give the loop a chance to (wrongly) seat 2
-        assert eng.stats()['active_slots'] == 1
+        # Give the loop a chance to (wrongly) seat 2. With warm
+        # programs all three requests can be over inside this window,
+        # so watch it: one reading at its end may find no slot in use.
+        seated = {eng.stats()['active_slots']}
+        window_end = time.time() + 0.3
+        while time.time() < window_end:
+            seated.add(eng.stats()['active_slots'])
+            time.sleep(0.005)
+        assert max(seated) == 1, seated
         # An interactive request takes the reserved slot immediately.
         rid, q = eng.submit([7, 8, 9], engine_lib.SamplingParams(
             max_new_tokens=2, priority='interactive'))

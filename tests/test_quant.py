@@ -27,6 +27,13 @@ def _float_model(**over):
     return cfg, model, params
 
 
+@pytest.fixture(scope='module')
+def float_model():
+    """The debug config, model and float parameters, once for the
+    module."""
+    return _float_model()
+
+
 def test_quantize_roundtrip_error_bound():
     rng = np.random.default_rng(0)
     w = jnp.asarray(rng.normal(size=(3, 16, 8)), jnp.float32)  # stacked
@@ -40,11 +47,11 @@ def test_quantize_roundtrip_error_bound():
     assert (err <= bound).all()
 
 
-def test_quantized_tree_matches_quant_model_structure():
+def test_quantized_tree_matches_quant_model_structure(float_model):
     """quantize_params(float tree) must equal the quant='int8' model's
     own init structure/dtypes — the property that makes sharding-spec
     derivation and apply() work unchanged."""
-    cfg, model, params = _float_model()
+    cfg, model, params = float_model
     qparams = quant.quantize_params(params)
     qcfg = dataclasses.replace(cfg, quant='int8')
     qinit = jax.jit(llama.LlamaModel(qcfg).init)(
@@ -70,8 +77,8 @@ def test_quantized_tree_matches_quant_model_structure():
                                                       y.names)
 
 
-def test_quantized_logits_close():
-    cfg, model, params = _float_model()
+def test_quantized_logits_close(float_model):
+    cfg, model, params = float_model
     qparams = quant.quantize_params(params)
     qmodel = llama.LlamaModel(dataclasses.replace(cfg, quant='int8'))
     tokens = jnp.asarray(
@@ -266,8 +273,8 @@ def test_int4_dense_matches_dequantized_matmul():
                                atol=0.02 * np.abs(want).max())
 
 
-def test_int4_logits_close_and_tree_matches_model():
-    cfg, model, params = _float_model()
+def test_int4_logits_close_and_tree_matches_model(float_model):
+    cfg, model, params = float_model
     qparams = quant.quantize_params(params, mode='int4')
     qcfg = dataclasses.replace(cfg, quant='int4')
     qmodel = llama.LlamaModel(qcfg)

@@ -55,6 +55,7 @@ def _post(url, payload, timeout=300):
         return json.loads(resp.read())
 
 
+@pytest.mark.usefixtures('one_device_children')
 def test_served_checkpoint_matches_transformers(ckpt_dir):
     transformers = pytest.importorskip('transformers')
     torch = pytest.importorskip('torch')

@@ -29,6 +29,12 @@ def _model_and_params():
     return model, params
 
 
+@pytest.fixture(scope='module')
+def debug_model():
+    """The debug model and its parameters, once for the module."""
+    return _model_and_params()
+
+
 def _run(engine, prompts, max_new=16):
     engine.start()
     try:
@@ -54,10 +60,10 @@ def _prompts(vocab, lens, seed=0):
 
 
 @pytest.mark.parametrize('cache_mode', ['dense', 'paged'])
-def test_spec_matches_plain_greedy(cache_mode):
+def test_spec_matches_plain_greedy(debug_model, cache_mode):
     """Random prompts (low acceptance) and a periodic prompt (high
     acceptance): token-for-token equality either way."""
-    model, params = _model_and_params()
+    model, params = debug_model
     vocab = model.cfg.vocab_size
     prompts = _prompts(vocab, [7, 19, 33])
     prompts.append([5, 9, 2] * 8)          # periodic: n-gram heaven
@@ -85,11 +91,11 @@ def _draft_model_and_params(seed=1, n_layers=1):
 
 
 @pytest.mark.parametrize('cache_mode', ['dense', 'paged'])
-def test_draft_model_spec_matches_plain_greedy(cache_mode):
+def test_draft_model_spec_matches_plain_greedy(debug_model, cache_mode):
     """A DIFFERENT (smaller, independently initialized) draft model:
     outputs must still be token-for-token the plain greedy engine's —
     the acceptance gate makes draft quality a pure speed knob."""
-    model, params = _model_and_params()
+    model, params = debug_model
     draft_model, draft_params = _draft_model_and_params()
     vocab = model.cfg.vocab_size
     prompts = _prompts(vocab, [7, 19, 33])
@@ -109,12 +115,12 @@ def test_draft_model_spec_matches_plain_greedy(cache_mode):
     assert spec.perf['spec_verify_steps'] > 0
 
 
-def test_self_draft_accepts_everything():
+def test_self_draft_accepts_everything(debug_model):
     """Draft == target (params shared): every greedy draft token IS the
     target's argmax, so acceptance is exactly k on every verify step —
     the mechanism's upper bound, and a strong end-to-end check that
     draft cache positions stay aligned with the target's."""
-    model, params = _model_and_params()
+    model, params = debug_model
     k = 3
     spec = engine_lib.InferenceEngine(model, params, num_slots=2,
                                       max_seq_len=128,
@@ -134,11 +140,11 @@ def test_self_draft_accepts_everything():
         k * spec.perf['spec_verify_steps'], spec.perf
 
 
-def test_draft_model_spec_sampled_completes():
+def test_draft_model_spec_sampled_completes(debug_model):
     """Sampled requests ride the same rejection-sampling verify with a
     draft-model point mass: requests complete with valid lengths and a
     same-seed rerun is deterministic."""
-    model, params = _model_and_params()
+    model, params = debug_model
     draft_model, draft_params = _draft_model_and_params()
 
     def run_once():
@@ -167,10 +173,10 @@ def test_draft_model_spec_sampled_completes():
     assert a == b     # keyed rng: reruns are bit-identical
 
 
-def test_spec_accepts_on_looping_output():
+def test_spec_accepts_on_looping_output(debug_model):
     """Greedy decode from a random-weight model falls into short loops;
     the proposer must convert those into accepted multi-token steps."""
-    model, params = _model_and_params()
+    model, params = debug_model
     prompt = [5, 9, 2] * 8
     spec = engine_lib.InferenceEngine(model, params, num_slots=1,
                                       max_seq_len=256,
@@ -187,13 +193,13 @@ def test_spec_accepts_on_looping_output():
     assert p['spec_accept_per_step'] > 0.2, p
 
 
-def test_spec_xla_gather_fallback_matches(monkeypatch):
+def test_spec_xla_gather_fallback_matches(debug_model, monkeypatch):
     """The SKYT_SPEC_PAGED_ATTN=xla escape hatch (gather verify path)
     produces identical outputs to plain decode. The pallas MQ kernel is
     the default since the on-chip gate, so every other spec test covers
     it — this keeps the documented fallback from rotting."""
     monkeypatch.setenv('SKYT_SPEC_PAGED_ATTN', 'xla')
-    model, params = _model_and_params()
+    model, params = debug_model
     vocab = model.cfg.vocab_size
     prompts = _prompts(vocab, [7, 19], seed=6) + [[5, 9, 2] * 8]
     plain = engine_lib.InferenceEngine(model, params, num_slots=2,
@@ -207,11 +213,11 @@ def test_spec_xla_gather_fallback_matches(monkeypatch):
         _run(spec, prompts, max_new=12)
 
 
-def test_spec_with_sampling_mix_rides_spec_path():
+def test_spec_with_sampling_mix_rides_spec_path(debug_model):
     """A batch mixing greedy and temperature-sampled requests rides the
     SPEC path (rejection-sampling verify for the sampled slot, argmax
     verify for the greedy one) and finishes both."""
-    model, params = _model_and_params()
+    model, params = debug_model
     vocab = model.cfg.vocab_size
     spec = engine_lib.InferenceEngine(model, params, num_slots=2,
                                       max_seq_len=128,
@@ -238,13 +244,13 @@ def test_spec_with_sampling_mix_rides_spec_path():
         spec.stop()
 
 
-def test_spec_survives_plain_interlude():
+def test_spec_survives_plain_interlude(debug_model):
     """While a sampled request shares the batch, chunks route through
     the plain path — which must keep the device history current so
     speculation resumes with real acceptance (and identical output)
     once the batch is greedy-only again (regression: plain chunks once
     skipped the history write, silently zeroing acceptance forever)."""
-    model, params = _model_and_params()
+    model, params = debug_model
     vocab = model.cfg.vocab_size
     prompt = [5, 9, 2] * 8
     plain = engine_lib.InferenceEngine(model, params, num_slots=2,
@@ -280,10 +286,10 @@ def test_spec_survives_plain_interlude():
     assert spec.perf['spec_accepted'] > 0, spec.perf
 
 
-def test_spec_eos_and_slot_reuse():
+def test_spec_eos_and_slot_reuse(debug_model):
     """EOS mid-accepted-run releases the slot after the EOS token and a
     re-admitted request into the same slot stays correct."""
-    model, params = _model_and_params()
+    model, params = debug_model
     vocab = model.cfg.vocab_size
     prompts = _prompts(vocab, [9, 21, 13], seed=2)
     plain = engine_lib.InferenceEngine(model, params, num_slots=1,
@@ -318,10 +324,10 @@ def test_spec_eos_and_slot_reuse():
     assert run_eos(plain) == run_eos(spec)
 
 
-def test_spec_max_seq_tail():
+def test_spec_max_seq_tail(debug_model):
     """Requests running into max_seq_len: the spec path must hand the
     tail to the plain path instead of overrunning the cache."""
-    model, params = _model_and_params()
+    model, params = debug_model
     vocab = model.cfg.vocab_size
     prompt = _prompts(vocab, [40], seed=3)[0]
     plain = engine_lib.InferenceEngine(model, params, num_slots=1,
@@ -338,13 +344,13 @@ def test_spec_max_seq_tail():
     assert len(out_s[0]) < 64
 
 
-def test_spec_non_pow2_max_seq_hist_width():
+def test_spec_non_pow2_max_seq_hist_width(debug_model):
     """Regression: with a non-power-of-two max_seq_len, a long prompt's
     pow2 admission bucket can exceed the history buffer's
     max_seq_len + k + 2 width; the insert must clamp, not error out
     (an unclamped dynamic_update_slice kills the engine loop thread and
     every request hangs)."""
-    model, params = _model_and_params()
+    model, params = debug_model
     vocab = model.cfg.vocab_size
     # width = 48 + 2 + 2 = 52; n=40 buckets to 64 > 52 without the clamp
     prompt = _prompts(vocab, [40], seed=7)[0]

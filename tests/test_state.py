@@ -58,3 +58,44 @@ class TestClusterState:
         state.set_config('k', {'a': 1})
         assert state.get_config('k') == {'a': 1}
         assert state.get_config('missing', 42) == 42
+
+
+def test_readers_and_writers_share_the_connection_safely(tmp_state_dir):
+    """The module keeps one sqlite connection for the process. A serve
+    controller launches its replicas from one thread each, and all of
+    them read and write cluster rows at once: a read that does not take
+    the module's lock meets another thread's statement on the same
+    connection and raises `sqlite3.InterfaceError: bad parameter or
+    other API misuse` (seen in a controller's launch thread, which then
+    never brought its replica up)."""
+    import sys
+    import threading
+    import time
+
+    errors = []
+    stop = time.monotonic() + 1.5
+
+    def work(i):
+        name = f'c{i}'
+        try:
+            while time.monotonic() < stop and not errors:
+                state.add_or_update_cluster(
+                    name, handle={'i': i}, status=state.ClusterStatus.UP)
+                assert state.get_cluster(name)['handle'] == {'i': i}
+                assert any(c['name'] == name for c in state.get_clusters())
+        except Exception as e:  # pylint: disable=broad-except
+            errors.append(repr(e))
+
+    interval = sys.getswitchinterval()
+    sys.setswitchinterval(1e-5)
+    try:
+        threads = [threading.Thread(target=work, args=(i,))
+                   for i in range(16)]
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join(timeout=30)
+        assert not any(t.is_alive() for t in threads)
+    finally:
+        sys.setswitchinterval(interval)
+    assert not errors, errors[:3]

@@ -143,3 +143,45 @@ class TestTimeline:
         assert len(timeline._events) == 2   # no new events
         timeline.reset()
         assert not timeline._events
+
+
+def test_time_limit_hook_fails_a_hung_test_and_goes_on(tmp_path):
+    """tests/conftest.py's per-test limit: a body that sleeps past its
+    `time_limit` fails with the limit in its message, and the test
+    after it still runs. The inner pytest loads this suite's conftest
+    as a plugin, in a process of its own."""
+    import os
+    import subprocess
+    import sys
+    (tmp_path / 'test_hang.py').write_text(
+        'import time, pytest\n'
+        '@pytest.mark.time_limit(1)\n'
+        'def test_hangs(): time.sleep(60)\n'
+        'def test_after(): pass\n')
+    env = dict(os.environ, PYTHONPATH=os.path.dirname(__file__))
+    res = subprocess.run(
+        [sys.executable, '-m', 'pytest', '-p', 'conftest', '-p',
+         'no:cacheprovider', '-q', 'test_hang.py'],
+        cwd=tmp_path, env=env, capture_output=True, text=True, timeout=50)
+    assert res.returncode == 1, res.stdout + res.stderr
+    assert '1 failed, 1 passed' in res.stdout, res.stdout
+    assert ('test_hang.py::test_hangs: call exceeded its time limit of '
+            '1 s') in res.stdout, res.stdout
+
+
+def test_control_plane_imports_stay_light():
+    """An agent or a controller imports neither JAX nor the schema
+    validator nor networkx: every `skyt launch` starts several of these
+    processes, and jsonschema's format checkers alone cost 1.5 s to
+    import (skypilot_tpu/utils/schemas.py, skypilot_tpu/dag.py import
+    them where they are used)."""
+    import subprocess
+    import sys
+    code = ('import sys\n'
+            'import skypilot_tpu.runtime.agent\n'
+            'import skypilot_tpu.jobs.controller\n'
+            'heavy = {"jax", "jsonschema", "networkx"} & set(sys.modules)\n'
+            'assert not heavy, heavy\n')
+    res = subprocess.run([sys.executable, '-c', code], capture_output=True,
+                         text=True, timeout=60)
+    assert res.returncode == 0, res.stderr

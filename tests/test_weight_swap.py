@@ -7,7 +7,7 @@ metrics, and abort-keeps-old-weights under every `weights.swap` fault
 kind. Controller half: the canary -> bake -> fleet state machine with
 auto-rollback, restart resume semantics, adoption composition, and
 the weights-only spec diff routing — all against an injected swap
-transport (the real-HTTP drills live in test_chaos.py).
+transport (the real-HTTP drills live in test_chaos_*.py).
 """
 import dataclasses
 import threading
@@ -322,7 +322,7 @@ def test_admin_weights_route_contract(debug_setup, monkeypatch):
     import requests as req_lib
 
     from skypilot_tpu.infer import server as server_lib
-    from tests.test_chaos import _free_port, _run_app_bg, _wait_http
+    from chaos_helpers import _free_port, _run_app_bg, _wait_http
     reg = metrics_lib.MetricsRegistry()
     eng = _make_engine(debug_setup, reg)
     _, _, _, p1 = debug_setup
@@ -578,7 +578,7 @@ def test_admin_reshard_route_contract(debug_setup, monkeypatch):
     import requests as req_lib
 
     from skypilot_tpu.infer import server as server_lib
-    from tests.test_chaos import _free_port, _run_app_bg, _wait_http
+    from chaos_helpers import _free_port, _run_app_bg, _wait_http
     reg = metrics_lib.MetricsRegistry()
     eng = _make_engine(debug_setup, reg)
     eng.start()
@@ -842,7 +842,7 @@ def test_rollout_resume_semantics(rollout_mgr, monkeypatch):
     # fake replicas have no cluster records, so the real adoption
     # ladder would reap them before the resume logic runs (the
     # adoption x rollout COMPOSITION has its own test below and a
-    # real-process drill in test_chaos.py).
+    # real-process drill in test_chaos_*.py).
     monkeypatch.setattr(replica_managers.ReplicaManager,
                         '_reconcile_restart', lambda self: None)
 
