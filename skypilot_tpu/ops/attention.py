@@ -197,15 +197,14 @@ def _attention(q: jax.Array, k: jax.Array, v: jax.Array,
         rungs = []
         tuned = autotune.lookup_flash(q.shape, k.shape, q.dtype,
                                       causal, has_seg, window)
-        if tuned is not None and tuned != (flash_lib.DEFAULT_BLOCK_Q,
-                                           flash_lib.DEFAULT_BLOCK_K):
+        # What the shape rule gives each kernel (ops/dispatch.py).
+        plan = dispatch.flash_blocks(sq, sk, q.shape[3], q.dtype,
+                                     has_seg, window)
+        if tuned is not None and set(plan.values()) != {tuned}:
             rungs.append(('pallas_tuned', rung(*tuned)))
-        rungs.append(('pallas', rung(flash_lib.DEFAULT_BLOCK_Q,
-                                     flash_lib.DEFAULT_BLOCK_K)))
-        eff = dispatch.flash_blocks(sq, sk, flash_lib.DEFAULT_BLOCK_Q,
-                                    flash_lib.DEFAULT_BLOCK_K,
-                                    q.dtype, has_seg)
-        if eff != (sq, sk):   # else 'pallas' IS the full-block rung
+        rungs.append(('pallas', rung(None, None)))
+        if set(plan.values()) != {(sq, sk)}:
+            # else 'pallas' IS the full-block rung
             rungs.append(('pallas_full', rung(sq, sk)))
         rungs.append(('xla', xla))
         return dispatch.run_ladder('flash_attention', rungs)
@@ -244,9 +243,6 @@ def _flash_ok(q: jax.Array, k: jax.Array, has_seg: bool = False) -> bool:
             sq % 8 == 0 and sk % 8 == 0 and
             d % 64 == 0 and d <= 512):
         return False
-    from skypilot_tpu.ops import flash_attention as flash_lib
-    bq, bk = dispatch.flash_blocks(sq, sk, flash_lib.DEFAULT_BLOCK_Q,
-                                   flash_lib.DEFAULT_BLOCK_K,
-                                   q.dtype, has_seg)
-    return dispatch.flash_vmem_ok(bq, bk, d,
-                                  jnp.dtype(q.dtype).itemsize)
+    return dispatch.flash_vmem_ok(
+        dispatch.flash_blocks(sq, sk, d, q.dtype, has_seg), d,
+        jnp.dtype(q.dtype).itemsize, has_seg)

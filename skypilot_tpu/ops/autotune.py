@@ -1,9 +1,9 @@
 """Kernel block-size autotuning with a persistent on-disk cache.
 
-The flash kernels' default (256, 256) blocks are a one-size guess; the
-best block shape depends on (device generation, sequence lengths, head
-dim, dtype). This module sweeps the small legal candidate set ONCE per
-(device_kind, op, shape-bucket, dtype) key, times each candidate on the
+The flash kernels' shape rule (dispatch.flash_blocks) was tuned on one
+device at one head size; the best blocks depend on (device generation,
+sequence lengths, head dim, dtype). This module sweeps the small legal
+candidate set ONCE per (device_kind, op, shape-bucket, dtype) key, on the
 real device, and persists the winner so every later process — train
 jobs, serve replicas — starts tuned.
 
@@ -250,7 +250,7 @@ def flash_candidates(sq: int, sk: int, dtype,
     out: List[Tuple[int, int]] = []
     for wq in _FLASH_CANDIDATE_BLOCKS:
         for wk in _FLASH_CANDIDATE_BLOCKS:
-            cand = dispatch.flash_blocks(sq, sk, wq, wk, dtype, has_seg)
+            cand = dispatch.clamp_flash_blocks(sq, sk, wq, wk, dtype, has_seg)
             if cand not in out:
                 out.append(cand)
     if (sq, sk) not in out:

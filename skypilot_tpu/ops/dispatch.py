@@ -37,7 +37,7 @@ is only offered if its block specs pass the mirrored legality rule.
 import functools
 import math
 import threading
-from typing import Any, Callable, Dict, List, Tuple
+from typing import Any, Callable, Dict, List, Optional, Tuple
 
 from skypilot_tpu.utils import log_utils
 from skypilot_tpu.utils import metrics as metrics_lib
@@ -66,6 +66,9 @@ _lock = threading.Lock()
 # op -> most recently selected path (trace-time); surfaced in engine
 # /stats and flight-recorder snapshots.
 _paths: Dict[str, str] = {}
+# flash kernel -> the tile plan of the shape traced last (trace-time);
+# logged by sft after its first step.
+_flash_plans: Dict[str, Dict[str, Any]] = {}
 
 
 def sublane_multiple(dtype) -> int:
@@ -100,32 +103,124 @@ def choose_block(dim: int, want: int, multiple: int = 8) -> int:
     return dim
 
 
-def flash_blocks(sq: int, sk: int, want_q: int, want_k: int,
-                 q_dtype, has_seg: bool) -> Tuple[int, int]:
-    """Legal (block_q, block_k) for the flash kernels.
+FLASH_KERNELS = ('fwd', 'dq', 'dkv')
 
-    Segment-id blocks place the seq extent in the LANE position
-    ([b, 1, s] layout), so with packed sequences the seq blocks must
-    be 128-aligned (or full); without, the q/k blocks only need the
-    dtype's sublane alignment."""
-    mult = LANES if has_seg else sublane_multiple(q_dtype)
-    return (choose_block(sq, want_q, mult), choose_block(sk, want_k, mult))
+# The largest (block_q, block_k) the shape rule gives each flash kernel:
+# of the extents whose working set fits VMEM_BUDGET_BYTES, the fastest on
+# one v5e at head 128 in bf16, at S 512 to 8,192 (PERF.md §6, PR 27).
+# Every kernel ran faster the larger its tiles, whole-sequence tiles
+# included: a tile's fixed cost (the grid step, and per query row the
+# column statistics and the accumulator's read-modify-write) outweighs
+# the extra masked area. The forward gains from a wide block_k, dq from a
+# tall block_q; dk/dv at 1,024 x 1,024 is over the budget, and the
+# rectangles between are slower than 512 x 512.
+_FLASH_MAX_BLOCKS = {'fwd': (512, 1024), 'dq': (1024, 1024),
+                     'dkv': (512, 512)}
+_FLASH_MIN_WINDOW_BLOCK = 256
 
-
-def flash_vmem_bytes(block_q: int, block_k: int, d: int,
-                     itemsize: int) -> int:
-    """Rough per-invocation VMEM working set of the flash forward:
-    q/k/v/out blocks + f32 scratch (acc, m, l, lse) + the f32 score
-    block. The backward's is the same order of magnitude."""
-    io = (block_q * d * 2 + block_k * d * 2) * itemsize
-    scratch = (block_q * d + block_q * 2 + block_q * LANES) * 4
-    scores = block_q * block_k * 4
-    return io + scratch + scores
+# Mosaic's scoped VMEM when `vmem_limit_bytes` is not given (v5e).
+_MOSAIC_DEFAULT_VMEM_BYTES = 16 * 1024 * 1024
 
 
-def flash_vmem_ok(block_q: int, block_k: int, d: int, itemsize: int) -> bool:
-    return flash_vmem_bytes(block_q, block_k, d,
-                            itemsize) <= VMEM_BUDGET_BYTES
+def clamp_flash_blocks(sq: int, sk: int, want_q: int, want_k: int,
+                       q_dtype, has_seg: bool,
+                       kernel: str = 'fwd') -> Tuple[int, int]:
+    """Legal (block_q, block_k) nearest a request.
+
+    An extent that rides the LANE axis of some block must be
+    128-aligned (or full): with packed sequences both do (the
+    [b, 1, s] segment-id blocks), and the dk/dv kernel reads its row
+    statistics as [b, h, 1, sq] rows, so its q extent always does.
+    Otherwise the blocks only need the dtype's sublane alignment."""
+    sub = sublane_multiple(q_dtype)
+    mult_q = LANES if has_seg or kernel == 'dkv' else sub
+    mult_k = LANES if has_seg else sub
+    return (choose_block(sq, want_q, mult_q),
+            choose_block(sk, want_k, mult_k))
+
+
+def flash_blocks(sq: int, sk: int, d: int, q_dtype, has_seg: bool,
+                 window: int = 0,
+                 want: Optional[Tuple[int, int]] = None
+                 ) -> Dict[str, Tuple[int, int]]:
+    """The tile rule: kernel ('fwd', 'dq', 'dkv') -> (block_q, block_k).
+
+    With no request the extents come from the shape: the kernel's
+    tuned maximum (`_FLASH_MAX_BLOCKS`), no wider than a sliding
+    window, halved while the kernel's working set (`flash_vmem_bytes`)
+    is over the VMEM budget, then clamped to what the shape allows
+    (short, odd and decode shapes get a legal divisor or the full dim,
+    never a tile larger than the sequence). A request (`want`, from
+    the autotune cache or a caller) is clamped the same way and given
+    to all three kernels."""
+    import jax.numpy as jnp
+    itemsize = jnp.dtype(q_dtype).itemsize
+    plan = {}
+    for kernel in FLASH_KERNELS:
+        wq, wk = want or _FLASH_MAX_BLOCKS[kernel]
+        if want is None:
+            if window > 0:
+                # A tile wider than the window is mostly masked.
+                cap = max(_FLASH_MIN_WINDOW_BLOCK, window)
+                wq, wk = min(wq, cap), min(wk, cap)
+            wq, wk = min(wq, sq), min(wk, sk)
+            while (max(wq, wk) > LANES and flash_vmem_bytes(
+                    kernel, wq, wk, d, itemsize, has_seg)
+                    > VMEM_BUDGET_BYTES):
+                if wq >= wk:
+                    wq //= 2
+                else:
+                    wk //= 2
+        plan[kernel] = clamp_flash_blocks(sq, sk, wq, wk, q_dtype,
+                                          has_seg, kernel)
+    return plan
+
+
+def flash_vmem_bytes(kernel: str, block_q: int, block_k: int, d: int,
+                     itemsize: int, has_seg: bool = False) -> int:
+    """VMEM one invocation of a flash kernel holds: every streamed
+    block twice (the pipeline's double buffer), the float32 scratch
+    (a [n, 1] column pads to 128 lanes, a [1, n] row to 8 sublanes),
+    and the score-sized temporaries Mosaic keeps: two float32 and the
+    cast one in the forward (s waits for the row maximum), one and the
+    cast one in dq, two in dk/dv — read off the smallest
+    `vmem_limit_bytes` each kernel still compiles under (v5e, jaxlib
+    0.9.0: 10, 6 and 8 bytes an entry in bf16)."""
+    q_blk = block_q * d * itemsize
+    k_blk = block_k * d * itemsize
+    col = block_q * LANES * 4           # [block_q, 1] or lane-replicated
+    row = 8 * block_q * 4               # [1, block_q]
+    seg = 2 * 8 * (block_q + block_k) * 4 if has_seg else 0
+    tile = block_q * block_k
+    if kernel == 'fwd':
+        io = 2 * (2 * q_blk + 2 * k_blk + col)        # q, o; k, v; lse
+        scratch = 2 * col + block_q * d * 4           # m, l; acc
+        live = tile * (2 * 4 + itemsize)
+    elif kernel == 'dq':
+        io = 2 * (4 * q_blk + 2 * k_blk + col)        # q, dO, o, dq; lse
+        scratch = col + block_q * d * 4               # delta; dq
+        live = tile * (4 + itemsize)
+    elif kernel == 'dkv':
+        io = 2 * (2 * q_blk + 4 * k_blk + 2 * row)    # q, dO; k, v, dk, dv
+        scratch = 2 * block_k * d * 4
+        live = tile * 2 * 4
+    else:
+        raise ValueError(f'unknown flash kernel {kernel!r}')
+    return io + seg + scratch + live
+
+
+def flash_vmem_ok(plan: Dict[str, Tuple[int, int]], d: int, itemsize: int,
+                  has_seg: bool = False) -> bool:
+    """Whether each kernel of a tile plan fits the budget."""
+    return all(flash_vmem_bytes(kernel, bq, bk, d, itemsize, has_seg)
+               <= VMEM_BUDGET_BYTES for kernel, (bq, bk) in plan.items())
+
+
+def flash_vmem_limit(need: int) -> Optional[int]:
+    """`vmem_limit_bytes` for a flash kernel whose `flash_vmem_bytes`
+    is `need`: None while twice that fits Mosaic's default scoped VMEM
+    (the count cannot see what the compiler spills), else twice it."""
+    return 2 * need if 2 * need > _MOSAIC_DEFAULT_VMEM_BYTES else None
 
 
 def is_tracer(x: Any) -> bool:
@@ -152,6 +247,26 @@ def record_path(op: str, path: str) -> None:
     span = tracing.current_span()
     if span is not None:
         span.set_attribute(f'ops.path.{op}', path)
+
+
+def record_flash_plan(kernel: str, plan: Dict[str, Any]) -> None:
+    """Remember the tile plan a flash kernel was traced with (extents
+    and, per head, tiles visited, masked and skipped) and stamp it on
+    the current trace span, beside `ops.path.flash_attention`."""
+    with _lock:
+        _flash_plans[kernel] = dict(plan)
+    from skypilot_tpu.utils import tracing
+    span = tracing.current_span()
+    if span is not None:
+        span.set_attribute(
+            f'ops.flash_plan.{kernel}',
+            ' '.join(f'{k}={v}' for k, v in plan.items()))
+
+
+def flash_plan_snapshot() -> Dict[str, Dict[str, Any]]:
+    """flash kernel -> its last traced tile plan."""
+    with _lock:
+        return {k: dict(v) for k, v in _flash_plans.items()}
 
 
 def snapshot() -> Dict[str, str]:
@@ -270,6 +385,7 @@ def device_info() -> Dict[str, Any]:
 
 
 def reset_for_tests() -> None:
-    """Clear the path snapshot (unit tests)."""
+    """Clear the path and tile-plan snapshots (unit tests)."""
     with _lock:
         _paths.clear()
+        _flash_plans.clear()

@@ -5,19 +5,30 @@ Blockwise online-softmax attention (Flash-Attention-2 schedule):
 * forward: grid over (batch, q_heads, q_blocks, k_blocks) with the k axis
   innermost so the VMEM scratch accumulators (running max m, running sum
   l, output acc) persist across k iterations of one q block; also emits
-  the per-row logsumexp L for the backward. Causal masking skips
-  fully-masked k blocks via pl.when; GQA is folded into the k/v index_map
-  (head h reads kv head h // group). Segment ids (packed sequences) are
-  masked in-kernel.
+  the per-row logsumexp L for the backward. GQA is folded into the k/v
+  index_map (head h reads kv head h // group). Segment ids (packed
+  sequences) are masked in-kernel.
 * backward: two kernels, both recomputing p = exp(s - L) blockwise from
-  the saved residuals (q, k, v, L, delta = rowsum(dO*O)) — no O(S^2)
-  materialization:
+  the saved residuals (q, k, v, out, L) — no O(S^2) materialization:
     - dq kernel: same grid as forward (k innermost), accumulates
-      dq += ds @ k in VMEM scratch;
+      dq += ds @ k in VMEM scratch; delta = rowsum(dO*O) is made once
+      per q block, in the kernel, as the column it is used as;
     - dk/dv kernel: grid (batch, q_heads, k_blocks, q_blocks) with q
-      innermost, accumulates dk/dv per *query* head; the GQA group sum
-      down to kv heads happens outside the kernel (one cheap XLA
-      reduce), avoiding non-contiguous output revisits.
+      innermost, computed in the transposed orientation (s^T = k q^T,
+      [block_k, block_q]) so that no product contracts dimension 0 of
+      an operand and L and delta are read as compact [1, block_q] rows;
+      accumulates dk/dv per *query* head; the GQA group sum down to kv
+      heads happens outside the kernel (one cheap XLA reduce), avoiding
+      non-contiguous output revisits.
+
+The tile plan (docs/kernels.md): each kernel gets its own (block_q,
+block_k) from the shape (dispatch.flash_blocks). Under a causal or
+sliding-window mask a tile is *skipped* (no allowed entry: no compute,
+and the index_maps clamp the streamed block index to the nearest
+visited tile, so nothing is copied in for it), *masked* (the diagonal
+or the window's edge crosses it: iota, compare, select) or *plain*
+(wholly allowed: none of that). With segment ids every visited tile is
+masked. The plan is recorded at trace time (dispatch.record_flash_plan).
 
 Kernel conventions follow /opt/skills/guides/pallas_guide.md (block
 specs, scratch via pl.pallas_call scratch_shapes, MXU-aligned tiles).
@@ -35,12 +46,15 @@ from skypilot_tpu.utils import env
 
 NEG_INF = -1e30
 
-# Row statistics (lse, delta) are carried as [..., seq, LANES] arrays with
-# the value replicated across the 128 lanes: Mosaic requires the last two
-# dims of every block to be (8k, 128)-tileable or equal to the array dims,
-# so a (1, block_q)-shaped row block does not lower. Same layout as
-# jax.experimental.pallas.ops.tpu.flash_attention (its MIN_BLOCK_SIZE).
+# The forward writes the logsumexp as [b, hq, sq, LANES] with the value
+# replicated across the 128 lanes: it is made as a [block_q, 1] column,
+# the dq kernel reads it back as one, and Mosaic requires the last two
+# dims of every block to be (8k, 128)-tileable or equal to the array
+# dims. The dk/dv kernel wants rows and gets lane 0 as [b, hq, 1, sq].
 LANES = 128
+
+_NT = (((1,), (1,)), ((), ()))    # a @ b^T: contract the last dims
+_NN = (((1,), (0,)), ((), ()))    # a @ b
 
 
 def bwd_impl_choice() -> str:
@@ -50,46 +64,107 @@ def bwd_impl_choice() -> str:
     can never take down a training run."""
     return env.get('SKYT_FLASH_BWD', 'pallas')
 
-DEFAULT_BLOCK_Q = 256
-DEFAULT_BLOCK_K = 256
 
-
-def _block_mask(s, qi, ki, block_q, block_k, causal, window,
-                q_seg_ref, k_seg_ref):
-    """Apply causal / sliding-window / segment masking to a
-    [block_q, block_k] score block. window > 0 (Mistral, every other
-    Gemma-2 layer, Phi-3): query p also requires p - k_pos < window.
-    Returns the masked scores."""
+def _block_mask(s, q_start, k_start, causal, window, q_seg, k_seg,
+                q_axis=0):
+    """Apply causal / sliding-window / segment masking to a score
+    block whose queries run along `q_axis` (0: [block_q, block_k]; 1:
+    the dk/dv kernel's transposed [block_k, block_q]). window > 0
+    (Mistral, every other Gemma-2 layer, Phi-3): query p also requires
+    p - k_pos < window. Returns the masked scores."""
     if causal or window > 0:
-        q_pos = qi * block_q + jax.lax.broadcasted_iota(
-            jnp.int32, s.shape, 0)
-        k_pos = ki * block_k + jax.lax.broadcasted_iota(
-            jnp.int32, s.shape, 1)
+        # q_pos - k_pos of every entry.
+        rel = (jax.lax.broadcasted_iota(jnp.int32, s.shape, q_axis) -
+               jax.lax.broadcasted_iota(jnp.int32, s.shape, 1 - q_axis) +
+               (q_start - k_start))
         if causal:
-            s = jnp.where(q_pos >= k_pos, s, NEG_INF)
+            s = jnp.where(rel >= 0, s, NEG_INF)
         if window > 0:
-            s = jnp.where(q_pos - k_pos < window, s, NEG_INF)
-    if q_seg_ref is not None:
-        q_seg = q_seg_ref[0, 0]           # [block_q]
-        k_seg = k_seg_ref[0, 0]           # [block_k]
-        s = jnp.where(q_seg[:, None] == k_seg[None, :], s, NEG_INF)
+            s = jnp.where(rel < window, s, NEG_INF)
+    if q_seg is not None:
+        same = (q_seg[:, None] == k_seg[None, :] if q_axis == 0 else
+                k_seg[:, None] == q_seg[None, :])
+        s = jnp.where(same, s, NEG_INF)
     return s
 
 
-def _qk_block_overlaps(qi, ki, block_q, block_k, causal, window):
-    """Traced bool: does this (q block, k block) pair contain ANY
-    unmasked (q, k) entry under causal+window? Used to skip whole
-    blocks: above the diagonal (causal) and, with a window, entirely
-    below it."""
+def _tile_visited(qi, ki, block_q, block_k, causal, window):
+    """Does this (q block, k block) pair contain ANY unmasked (q, k)
+    entry under causal+window? The others are skipped whole: above the
+    diagonal (causal) and, with a window, entirely below it. Python
+    ints (the trace-time tile count) or traced scalars (the kernels)."""
     cond = True
     if causal:
-        cond = jnp.logical_and(cond, ki * block_k < (qi + 1) * block_q)
+        cond = cond & (ki * block_k < (qi + 1) * block_q)
     if window > 0:
         # Highest k in the block must reach the lowest q's window
         # start: (ki+1)*bk - 1 >= qi*bq - (window - 1).
-        cond = jnp.logical_and(
-            cond, (ki + 1) * block_k > qi * block_q - window + 1)
+        cond = cond & ((ki + 1) * block_k > qi * block_q - window + 1)
     return cond
+
+
+def _tile_crossed(qi, ki, block_q, block_k, causal, window):
+    """Does the pair contain ANY masked entry under causal+window: does
+    the diagonal, or the window's lower edge, cross the tile?"""
+    cond = False
+    if causal:
+        cond = cond | ((ki + 1) * block_k - 1 > qi * block_q)
+    if window > 0:
+        cond = cond | ((qi + 1) * block_q - 1 - ki * block_k >= window)
+    return cond
+
+
+def _visited_k_blocks(qi, block_q, block_k, num_k_blocks, causal, window):
+    """(first, last) k block that q block `qi` visits (traced)."""
+    lo, hi = 0, num_k_blocks - 1
+    if causal:
+        hi = jnp.minimum(hi, ((qi + 1) * block_q - 1) // block_k)
+    if window > 0:
+        lo = jnp.maximum(qi * block_q - window + 1, 0) // block_k
+    return lo, hi
+
+
+def _visited_q_blocks(ki, block_q, block_k, num_q_blocks, causal, window):
+    """(first, last) q block that k block `ki` visits (traced)."""
+    lo, hi = 0, num_q_blocks - 1
+    if causal:
+        lo = (ki * block_k) // block_q
+    if window > 0:
+        hi = jnp.minimum(hi, ((ki + 1) * block_k + window - 2) // block_q)
+    return lo, hi
+
+
+def _clamp(i, lo, hi):
+    return jnp.minimum(jnp.maximum(i, lo), hi)
+
+
+def tile_counts(sq, sk, block_q, block_k, causal, window, has_seg):
+    """Tiles of one head the plan visits, masks and skips."""
+    visited = masked = 0
+    nq, nk = sq // block_q, sk // block_k
+    for qi in range(nq):
+        for ki in range(nk):
+            if _tile_visited(qi, ki, block_q, block_k, causal, window):
+                visited += 1
+                masked += bool(has_seg or _tile_crossed(
+                    qi, ki, block_q, block_k, causal, window))
+    return {'visited': visited, 'masked': masked,
+            'skipped': nq * nk - visited}
+
+
+def _for_tile(qi, ki, block_q, block_k, causal, window, has_seg, tile):
+    """Run `tile(masked)` for the grid step's tile: not at all where it
+    is skipped, with the mask work only where a mask can bite."""
+    if not (causal or window > 0):
+        tile(has_seg)
+        return
+    visited = _tile_visited(qi, ki, block_q, block_k, causal, window)
+    if has_seg:
+        pl.when(visited)(lambda: tile(True))
+        return
+    crossed = _tile_crossed(qi, ki, block_q, block_k, causal, window)
+    pl.when(visited & crossed)(lambda: tile(True))
+    pl.when(visited & jnp.logical_not(crossed))(lambda: tile(False))
 
 
 def _fwd_kernel(*refs, scale: float, causal: bool, window: int,
@@ -100,7 +175,6 @@ def _fwd_kernel(*refs, scale: float, causal: bool, window: int,
          o_ref, lse_ref, m_scr, l_scr, acc_scr) = refs
     else:
         q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr, acc_scr = refs
-        q_seg_ref = k_seg_ref = None
     qi = pl.program_id(2)
     ki = pl.program_id(3)
 
@@ -110,36 +184,37 @@ def _fwd_kernel(*refs, scale: float, causal: bool, window: int,
         l_scr[:] = jnp.zeros_like(l_scr)
         acc_scr[:] = jnp.zeros_like(acc_scr)
 
-    def _compute():
+    def _tile(masked):
         q = q_ref[0, 0]                   # [block_q, d]
         k = k_ref[0, 0]                   # [block_k, d]
         s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32) * scale  # [bq, bk]
-        s = _block_mask(s, qi, ki, block_q, block_k, causal, window,
-                        q_seg_ref, k_seg_ref)
+            q, k, _NT, preferred_element_type=jnp.float32) * scale
+        if masked:
+            s = _block_mask(
+                s, qi * block_q, ki * block_k, causal, window,
+                q_seg_ref[0, 0] if has_seg else None,
+                k_seg_ref[0, 0] if has_seg else None)
         m_prev = m_scr[:]                 # [bq, 1]
         m_cur = jnp.max(s, axis=1, keepdims=True)
         m_new = jnp.maximum(m_prev, m_cur)
-        # Exact 0 for masked entries: a row whose FIRST visited block is
-        # fully masked has m_new == NEG_INF, and exp(NEG_INF - NEG_INF)
-        # would be 1 — with a sliding window that case is routine (rows
-        # near the end of a q block whose window starts past this k
-        # block), so guard by value rather than rely on underflow.
-        p = jnp.where(s <= NEG_INF / 2, 0.0, jnp.exp(s - m_new))
+        p = jnp.exp(s - m_new)
+        if masked:
+            # Exact 0 for masked entries: a row whose FIRST visited
+            # block is fully masked has m_new == NEG_INF, and
+            # exp(NEG_INF - NEG_INF) would be 1 — with a sliding window
+            # that case is routine (rows near the end of a q block
+            # whose window starts past this k block), so guard by value
+            # rather than rely on underflow.
+            p = jnp.where(s <= NEG_INF / 2, 0.0, p)
         alpha = jnp.exp(m_prev - m_new)   # [bq, 1]
         l_new = alpha * l_scr[:] + jnp.sum(p, axis=1, keepdims=True)
         acc_scr[:] = acc_scr[:] * alpha + jax.lax.dot_general(
-            p.astype(v_ref.dtype), v_ref[0, 0], (((1,), (0,)), ((), ())),
+            p.astype(v_ref.dtype), v_ref[0, 0], _NN,
             preferred_element_type=jnp.float32)
         m_scr[:] = m_new
         l_scr[:] = l_new
 
-    if causal or window > 0:
-        pl.when(_qk_block_overlaps(qi, ki, block_q, block_k, causal,
-                                   window))(_compute)
-    else:
-        _compute()
+    _for_tile(qi, ki, block_q, block_k, causal, window, has_seg, _tile)
 
     @pl.when(ki == num_k_blocks - 1)
     def _finalize():
@@ -156,44 +231,43 @@ def _dq_kernel(*refs, scale: float, causal: bool, window: int,
                block_q: int, block_k: int, num_k_blocks: int,
                has_seg: bool):
     if has_seg:
-        (q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-         q_seg_ref, k_seg_ref, dq_ref, dq_scr) = refs
+        (q_ref, k_ref, v_ref, do_ref, o_ref, lse_ref,
+         q_seg_ref, k_seg_ref, dq_ref, dq_scr, delta_scr) = refs
     else:
-        (q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-         dq_ref, dq_scr) = refs
-        q_seg_ref = k_seg_ref = None
+        (q_ref, k_ref, v_ref, do_ref, o_ref, lse_ref,
+         dq_ref, dq_scr, delta_scr) = refs
     qi = pl.program_id(2)
     ki = pl.program_id(3)
 
     @pl.when(ki == 0)
     def _init():
         dq_scr[:] = jnp.zeros_like(dq_scr)
+        # delta_i = sum_d dO_i * O_i, the softmax-grad row correction.
+        delta_scr[:] = jnp.sum(
+            do_ref[0, 0].astype(jnp.float32) *
+            o_ref[0, 0].astype(jnp.float32), axis=1, keepdims=True)
 
-    def _compute():
+    def _tile(masked):
         q = q_ref[0, 0]                   # [bq, d]
         k = k_ref[0, 0]                   # [bk, d]
         s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32) * scale
-        s = _block_mask(s, qi, ki, block_q, block_k, causal, window,
-                        q_seg_ref, k_seg_ref)
+            q, k, _NT, preferred_element_type=jnp.float32) * scale
+        if masked:
+            s = _block_mask(
+                s, qi * block_q, ki * block_k, causal, window,
+                q_seg_ref[0, 0] if has_seg else None,
+                k_seg_ref[0, 0] if has_seg else None)
         lse = lse_ref[0, 0][:, :1]        # [bq, 1] (lane-replicated)
         p = jnp.exp(s - lse)              # [bq, bk]
-        do = do_ref[0, 0]                 # [bq, d]
         dp = jax.lax.dot_general(
-            do, v_ref[0, 0], (((1,), (1,)), ((), ())),
+            do_ref[0, 0], v_ref[0, 0], _NT,
             preferred_element_type=jnp.float32)  # [bq, bk]
-        delta = delta_ref[0, 0][:, :1]    # [bq, 1]
-        ds = p * (dp - delta) * scale
+        ds = p * (dp - delta_scr[:]) * scale
         dq_scr[:] += jax.lax.dot_general(
-            ds.astype(k.dtype), k, (((1,), (0,)), ((), ())),
+            ds.astype(k.dtype), k, _NN,
             preferred_element_type=jnp.float32)
 
-    if causal or window > 0:
-        pl.when(_qk_block_overlaps(qi, ki, block_q, block_k, causal,
-                                   window))(_compute)
-    else:
-        _compute()
+    _for_tile(qi, ki, block_q, block_k, causal, window, has_seg, _tile)
 
     @pl.when(ki == num_k_blocks - 1)
     def _finalize():
@@ -209,7 +283,6 @@ def _dkv_kernel(*refs, scale: float, causal: bool, window: int,
     else:
         (q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
          dk_ref, dv_ref, dk_scr, dv_scr) = refs
-        q_seg_ref = k_seg_ref = None
     ki = pl.program_id(2)
     qi = pl.program_id(3)
 
@@ -218,36 +291,33 @@ def _dkv_kernel(*refs, scale: float, causal: bool, window: int,
         dk_scr[:] = jnp.zeros_like(dk_scr)
         dv_scr[:] = jnp.zeros_like(dv_scr)
 
-    def _compute():
+    def _tile(masked):
+        # Transposed orientation: every score-sized value is
+        # [block_k, block_q], the row statistics broadcast down the
+        # sublanes, and all four products are a @ b or a @ b^T.
         q = q_ref[0, 0]                   # [bq, d]
         k = k_ref[0, 0]                   # [bk, d]
-        s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32) * scale
-        s = _block_mask(s, qi, ki, block_q, block_k, causal, window,
-                        q_seg_ref, k_seg_ref)
-        lse = lse_ref[0, 0][:, :1]        # [bq, 1] (lane-replicated)
-        p = jnp.exp(s - lse)              # [bq, bk]
         do = do_ref[0, 0]                 # [bq, d]
+        st = jax.lax.dot_general(
+            k, q, _NT, preferred_element_type=jnp.float32) * scale
+        if masked:
+            st = _block_mask(
+                st, qi * block_q, ki * block_k, causal, window,
+                q_seg_ref[0, 0] if has_seg else None,
+                k_seg_ref[0, 0] if has_seg else None, q_axis=1)
+        pt = jnp.exp(st - lse_ref[0, 0])  # [bk, bq] - [1, bq]
         dv_scr[:] += jax.lax.dot_general(
-            p.astype(do.dtype), do, (((0,), (0,)), ((), ())),
+            pt.astype(do.dtype), do, _NN,
             preferred_element_type=jnp.float32)  # [bk, d]
-        dp = jax.lax.dot_general(
-            do, v_ref[0, 0], (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32)  # [bq, bk]
-        delta = delta_ref[0, 0][:, :1]    # [bq, 1]
-        ds = p * (dp - delta) * scale
+        dpt = jax.lax.dot_general(
+            v_ref[0, 0], do, _NT,
+            preferred_element_type=jnp.float32)  # [bk, bq]
+        dst = pt * (dpt - delta_ref[0, 0]) * scale
         dk_scr[:] += jax.lax.dot_general(
-            ds.astype(q.dtype), q, (((0,), (0,)), ((), ())),
+            dst.astype(q.dtype), q, _NN,
             preferred_element_type=jnp.float32)  # [bk, d]
 
-    if causal or window > 0:
-        # Same overlap predicate, evaluated from this kernel's
-        # (ki outer, qi inner) grid order.
-        pl.when(_qk_block_overlaps(qi, ki, block_q, block_k, causal,
-                                   window))(_compute)
-    else:
-        _compute()
+    _for_tile(qi, ki, block_q, block_k, causal, window, has_seg, _tile)
 
     @pl.when(qi == num_q_blocks - 1)
     def _finalize():
@@ -265,33 +335,33 @@ def flash_attention_fwd_lse(q: jax.Array, k: jax.Array, v: jax.Array,
     — wrap it in your own custom_vjp (parallel/ring_attention.py routes
     its backward through the einsum path).
     """
-    out, lse = _flash_fwd_impl(q, k, v, None, causal, DEFAULT_BLOCK_Q,
-                               DEFAULT_BLOCK_K, 0)
+    out, lse = _flash_fwd_impl(q, k, v, None, causal, None, None, 0)
     return out, lse[..., 0]
 
 
 def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array,
                     causal: bool = True,
                     segment_ids: Optional[jax.Array] = None,
-                    block_q: int = DEFAULT_BLOCK_Q,
-                    block_k: int = DEFAULT_BLOCK_K,
+                    block_q: Optional[int] = None,
+                    block_k: Optional[int] = None,
                     window: int = 0) -> jax.Array:
     """q: [B, Sq, Hq, D]; k, v: [B, Sk, Hkv, D] -> [B, Sq, Hq, D].
 
-    block_q/block_k are REQUESTS, not contracts: they are clamped
-    through the divisibility-safe selector (ops/dispatch.py) to a
-    tile-aligned divisor of the seq dims or to the full dims, so any
-    legal input shape lowers — decode shapes included. Serving/train
-    call sites should go through ops.attention's dispatch ladder,
-    which adds the conservative-Pallas and XLA fallback rungs.
+    With no block_q/block_k the tile rule (dispatch.flash_blocks)
+    gives the forward, dq and dk/dv kernels each their extents from
+    the shape. Given, they are REQUESTS, not contracts, for all three:
+    clamped through the divisibility-safe selector (ops/dispatch.py)
+    to a tile-aligned divisor of the seq dims or to the full dims, so
+    any legal input shape lowers — decode shapes included.
+    Serving/train call sites should go through ops.attention's
+    dispatch ladder, which adds the conservative-Pallas and XLA
+    fallback rungs.
 
     segment_ids: optional [B, S] int32 packed-sequence ids, masked
     in-kernel (forward and backward).
     window: sliding-window attention (> 0: query p sees k in
-    (p - window, p]). Out-of-window blocks skip their COMPUTE (the
-    same pl.when structure as the causal above-diagonal skip — a FLOP
-    saving; the grid still fetches every k/v block, so memory traffic
-    is unchanged).
+    (p - window, p]). Out-of-window tiles are skipped like the tiles
+    above the causal diagonal: no compute and no fetch.
     """
     return _flash(q, k, v, segment_ids, causal, block_q, block_k,
                   window)
@@ -304,39 +374,62 @@ def _flash(q, k, v, segment_ids, causal, block_q, block_k, window):
     return out
 
 
-def _shape_checks(q, k, block_q, block_k, has_seg=False):
-    """Shape-robust block selection (docs/kernels.md): requested
-    blocks are CLAMPED through the divisibility-safe selector — to a
+def _plan(q, k, block_q, block_k, has_seg, causal, window, kernels):
+    """Shape checks, then (tile plan, `vmem_limit_bytes`) of `kernels`
+    (docs/kernels.md): extents from the shape rule, or the requested
+    blocks CLAMPED through the divisibility-safe selector — to a
     tile-aligned divisor of the seq dim, or to the full dim (always
     legal) — so any legal input shape lowers, decode shapes like
-    (4, 32, 8, 256) included. A block pair whose
-    VMEM working set cannot fit is refused at TRACE time (a
-    ValueError the dispatch ladder catches), because the Mosaic
-    compile error it would become is not catchable."""
-    b, sq, hq, d = q.shape
-    _, sk, hkv, _ = k.shape
+    (4, 32, 8, 256) included. A block pair whose VMEM working set
+    cannot fit is refused at TRACE time (a ValueError the dispatch
+    ladder catches), because the Mosaic compile error it would become
+    is not catchable. What is traced is recorded, with its tile counts
+    (dispatch.record_flash_plan)."""
+    sq, hq, d = q.shape[1:]
+    sk, hkv = k.shape[1:3]
     if hq % hkv != 0:
         raise ValueError(
             f'q heads ({hq}) must be a multiple of kv heads ({hkv})')
-    block_q, block_k = dispatch.flash_blocks(sq, sk, block_q, block_k,
-                                             q.dtype, has_seg)
-    if not dispatch.interpret_mode() and not dispatch.flash_vmem_ok(
-            block_q, block_k, d, jnp.dtype(q.dtype).itemsize):
-        raise ValueError(
-            f'flash blocks ({block_q}, {block_k}) x d={d} exceed the '
-            f'VMEM budget ({dispatch.VMEM_BUDGET_BYTES}B) — refusing '
-            'a certain Mosaic compile failure')
-    return b, sq, sk, hq, hkv, d, block_q, block_k
+    want = None
+    if block_q is not None or block_k is not None:
+        want = (block_q or sq, block_k or sk)
+    plan = dispatch.flash_blocks(sq, sk, d, q.dtype, has_seg, window, want)
+    limits = {}
+    for kernel in kernels:
+        bq, bk = plan[kernel]
+        need = dispatch.flash_vmem_bytes(
+            kernel, bq, bk, d, jnp.dtype(q.dtype).itemsize, has_seg)
+        if not dispatch.interpret_mode() and \
+                need > dispatch.VMEM_BUDGET_BYTES:
+            raise ValueError(
+                f'flash {kernel} blocks {plan[kernel]} x d={d} need '
+                f'{need}B of VMEM, over the budget '
+                f'({dispatch.VMEM_BUDGET_BYTES}B) — refusing a certain '
+                'Mosaic compile failure')
+        limits[kernel] = dispatch.flash_vmem_limit(need)
+        dispatch.record_flash_plan(kernel, {
+            'block_q': bq, 'block_k': bk,
+            **tile_counts(sq, sk, bq, bk, causal, window, has_seg)})
+    return plan, limits
+
+
+def _params(vmem_limit):
+    return pltpu.CompilerParams(
+        dimension_semantics=('parallel', 'parallel', 'parallel',
+                             'arbitrary'),
+        vmem_limit_bytes=vmem_limit)
 
 
 def _flash_fwd_impl(q, k, v, segment_ids, causal, block_q, block_k,
                     window=0):
     has_seg = segment_ids is not None
-    b, sq, sk, hq, hkv, d, block_q, block_k = _shape_checks(
-        q, k, block_q, block_k, has_seg)
+    plan, limits = _plan(q, k, block_q, block_k, has_seg, causal, window,
+                         ('fwd',))
+    block_q, block_k = plan['fwd']
+    b, sq, hq, d = q.shape
+    sk, hkv = k.shape[1:3]
     group = hq // hkv
     nq, nk = sq // block_q, sk // block_k
-    scale = d ** -0.5
 
     # Kernel layout: [B, H, S, D] (head-major so blocks are contiguous).
     qt = q.transpose(0, 2, 1, 3)
@@ -344,18 +437,22 @@ def _flash_fwd_impl(q, k, v, segment_ids, causal, block_q, block_k,
     vt = v.transpose(0, 2, 1, 3)
 
     kernel = functools.partial(
-        _fwd_kernel, scale=scale, causal=causal, window=window,
+        _fwd_kernel, scale=d ** -0.5, causal=causal, window=window,
         block_q=block_q, block_k=block_k, num_k_blocks=nk,
         has_seg=has_seg)
 
-    in_specs = [
-        pl.BlockSpec((1, 1, block_q, d),
-                     lambda bi, hi, qi, ki: (bi, hi, qi, 0)),
-        pl.BlockSpec((1, 1, block_k, d),
-                     lambda bi, hi, qi, ki: (bi, hi // group, ki, 0)),
-        pl.BlockSpec((1, 1, block_k, d),
-                     lambda bi, hi, qi, ki: (bi, hi // group, ki, 0)),
-    ]
+    def kc(qi, ki):
+        # A skipped step names the nearest visited k block: already
+        # resident, so nothing is copied in for it.
+        return _clamp(ki, *_visited_k_blocks(qi, block_q, block_k, nk,
+                                             causal, window))
+
+    q_spec = pl.BlockSpec((1, 1, block_q, d),
+                          lambda bi, hi, qi, ki: (bi, hi, qi, 0))
+    kv_spec = pl.BlockSpec(
+        (1, 1, block_k, d),
+        lambda bi, hi, qi, ki: (bi, hi // group, kc(qi, ki), 0))
+    in_specs = [q_spec, kv_spec, kv_spec]
     operands = [qt, kt, vt]
     if has_seg:
         # [b, 1, s] so the seq extent rides the LANE axis of the block
@@ -367,7 +464,7 @@ def _flash_fwd_impl(q, k, v, segment_ids, causal, block_q, block_k,
             pl.BlockSpec((1, 1, block_q),
                          lambda bi, hi, qi, ki: (bi, 0, qi)),
             pl.BlockSpec((1, 1, block_k),
-                         lambda bi, hi, qi, ki: (bi, 0, ki)),
+                         lambda bi, hi, qi, ki: (bi, 0, kc(qi, ki))),
         ]
         operands += [seg, seg]
 
@@ -376,8 +473,7 @@ def _flash_fwd_impl(q, k, v, segment_ids, causal, block_q, block_k,
         grid=(b, hq, nq, nk),
         in_specs=in_specs,
         out_specs=[
-            pl.BlockSpec((1, 1, block_q, d),
-                         lambda bi, hi, qi, ki: (bi, hi, qi, 0)),
+            q_spec,
             pl.BlockSpec((1, 1, block_q, LANES),
                          lambda bi, hi, qi, ki: (bi, hi, qi, 0)),
         ],
@@ -390,9 +486,7 @@ def _flash_fwd_impl(q, k, v, segment_ids, causal, block_q, block_k,
             pltpu.VMEM((block_q, 1), jnp.float32),   # running sum
             pltpu.VMEM((block_q, d), jnp.float32),   # output accumulator
         ],
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=('parallel', 'parallel', 'parallel',
-                                 'arbitrary')),
+        compiler_params=_params(limits['fwd']),
         interpret=dispatch.interpret_mode(),
     )(*operands)
     return out.transpose(0, 2, 1, 3), lse
@@ -415,10 +509,11 @@ def _bwd_rule(causal, block_q, block_k, window, res, g):
             q, k, v)
         return (*vjp(g), None)
     has_seg = segment_ids is not None
-    b, sq, sk, hq, hkv, d, block_q, block_k = _shape_checks(
-        q, k, block_q, block_k, has_seg)
+    plan, limits = _plan(q, k, block_q, block_k, has_seg, causal, window,
+                         ('dq', 'dkv'))
+    b, sq, hq, d = q.shape
+    sk, hkv = k.shape[1:3]
     group = hq // hkv
-    nq, nk = sq // block_q, sk // block_k
     scale = d ** -0.5
 
     qt = q.transpose(0, 2, 1, 3)
@@ -426,103 +521,92 @@ def _bwd_rule(causal, block_q, block_k, window, res, g):
     vt = v.transpose(0, 2, 1, 3)
     dot = g.transpose(0, 2, 1, 3)         # dO, [b, hq, sq, d]
     ot = out.transpose(0, 2, 1, 3)
+    seg = segment_ids.astype(jnp.int32)[:, None, :] if has_seg else None
 
-    # delta_i = sum_d dO_i * O_i, the softmax-grad row correction,
-    # lane-replicated to the Mosaic-friendly [b, hq, sq, LANES] layout.
-    delta = (dot.astype(jnp.float32) * ot.astype(jnp.float32)).sum(-1)
-    delta = jnp.broadcast_to(delta[..., None], (b, hq, sq, LANES))
+    # dq: the forward's grid; q, dO, O and L stay for a q block's k loop.
+    bq, bk = plan['dq']
+    nq, nk = sq // bq, sk // bk
 
-    qkv_spec = lambda bi, hi, qi, ki: (bi, hi, qi, 0)  # noqa: E731
-    kv_spec = lambda bi, hi, qi, ki: (bi, hi // group, ki, 0)  # noqa: E731
-    row_spec = lambda bi, hi, qi, ki: (bi, hi, qi, 0)  # noqa: E731
+    def kc(qi, ki):
+        return _clamp(ki, *_visited_k_blocks(qi, bq, bk, nk, causal,
+                                             window))
 
-    common_in_specs = [
-        pl.BlockSpec((1, 1, block_q, d), qkv_spec),       # q
-        pl.BlockSpec((1, 1, block_k, d), kv_spec),        # k
-        pl.BlockSpec((1, 1, block_k, d), kv_spec),        # v
-        pl.BlockSpec((1, 1, block_q, d), qkv_spec),       # dO
-        pl.BlockSpec((1, 1, block_q, LANES), row_spec),   # lse
-        pl.BlockSpec((1, 1, block_q, LANES), row_spec),   # delta
-    ]
-    operands = [qt, kt, vt, dot, lse, delta]
+    q_spec = pl.BlockSpec((1, 1, bq, d),
+                          lambda bi, hi, qi, ki: (bi, hi, qi, 0))
+    kv_spec = pl.BlockSpec(
+        (1, 1, bk, d),
+        lambda bi, hi, qi, ki: (bi, hi // group, kc(qi, ki), 0))
+    in_specs = [q_spec, kv_spec, kv_spec, q_spec, q_spec,
+                pl.BlockSpec((1, 1, bq, LANES),
+                             lambda bi, hi, qi, ki: (bi, hi, qi, 0))]
+    operands = [qt, kt, vt, dot, ot, lse]
     if has_seg:
-        seg = segment_ids.astype(jnp.int32)[:, None, :]  # lane-axis seq
-        common_in_specs += [
-            pl.BlockSpec((1, 1, block_q),
-                         lambda bi, hi, qi, ki: (bi, 0, qi)),
-            pl.BlockSpec((1, 1, block_k),
-                         lambda bi, hi, qi, ki: (bi, 0, ki)),
+        in_specs += [
+            pl.BlockSpec((1, 1, bq), lambda bi, hi, qi, ki: (bi, 0, qi)),
+            pl.BlockSpec((1, 1, bk),
+                         lambda bi, hi, qi, ki: (bi, 0, kc(qi, ki))),
         ]
         operands += [seg, seg]
-
-    dq_kernel = functools.partial(
-        _dq_kernel, scale=scale, causal=causal, window=window,
-        block_q=block_q, block_k=block_k, num_k_blocks=nk,
-        has_seg=has_seg)
     dqt = pl.pallas_call(
-        dq_kernel,
+        functools.partial(
+            _dq_kernel, scale=scale, causal=causal, window=window,
+            block_q=bq, block_k=bk, num_k_blocks=nk, has_seg=has_seg),
         grid=(b, hq, nq, nk),
-        in_specs=list(common_in_specs),
-        out_specs=pl.BlockSpec((1, 1, block_q, d), qkv_spec),
+        in_specs=in_specs,
+        out_specs=q_spec,
         out_shape=jax.ShapeDtypeStruct((b, hq, sq, d), q.dtype),
-        scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=('parallel', 'parallel', 'parallel',
-                                 'arbitrary')),
+        scratch_shapes=[pltpu.VMEM((bq, d), jnp.float32),    # dq
+                        pltpu.VMEM((bq, 1), jnp.float32)],   # delta
+        compiler_params=_params(limits['dq']),
         interpret=dispatch.interpret_mode(),
     )(*operands)
 
     # dk/dv per *query* head: the kernel walks q blocks innermost for a
     # fixed k block; the kv-head (GQA group) reduction is one XLA sum.
-    def dkv_q_spec(bi, hi, ki, qi):
-        return (bi, hi, qi, 0)
+    # Its row statistics cross HBM compact, as [b, hq, 1, sq] rows.
+    bq, bk = plan['dkv']
+    nq, nk = sq // bq, sk // bk
+    lse_row = lse[..., 0][:, :, None, :]
+    delta_row = (dot.astype(jnp.float32) *
+                 ot.astype(jnp.float32)).sum(-1)[:, :, None, :]
 
-    def dkv_kv_spec(bi, hi, ki, qi):
-        return (bi, hi // group, ki, 0)
+    def qc(ki, qi):
+        return _clamp(qi, *_visited_q_blocks(ki, bq, bk, nq, causal,
+                                             window))
 
-    def dkv_row_spec(bi, hi, ki, qi):
-        return (bi, hi, qi, 0)
-
-    dkv_in_specs = [
-        pl.BlockSpec((1, 1, block_q, d), dkv_q_spec),      # q
-        pl.BlockSpec((1, 1, block_k, d), dkv_kv_spec),     # k
-        pl.BlockSpec((1, 1, block_k, d), dkv_kv_spec),     # v
-        pl.BlockSpec((1, 1, block_q, d), dkv_q_spec),      # dO
-        pl.BlockSpec((1, 1, block_q, LANES), dkv_row_spec),  # lse
-        pl.BlockSpec((1, 1, block_q, LANES), dkv_row_spec),  # delta
-    ]
+    q_spec = pl.BlockSpec((1, 1, bq, d),
+                          lambda bi, hi, ki, qi: (bi, hi, qc(ki, qi), 0))
+    kv_spec = pl.BlockSpec((1, 1, bk, d),
+                           lambda bi, hi, ki, qi: (bi, hi // group, ki, 0))
+    row_spec = pl.BlockSpec((1, 1, 1, bq),
+                            lambda bi, hi, ki, qi: (bi, hi, 0, qc(ki, qi)))
+    dkv_spec = pl.BlockSpec((1, 1, bk, d),
+                            lambda bi, hi, ki, qi: (bi, hi, ki, 0))
+    in_specs = [q_spec, kv_spec, kv_spec, q_spec, row_spec, row_spec]
+    operands = [qt, kt, vt, dot, lse_row, delta_row]
     if has_seg:
-        dkv_in_specs += [
-            pl.BlockSpec((1, 1, block_q),
-                         lambda bi, hi, ki, qi: (bi, 0, qi)),
-            pl.BlockSpec((1, 1, block_k),
-                         lambda bi, hi, ki, qi: (bi, 0, ki)),
+        in_specs += [
+            pl.BlockSpec((1, 1, bq),
+                         lambda bi, hi, ki, qi: (bi, 0, qc(ki, qi))),
+            pl.BlockSpec((1, 1, bk), lambda bi, hi, ki, qi: (bi, 0, ki)),
         ]
-
-    dkv_kernel = functools.partial(
-        _dkv_kernel, scale=scale, causal=causal, window=window,
-        block_q=block_q, block_k=block_k, num_q_blocks=nq,
-        has_seg=has_seg)
-    dk_spec = lambda bi, hi, ki, qi: (bi, hi, ki, 0)  # noqa: E731
+        operands += [seg, seg]
     dkt, dvt = pl.pallas_call(
-        dkv_kernel,
+        functools.partial(
+            _dkv_kernel, scale=scale, causal=causal, window=window,
+            block_q=bq, block_k=bk, num_q_blocks=nq, has_seg=has_seg),
         grid=(b, hq, nk, nq),
-        in_specs=dkv_in_specs,
-        out_specs=[
-            pl.BlockSpec((1, 1, block_k, d), dk_spec),
-            pl.BlockSpec((1, 1, block_k, d), dk_spec),
-        ],
+        in_specs=in_specs,
+        out_specs=[dkv_spec, dkv_spec],
         out_shape=[
             jax.ShapeDtypeStruct((b, hq, sk, d), k.dtype),
             jax.ShapeDtypeStruct((b, hq, sk, d), v.dtype),
         ],
         scratch_shapes=[
-            pltpu.VMEM((block_k, d), jnp.float32),
-            pltpu.VMEM((block_k, d), jnp.float32),
+            pltpu.VMEM((bk, d), jnp.float32),
+            pltpu.VMEM((bk, d), jnp.float32),
         ],
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=('parallel', 'parallel', 'parallel',
-                                 'arbitrary')),
+        compiler_params=_params(limits['dkv']),
         interpret=dispatch.interpret_mode(),
     )(*operands)
 

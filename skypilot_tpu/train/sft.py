@@ -443,6 +443,14 @@ def main(argv=None) -> None:
                             'interpreted' if ops_dispatch.interpret_mode()
                             else 'compiled',
                             flash_attention.bwd_impl_choice())
+                    plans = ops_dispatch.flash_plan_snapshot()
+                    if plans:
+                        # Per kernel: tile extents and, per head, tiles
+                        # visited / masked / skipped.
+                        logger.info('flash tile plan: %s', ', '.join(
+                            '{} {block_q}x{block_k} {visited}/{masked}/'
+                            '{skipped}'.format(k, **p)
+                            for k, p in plans.items()))
                 tokens_seen += args.batch * args.seq * jax.process_count()
                 if hb is not None:
                     live_state['step'] = step

@@ -64,15 +64,49 @@ class TestBlockSelection:
     def test_flash_blocks_seg_uses_lane_alignment(self):
         # Packed sequences put the seq extent on the lane axis of the
         # segment-id blocks -> 128-aligned (or full-dim) blocks only.
-        bq, bk = dispatch.flash_blocks(512, 512, 256, 256,
-                                       jnp.float32, True)
-        assert bq % 128 == 0 and bk % 128 == 0
-        bq, _ = dispatch.flash_blocks(48, 48, 32, 32, jnp.float32, True)
-        assert bq == 48   # no 128-aligned divisor -> full dim
+        for want in (None, (256, 256), (192, 192)):
+            plan = dispatch.flash_blocks(512, 512, 64, jnp.float32, True,
+                                         want=want)
+            assert sorted(plan) == sorted(dispatch.FLASH_KERNELS)
+            for bq, bk in plan.values():
+                assert bq % 128 == 0 and bk % 128 == 0
+        plan = dispatch.flash_blocks(48, 48, 64, jnp.float32, True,
+                                     want=(32, 32))
+        for bq, _ in plan.values():
+            assert bq == 48   # no 128-aligned divisor -> full dim
+        # The dk/dv kernel reads its row statistics as [1, block_q]
+        # rows: its q extent rides the lane axis with or without
+        # segment ids; the other kernels keep the sublane-aligned 160.
+        plan = dispatch.flash_blocks(320, 320, 64, jnp.bfloat16, False,
+                                     want=(256, 256))
+        assert plan['fwd'] == (160, 160) and plan['dkv'] == (320, 160)
 
     def test_vmem_guard_refuses_impossible_blocks(self):
-        assert dispatch.flash_vmem_ok(256, 256, 128, 2)
-        assert not dispatch.flash_vmem_ok(8192, 8192, 256, 4)
+        every = dispatch.FLASH_KERNELS
+        assert dispatch.flash_vmem_ok(dict.fromkeys(every, (256, 256)),
+                                      128, 2)
+        assert not dispatch.flash_vmem_ok(
+            dict.fromkeys(every, (8192, 8192)), 256, 4)
+
+    def test_rule_plan_fits_vmem_budget_at_training_shape(self):
+        """What the rule gives (2048, 2048, 128, bf16) is larger than
+        the old 256 x 256 and fits VMEM_BUDGET_BYTES by the per-kernel
+        count; past Mosaic's default scoped VMEM a limit is passed."""
+        plan = dispatch.flash_blocks(2048, 2048, 128, jnp.bfloat16, False)
+        for kernel, (bq, bk) in plan.items():
+            assert bq > 256 and bk > 256, (kernel, bq, bk)
+            need = dispatch.flash_vmem_bytes(kernel, bq, bk, 128, 2)
+            assert need <= dispatch.VMEM_BUDGET_BYTES, (kernel, need)
+            limit = dispatch.flash_vmem_limit(need)
+            assert limit is None or limit >= 2 * need
+        # A score tile the budget cannot hold is halved, not offered.
+        wide = dispatch.flash_blocks(65536, 65536, 512, jnp.float32, False)
+        for kernel, (bq, bk) in wide.items():
+            assert dispatch.flash_vmem_bytes(
+                kernel, bq, bk, 512, 4) <= dispatch.VMEM_BUDGET_BYTES
+        assert dispatch.flash_vmem_limit(9 * 1024 * 1024) == \
+            18 * 1024 * 1024
+        assert dispatch.flash_vmem_limit(8 * 1024 * 1024) is None
 
 
 # ------------------------------------- shape grid over the public entry
@@ -107,11 +141,11 @@ class TestShapeGrid:
         assert jnp.max(jnp.abs(out - ref)) < 2e-5
         # The grid shape must also be statically LEGAL on the Pallas
         # rung it took (the part interpreter mode cannot prove).
-        bq, bk = dispatch.flash_blocks(sq, sk, flash_lib.DEFAULT_BLOCK_Q,
-                                       flash_lib.DEFAULT_BLOCK_K,
-                                       q.dtype, False)
-        assert dispatch.block_dim_ok(bq, sq, 8)
-        assert dispatch.block_dim_ok(bk, sk, 8)
+        for bq, bk in dispatch.flash_blocks(sq, sk, d, q.dtype,
+                                            False).values():
+            assert dispatch.block_dim_ok(bq, sq, 8)
+            assert dispatch.block_dim_ok(bk, sk, 8)
+            assert bq <= sq and bk <= sk
 
     def test_bench_r02_shape_lowers_via_flash_impl(self):
         """The headline regression: (4, 32, 8, 256) decode-shaped
@@ -144,6 +178,129 @@ class TestShapeGrid:
         assert jnp.max(jnp.abs(out - ref)) < 2e-5
 
 
+# ------------------------------------------------------- the tile plan
+def _seg_ids(b, s):
+    """Batch rows packed differently: 2 and 4 documents, uneven."""
+    cuts = [(s // 2,), (s // 8, s // 2, s - s // 8)]
+    return jnp.stack([jnp.searchsorted(jnp.array(cuts[i % 2]),
+                                       jnp.arange(s), side='right')
+                      for i in range(b)]).astype(jnp.int32)
+
+
+# (id, b, sq, sk, hq, hkv, causal, segmented, window, request): every
+# distinct plan the rule can give, and requests that cut it finer.
+TILE_PLAN_CASES = [
+    ('causal256-g1', 1, 256, 256, 2, 2, True, False, 0, None),
+    ('causal512-g2', 1, 512, 512, 2, 1, True, False, 0, None),
+    ('causal1024-g4', 1, 1024, 1024, 4, 1, True, False, 0, None),
+    ('causal2048-g2', 1, 2048, 2048, 2, 1, True, False, 0, None),
+    ('causal300-odd', 1, 300, 300, 2, 2, True, False, 0, None),
+    ('causal320-dkv-full-q', 1, 320, 320, 2, 1, True, False, 0,
+     (256, 256)),
+    ('segments-b2', 2, 512, 512, 4, 2, True, True, 0, None),
+    ('window300', 1, 1024, 1024, 2, 2, True, False, 300, None),
+    ('window300-noncausal', 1, 512, 512, 2, 2, False, False, 300, None),
+    ('noncausal', 1, 512, 512, 2, 1, False, False, 0, None),
+    ('cross-lengths', 1, 128, 512, 2, 2, False, False, 0, None),
+    ('request-128x256', 1, 512, 512, 2, 2, True, False, 0, (128, 256)),
+    ('request-256x128-window', 1, 512, 512, 2, 1, True, False, 200,
+     (256, 128)),
+    ('request-128x128-segments', 2, 256, 256, 2, 2, True, True, 0,
+     (128, 128)),
+]
+
+
+class TestTilePlan:
+
+    @pytest.mark.parametrize('case', TILE_PLAN_CASES,
+                             ids=[c[0] for c in TILE_PLAN_CASES])
+    def test_forward_and_gradients_match_reference(self, case):
+        """Forward and dq, dk, dv against mha_reference for each plan:
+        skipped tiles (and their clamped fetches), masked and plain
+        bodies, the transposed dk/dv kernel and its row statistics."""
+        _, b, sq, sk, hq, hkv, causal, segmented, window, want = case
+        q, k, v = _qkv(b, sq, sk, hq, hkv, 64, seed=sq + hq)
+        w = jax.random.normal(jax.random.PRNGKey(1), q.shape)
+        seg = _seg_ids(b, sq) if segmented else None
+        bq, bk = want or (None, None)
+
+        def flash(q_, k_, v_):
+            return flash_lib.flash_attention(
+                q_, k_, v_, causal=causal, segment_ids=seg,
+                window=window, block_q=bq, block_k=bk)
+
+        def reference(q_, k_, v_):
+            return attention_ops.mha_reference(
+                q_, k_, v_, causal=causal, segment_ids=seg, window=window)
+
+        dispatch.reset_for_tests()
+        out, vjp = jax.vjp(flash, q, k, v)
+        ref, ref_vjp = jax.vjp(reference, q, k, v)
+        assert jnp.max(jnp.abs(out - ref)) < 2e-5
+        for name, got, exp in zip(('dq', 'dk', 'dv'), vjp(w), ref_vjp(w)):
+            assert jnp.max(jnp.abs(got - exp)) < 1e-4, name
+        # What was recorded at trace time is what the mask gives: a
+        # tile is visited iff some entry of it is allowed, and needs
+        # mask work iff some entry of it is not.
+        q_pos = jnp.arange(sq)[:, None]
+        k_pos = jnp.arange(sk)[None, :]
+        allowed = jnp.ones((sq, sk), bool)
+        if causal:
+            allowed &= q_pos >= k_pos
+        if window > 0:
+            allowed &= q_pos - k_pos < window
+        plans = dispatch.flash_plan_snapshot()
+        assert sorted(plans) == sorted(dispatch.FLASH_KERNELS)
+        rule = dispatch.flash_blocks(sq, sk, 64, q.dtype, segmented,
+                                     window, want)
+        for kernel, plan in plans.items():
+            tq, tk = plan['block_q'], plan['block_k']
+            assert (tq, tk) == rule[kernel]
+            tiles = allowed.reshape(sq // tq, tq, sk // tk, tk)
+            some = tiles.any(axis=(1, 3))
+            every = tiles.all(axis=(1, 3))
+            assert plan['visited'] == int(some.sum()), kernel
+            assert plan['skipped'] == int((~some).sum()), kernel
+            assert plan['masked'] == int(
+                (some if segmented else some & ~every).sum()), kernel
+
+    def test_training_shape_counts(self):
+        """The numbers PERF.md quotes for sft-2k, per head."""
+        assert flash_lib.tile_counts(2048, 2048, 256, 256, True, 0,
+                                     False) == {
+            'visited': 36, 'masked': 8, 'skipped': 28}
+        assert flash_lib.tile_counts(2048, 2048, 512, 512, True, 0,
+                                     False) == {
+            'visited': 10, 'masked': 4, 'skipped': 6}
+
+    def test_fwd_lse_shape_and_values(self):
+        """Ring attention's entry: [B, Hq, Sq] float32 logsumexp of the
+        scaled, masked scores, whatever layout the kernel writes."""
+        q, k, v = _qkv(2, 512, 512, 4, 2, 64, seed=41)
+        out, lse = flash_lib.flash_attention_fwd_lse(q, k, v, causal=True)
+        assert out.shape == q.shape
+        assert lse.shape == (2, 4, 512) and lse.dtype == jnp.float32
+        logits = jnp.einsum('bqhd,bkhd->bhqk', q,
+                            jnp.repeat(k, 2, axis=2)) * 64 ** -0.5
+        mask = jnp.arange(512)[:, None] >= jnp.arange(512)[None, :]
+        want = jax.nn.logsumexp(jnp.where(mask, logits, -jnp.inf), axis=-1)
+        assert jnp.max(jnp.abs(lse - want)) < 2e-5
+        ref = attention_ops.mha_reference(q, k, v, causal=True)
+        assert jnp.max(jnp.abs(out - ref)) < 2e-5
+
+    def test_plan_lands_on_span_and_snapshot(self, monkeypatch):
+        from skypilot_tpu.utils import tracing
+        monkeypatch.setenv('SKYT_TRACE', '1')
+        monkeypatch.setenv('SKYT_TRACE_SAMPLE', '1')
+        dispatch.reset_for_tests()
+        q, k, v = _qkv(1, 512, 512, 1, 1, 64, seed=43)
+        with tracing.Tracer('test').start_span('trace-flash') as span:
+            flash_lib.flash_attention(q, k, v, block_q=128, block_k=256)
+        assert span.attributes['ops.flash_plan.fwd'] == (
+            'block_q=128 block_k=256 visited=6 masked=4 skipped=2')
+        assert dispatch.flash_plan_snapshot()['fwd']['visited'] == 6
+
+
 # --------------------------------------------------- the fallback ladder
 class TestLadder:
 
@@ -170,12 +327,12 @@ class TestLadder:
 
     def test_where_filter_targets_one_rung(self):
         """where=path:pallas kills only the default-block rung; the
-        conservative full-array rung (present because 512 > the 256
-        default block) must pick it up — partial degradation, not a
-        collapse to XLA."""
+        conservative full-array rung (present because at 2,048 the
+        tile rule gives blocks smaller than the sequence) must pick it
+        up — partial degradation, not a collapse to XLA."""
         dispatch.reset_for_tests()
         faults.configure('ops.lowering=error,where=path:pallas')
-        q, k, v = _qkv(1, 512, 512, 1, 1, 64, seed=13)
+        q, k, v = _qkv(1, 2048, 2048, 1, 1, 64, seed=13)
         out = attention_ops.attention(q, k, v, impl='flash')
         ref = attention_ops.mha_reference(q, k, v)
         assert jnp.max(jnp.abs(out - ref)) < 2e-5

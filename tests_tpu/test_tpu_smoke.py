@@ -114,6 +114,37 @@ class TestFlashKernelLowers:
         assert bool(jnp.isfinite(out.astype(jnp.float32)).all())
 
 
+    def test_bwd_with_segment_ids(self):
+        """Packed rows through all three kernels at the training shape:
+        every visited tile takes the masked body, and the dk/dv kernel
+        reads its segment ids and row statistics as lane-axis rows."""
+        from skypilot_tpu.ops.attention import mha_reference
+        from skypilot_tpu.ops.flash_attention import flash_attention
+
+        b, s, hq, hkv, d = 2, 2048, 4, 2, 128
+        q = _rand(0, (b, s, hq, d))
+        k = _rand(1, (b, s, hkv, d))
+        v = _rand(2, (b, s, hkv, d))
+        seg = jnp.stack([jnp.arange(s) // 768, jnp.arange(s) // 300]
+                        ).astype(jnp.int32)
+
+        w = _rand(3, (b, s, hq, d)).astype(jnp.float32)
+
+        def loss(fn):
+            return lambda q, k, v: (fn(
+                q, k, v, causal=True, segment_ids=seg).astype(
+                jnp.float32) * w).sum()
+        grads = jax.jit(jax.grad(loss(flash_attention),
+                                 argnums=(0, 1, 2)))(q, k, v)
+        grefs = jax.jit(jax.grad(loss(mha_reference),
+                                 argnums=(0, 1, 2)))(q, k, v)
+        for name, g, gr in zip(('dq', 'dk', 'dv'), grads, grefs):
+            # Cotangents of order 1, so the comparison bites: bf16
+            # operands against the reference's, in norm.
+            g, gr = np.asarray(g, np.float32), np.asarray(gr, np.float32)
+            assert np.linalg.norm(g - gr) <= 2e-2 * np.linalg.norm(gr), name
+
+
 class TestDispatchShapeGridLowers:
     """The never-crash contract ON-CHIP: every adversarial shape in
     the CPU grid (tests/test_ops_dispatch.py) must lower through the
