@@ -107,7 +107,7 @@ def child_env(platform: str, run_dir: str) -> dict:
     """The child's environment: the platform named explicitly (the
     ambient value here is `cpu`), and an empty home so that nothing a
     child compiles can depend on a file an earlier run left under ~
-    (autotune entries, link profiles). The compile cache is placed by
+    (link profiles). The compile cache is placed by
     the entry points themselves: JAX_COMPILATION_CACHE_DIR if the
     caller set it, else <checkout>/.jax_cache."""
     env = dict(os.environ)

@@ -25,7 +25,7 @@ for a in "$@"; do
   if [ "$a" = "--full" ]; then FULL=1; else ARGS+=("$a"); fi
 done
 
-python -m compileall -q skypilot_tpu tests tests_tpu tools bench.py __graft_entry__.py
+python -m compileall -q skypilot_tpu tests tests_tpu tools __graft_entry__.py
 python tools/lint.py "${ARGS[@]}"
 if [ "$FULL" = "1" ]; then
   python -m pytest tests/ -q

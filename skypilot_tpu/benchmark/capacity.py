@@ -43,7 +43,7 @@ class Trial:
 
 @dataclasses.dataclass(frozen=True)
 class CapacityResult:
-    """Structured capacity artifact (bench.py archives it verbatim)."""
+    """Structured capacity artifact."""
     max_sustained_qps: float      # highest PASSING rate observed
     slo_attainment: float         # attainment measured at that rate
     target: float

@@ -19,10 +19,9 @@ the pure decision function; every input is measured elsewhere:
 
 The verdict weighs per-request benefit (interference seconds saved
 across the request's decoded tokens) against per-request cost (KV
-page transfer seconds). Served in ``GET /fleet/interference`` and
-logged by ``bench.py``'s interference phase. Dependency-free and
-deterministic — the advisor goldens in tests/test_tickstats.py pin it
-against hand-computed inputs.
+page transfer seconds). Served in ``GET /fleet/interference``.
+Dependency-free and deterministic — the advisor goldens in
+tests/test_tickstats.py pin it against hand-computed inputs.
 """
 from typing import Any, Dict, Optional
 

@@ -1009,11 +1009,10 @@ class InferenceEngine:
         self._tickstats = tickstats_lib.from_env(reg)
         self._tick_t0: Optional[float] = None
         self._tick_perf0 = (0, 0, 0)
-        # Prefill isolation (the disaggregation counterfactual measured
-        # by bench.py's interference phase): admit prefill only from
-        # ticks with no active decode slots, so decode chunks never
-        # share a tick with prefill. A schedule property fixed at
-        # construction, like the recorder itself.
+        # Prefill isolation (the disaggregation counterfactual): admit
+        # prefill only from ticks with no active decode slots, so decode
+        # chunks never share a tick with prefill. A schedule property
+        # fixed at construction, like the recorder itself.
         self._isolate_prefill = env.get_bool(
             'SKYT_TICKSTATS_ISOLATE', False)
         # KV bytes per decoded token at the active kv dtype (PR 12
@@ -3601,8 +3600,8 @@ class InferenceEngine:
             # Isolated-prefill schedule (SKYT_TICKSTATS_ISOLATE): hold
             # admission while any decode slot is live, so prefill only
             # runs from all-idle ticks and decode chunks never share a
-            # tick with it — the measured counterfactual bench.py's
-            # interference phase compares the mixed schedule against.
+            # tick with it — the counterfactual the mixed schedule is
+            # compared against (tests/test_tickstats.py).
             hold_admission = swap_draining or (
                 self._isolate_prefill and
                 any(s is not None for s in self._slots))
@@ -3816,8 +3815,7 @@ class InferenceEngine:
         (_put_many) — replacing the per-token Python loop + per-token
         queue lock that dominated steady-state host time at large
         chunk x slots. perf['host_finish_s'] accumulates the post-pull
-        host time (cutoff math + delivery), the numerator of bench.py's
-        host_overhead micro-bench."""
+        host time (cutoff math + delivery)."""
         kind, toks_dev, lps_dev, counts_dev, entries, chunk = pending
         toks_np = self._pull(toks_dev)        # sync point
         counts_np = self._pull(counts_dev) if counts_dev is not None \

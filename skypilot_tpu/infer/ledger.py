@@ -30,8 +30,7 @@ base id or a loaded adapter name. The metric-cardinality analysis
 pass enforces this discipline for every labeled family.
 
 Gated by SKYT_CAPACITY_LEDGER (default on — the per-chunk cost is a
-dict update and two counter incs, bounded by the ≤1% steady-decode
-overhead acceptance in bench.py).
+dict update and two counter incs).
 """
 import threading
 from typing import Dict, Optional, Tuple

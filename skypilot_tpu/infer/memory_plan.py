@@ -83,7 +83,7 @@ def kv_bytes_per_token(cfg, kv_dtype: str = 'auto') -> int:
 def kv_pages_ratio(cfg, kv_dtype: str = 'int8') -> float:
     """Pages a fixed HBM budget holds at `kv_dtype` relative to the
     float pool — the concurrent-users-per-chip multiplier the
-    quantized KV cache buys (bench.py 'kv+ragged bench')."""
+    quantized KV cache buys."""
     return kv_bytes_per_token(cfg, 'auto') / \
         kv_bytes_per_token(cfg, kv_dtype)
 

@@ -85,43 +85,6 @@ def mha_reference(q: jax.Array, k: jax.Array, v: jax.Array,
     return out.reshape(b, sq, hq, d)
 
 
-def attention(q: jax.Array, k: jax.Array, v: jax.Array,
-              causal: bool = True,
-              segment_ids: Optional[jax.Array] = None,
-              impl: str = 'auto',
-              window: int = 0,
-              window_active=None,
-              logit_softcap: float = 0.0,
-              softmax_scale: Optional[float] = None) -> jax.Array:
-    """Public entry: eager autotune hook + the jit'd dispatch ladder.
-
-    When SKYT_AUTOTUNE=1 and the inputs are CONCRETE (not tracers —
-    i.e. this call is at setup/bench time, not inside a model trace),
-    a flash block-size sweep runs first for this shape if the autotune
-    cache has no entry; the jit'd ladder below then reads the winner.
-    One env check when disabled."""
-    flash_unsupported = (logit_softcap > 0.0 or
-                         softmax_scale is not None or
-                         (window > 0 and window_active is not None))
-    if impl in ('auto', 'flash') and not flash_unsupported:
-        from skypilot_tpu.ops import autotune
-        # Gate the sweep on the SAME impl resolution the ladder uses:
-        # sweeping a shape whose dispatch resolves to the XLA path
-        # would burn minutes populating a cache entry nothing reads.
-        if (autotune.enabled() and not dispatch.is_tracer(q) and
-                _resolve_impl(q, k, impl, window, window_active,
-                              flash_unsupported,
-                              segment_ids is not None) == 'flash'):
-            autotune.maybe_sweep_flash(q, k, v, causal=causal,
-                                       segment_ids=segment_ids,
-                                       window=window)
-    return _attention(q, k, v, causal=causal, segment_ids=segment_ids,
-                      impl=impl, window=window,
-                      window_active=window_active,
-                      logit_softcap=logit_softcap,
-                      softmax_scale=softmax_scale)
-
-
 @functools.partial(jax.jit, static_argnames=('causal', 'impl', 'window',
                                              'logit_softcap',
                                              'softmax_scale'))
@@ -134,9 +97,9 @@ def _attention(q: jax.Array, k: jax.Array, v: jax.Array,
                logit_softcap: float = 0.0,
                softmax_scale: Optional[float] = None) -> jax.Array:
     """Dispatch: 'auto' prefers the Pallas flash kernel on TPU when
-    shapes allow, else the XLA reference — and every Pallas choice now
-    runs through the fallback ladder (ops/dispatch.py): tuned-Pallas →
-    default-Pallas → conservative full-array-block Pallas → XLA
+    shapes allow (`_resolve_impl`), else the XLA reference. The flash
+    choice runs through the fallback ladder (ops/dispatch.py): Pallas
+    at the tiles the shape rule gives (`dispatch.flash_blocks`) → XLA
     reference, with the selected path recorded in
     skyt_ops_kernel_path_total{op,path} and on the current trace span.
     Soft-capped/rescaled attention (Gemma-2) always takes the XLA path
@@ -173,9 +136,7 @@ def _attention(q: jax.Array, k: jax.Array, v: jax.Array,
                         else 'a traced window gate (window_active)')
             raise ValueError(
                 f'flash attention does not support {offender}')
-        from skypilot_tpu.ops import autotune
         from skypilot_tpu.ops import flash_attention as flash_lib
-        sq, sk = q.shape[1], k.shape[1]
         has_seg = segment_ids is not None
 
         # Each device runs the kernel on its own batch rows and heads
@@ -186,28 +147,16 @@ def _attention(q: jax.Array, k: jax.Array, v: jax.Array,
             ((('act_batch', None),) if has_seg else ())
         operands = (q, k, v) + ((segment_ids,) if has_seg else ())
 
-        def rung(bq, bk):
-            def kernel(q, k, v, seg=None):
-                return flash_lib.flash_attention(
-                    q, k, v, causal=causal, segment_ids=seg,
-                    block_q=bq, block_k=bk, window=window)
-            return lambda: sharding_lib.per_shard(
-                kernel, in_axes, q_axes)(*operands)
+        def kernel(q, k, v, seg=None):
+            # No blocks asked for: the shape rule plans the tiles.
+            return flash_lib.flash_attention(
+                q, k, v, causal=causal, segment_ids=seg, window=window)
 
-        rungs = []
-        tuned = autotune.lookup_flash(q.shape, k.shape, q.dtype,
-                                      causal, has_seg, window)
-        # What the shape rule gives each kernel (ops/dispatch.py).
-        plan = dispatch.flash_blocks(sq, sk, q.shape[3], q.dtype,
-                                     has_seg, window)
-        if tuned is not None and set(plan.values()) != {tuned}:
-            rungs.append(('pallas_tuned', rung(*tuned)))
-        rungs.append(('pallas', rung(None, None)))
-        if set(plan.values()) != {(sq, sk)}:
-            # else 'pallas' IS the full-block rung
-            rungs.append(('pallas_full', rung(sq, sk)))
-        rungs.append(('xla', xla))
-        return dispatch.run_ladder('flash_attention', rungs)
+        def pallas():
+            return sharding_lib.per_shard(kernel, in_axes, q_axes)(*operands)
+
+        return dispatch.run_ladder('flash_attention',
+                                   [('pallas', pallas), ('xla', xla)])
     # 'xla_native': XLA is the CORRECT path for this op (softcap /
     # scale / traced window / auto-resolved shape), not ladder
     # degradation — keep it distinguishable from the 'xla' floor so
@@ -218,8 +167,8 @@ def _attention(q: jax.Array, k: jax.Array, v: jax.Array,
 
 def _resolve_impl(q, k, impl: str, window: int, window_active,
                   flash_unsupported: bool, has_seg: bool) -> str:
-    """The 'auto' gate, shared by the eager autotune hook and the
-    jit'd ladder so both agree on whether flash is in play."""
+    """The 'auto' gate: flash or XLA, from the shape and the features
+    asked for."""
     if impl != 'auto':
         return impl
     window_flash = (window > 0 and window_active is None and
@@ -246,3 +195,9 @@ def _flash_ok(q: jax.Array, k: jax.Array, has_seg: bool = False) -> bool:
     return dispatch.flash_vmem_ok(
         dispatch.flash_blocks(sq, sk, d, q.dtype, has_seg), d,
         jnp.dtype(q.dtype).itemsize, has_seg)
+
+
+# The public name is the jitted function. It keeps the name `_attention`:
+# the compiled kernels are named after it (`_attention.N
+# [tpu_custom_call]` in a device trace), and trace readers match that.
+attention = _attention

@@ -16,12 +16,11 @@ Two pieces:
   fall back to the full array dim (always legal by the "equal" arm of
   the Mosaic rule). Kernels built this way are statically legal.
 
-* **A fallback ladder** (``run_ladder``): tuned-Pallas →
-  conservative-Pallas (full-array blocks) → pure-XLA reference,
-  selected at TRACE time. Each non-final rung carries the
-  ``ops.lowering`` fault point, so ``SKYT_FAULTS=ops.lowering=error``
-  forces ladder descent — the whole subsystem is chaos-testable on
-  CPU. The chosen path is recorded in
+* **A fallback ladder** (``run_ladder``): Pallas at the blocks the
+  shape rule gives → pure-XLA reference, selected at TRACE time. Each
+  non-final rung carries the ``ops.lowering`` fault point, so
+  ``SKYT_FAULTS=ops.lowering=error`` forces ladder descent — the whole
+  subsystem is chaos-testable on CPU. The chosen path is recorded in
   ``skyt_ops_kernel_path_total{op,path}`` and as an attribute on the
   current trace span, so silent degradation is VISIBLE in the
   metrics/tracing plane (docs/kernels.md).
@@ -35,13 +34,11 @@ that is exactly why rung selection is static-validation-first: a rung
 is only offered if its block specs pass the mirrored legality rule.
 """
 import functools
-import math
 import threading
 from typing import Any, Callable, Dict, List, Optional, Tuple
 
 from skypilot_tpu.utils import log_utils
 from skypilot_tpu.utils import metrics as metrics_lib
-from skypilot_tpu.utils import env
 
 logger = log_utils.init_logger(__name__)
 
@@ -55,12 +52,11 @@ _SUBLANE_BY_ITEMSIZE = {4: 8, 2: 16, 1: 32}
 
 # A Pallas rung whose VMEM working set exceeds this is not offered:
 # a compile-time OOM inside Mosaic is as fatal as an illegal block
-# (and as invisible to a trace-time try/except). v5e has 16MB less
-# scratch overheads.
-VMEM_BUDGET_BYTES = int(
-    env.get('SKYT_OPS_VMEM_BUDGET', str(12 * 1024 * 1024)))
-
-_ENV_FORCE = 'SKYT_OPS_FORCE_PATH'
+# (and as invisible to a trace-time try/except). A constant of the tile
+# rule, not a setting: the per-kernel byte counts of `flash_vmem_bytes`
+# were fitted to it on one v5e (16 MiB of scoped VMEM less what the
+# count cannot see; PERF.md §6, PR 27).
+VMEM_BUDGET_BYTES = 12 * 1024 * 1024
 
 _lock = threading.Lock()
 # op -> most recently selected path (trace-time); surfaced in engine
@@ -152,9 +148,9 @@ def flash_blocks(sq: int, sk: int, d: int, q_dtype, has_seg: bool,
     window, halved while the kernel's working set (`flash_vmem_bytes`)
     is over the VMEM budget, then clamped to what the shape allows
     (short, odd and decode shapes get a legal divisor or the full dim,
-    never a tile larger than the sequence). A request (`want`, from
-    the autotune cache or a caller) is clamped the same way and given
-    to all three kernels."""
+    never a tile larger than the sequence). A request (`want`: the
+    tests' `flash_attention(block_q=, block_k=)`) is clamped the same
+    way and given to all three kernels."""
     import jax.numpy as jnp
     itemsize = jnp.dtype(q_dtype).itemsize
     plan = {}
@@ -223,13 +219,6 @@ def flash_vmem_limit(need: int) -> Optional[int]:
     is `need`: None while twice that fits Mosaic's default scoped VMEM
     (the count cannot see what the compiler spills), else twice it."""
     return 2 * need if 2 * need > _MOSAIC_DEFAULT_VMEM_BYTES else None
-
-
-def is_tracer(x: Any) -> bool:
-    """True when x is a jax tracer (inside jit/grad tracing) — i.e.
-    its VALUES are not available, only shape/dtype."""
-    import jax
-    return isinstance(x, jax.core.Tracer)
 
 
 def _counter() -> 'metrics_lib.Counter':
@@ -307,22 +296,9 @@ def run_ladder(op: str,
     time descends the ladder with a warning. The FINAL rung is the
     correctness floor (pure XLA): it is not fault-injected and its
     errors propagate — there is nothing further to fall back to.
-
-    SKYT_OPS_FORCE_PATH=<name> keeps only that rung plus the final
-    one (debug escape hatch; an unknown name is ignored loudly).
     """
     if not rungs:
         raise ValueError(f'ops.{op}: empty dispatch ladder')
-    forced = env.get(_ENV_FORCE, '')
-    if forced and len(rungs) > 1:
-        kept = [r for r in rungs if r[0] == forced]
-        if kept:
-            if rungs[-1][0] != forced:
-                kept.append(rungs[-1])
-            rungs = kept
-        elif forced != rungs[-1][0]:
-            logger.warning('%s=%r matches no rung of ops.%s (have %s)',
-                           _ENV_FORCE, forced, op, [r[0] for r in rungs])
     from skypilot_tpu.utils import faults
     last = len(rungs) - 1
     for i, (path, thunk) in enumerate(rungs):
@@ -344,14 +320,6 @@ def run_ladder(op: str,
     raise AssertionError('unreachable')
 
 
-def shape_bucket(n: int) -> int:
-    """Round a dim up to the next power of two (autotune cache keys
-    bucket shapes so one sweep covers the whole padded-bucket family)."""
-    if n <= 1:
-        return 1
-    return 1 << math.ceil(math.log2(n))
-
-
 def interpret_mode() -> bool:
     """Whether Pallas kernels run interpreted (any backend but the
     TPU) instead of compiled through Mosaic. The one place that
@@ -359,12 +327,6 @@ def interpret_mode() -> bool:
     an error here, never a quiet "interpret"."""
     import jax
     return jax.default_backend() != 'tpu'
-
-
-def device_kind() -> str:
-    """Device kind for autotune cache keys ('TPU v5 lite', 'cpu', ...)."""
-    import jax
-    return jax.devices()[0].device_kind
 
 
 @functools.lru_cache(maxsize=None)

@@ -42,7 +42,6 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from skypilot_tpu.ops import dispatch
-from skypilot_tpu.utils import env
 
 NEG_INF = -1e30
 
@@ -55,14 +54,6 @@ LANES = 128
 
 _NT = (((1,), (1,)), ((), ()))    # a @ b^T: contract the last dims
 _NN = (((1,), (0,)), ((), ()))    # a @ b
-
-
-def bwd_impl_choice() -> str:
-    """'pallas' (default) or 'xla' — SKYT_FLASH_BWD overrides. The XLA
-    path recomputes reference attention under custom_vjp (the round-1
-    behavior); the escape hatch exists so a pathological kernel compile
-    can never take down a training run."""
-    return env.get('SKYT_FLASH_BWD', 'pallas')
 
 
 def _block_mask(s, q_start, k_start, causal, window, q_seg, k_seg,
@@ -354,8 +345,7 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array,
     to a tile-aligned divisor of the seq dims or to the full dims, so
     any legal input shape lowers — decode shapes included.
     Serving/train call sites should go through ops.attention's
-    dispatch ladder, which adds the conservative-Pallas and XLA
-    fallback rungs.
+    dispatch ladder, which adds the XLA fallback rung.
 
     segment_ids: optional [B, S] int32 packed-sequence ids, masked
     in-kernel (forward and backward).
@@ -500,14 +490,6 @@ def _fwd_rule(q, k, v, segment_ids, causal, block_q, block_k, window):
 
 def _bwd_rule(causal, block_q, block_k, window, res, g):
     q, k, v, segment_ids, out, lse = res
-    if bwd_impl_choice() == 'xla':
-        from skypilot_tpu.ops import attention as attention_ops
-        _, vjp = jax.vjp(
-            lambda q_, k_, v_: attention_ops.mha_reference(
-                q_, k_, v_, causal=causal, segment_ids=segment_ids,
-                window=window),
-            q, k, v)
-        return (*vjp(g), None)
     has_seg = segment_ids is not None
     plan, limits = _plan(q, k, block_q, block_k, has_seg, causal, window,
                          ('dq', 'dkv'))

@@ -29,25 +29,22 @@ a ``lax.scan`` over adapters with the same mask-and-accumulate math
 alpha/rank scale is applied OUTSIDE the kernels, as the floor's final
 multiply, so every rung shares that op byte-for-byte. Ladder
 selection, fault injection (``ops.lowering``), and path accounting
-ride ops/dispatch.py; block sizes are swept through the generic
-``autotune.sweep`` helper.
+ride ops/dispatch.py; the token/seq block is the largest legal
+divisor of the dim up to ``_DEFAULT_BLOCK``.
 """
 import functools
-from typing import Tuple
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from skypilot_tpu.ops import autotune
 from skypilot_tpu.ops import dispatch
 from skypilot_tpu.parallel import mesh as mesh_lib
 
 OP = 'lora_grouped'
 
-# Candidate token/seq block extents, pruned per shape by legality.
-_CANDIDATE_BLOCKS = (128, 256, 512)
+# Token/seq block extent asked for, clamped per shape by legality.
 _DEFAULT_BLOCK = 256
 
 
@@ -183,71 +180,6 @@ def _xla_grouped(x, a, b, lora_ids, lora_scale) -> jax.Array:
     return acc * lora_scale[..., None].astype(dtype)
 
 
-# ------------------------------------------------------------ autotune
-def _tune_key(mode: str, tokens: int, din: int, r: int, dout: int,
-              n: int, dtype) -> str:
-    bucket = (f'{mode}.t{dispatch.shape_bucket(tokens)}.i{din}.r{r}'
-              f'.o{dout}.n{dispatch.shape_bucket(n)}')
-    return (f'{dispatch.device_kind()}|{OP}|{bucket}'
-            f'|{jnp.dtype(dtype).name}')
-
-
-def _block_candidates(dim: int, dtype) -> Tuple[int, ...]:
-    mult = dispatch.sublane_multiple(dtype)
-    out = []
-    for want in _CANDIDATE_BLOCKS:
-        cand = dispatch.choose_block(dim, want, mult)
-        if cand not in out:
-            out.append(cand)
-    if dim not in out:
-        out.append(dim)
-    return tuple(out)
-
-
-def _tuned_block(mode: str, dim: int, tokens: int, din: int, r: int,
-                 dout: int, n: int, dtype) -> int:
-    """Trace-time cache read: tuned block extent, else the clamped
-    default. Shapes are concrete even on tracers."""
-    entry = autotune.get_cache().get(
-        _tune_key(mode, tokens, din, r, dout, n, dtype))
-    if entry:
-        try:
-            blk = int(entry['block'])
-            if dispatch.block_dim_ok(blk, dim,
-                                     dispatch.sublane_multiple(dtype)):
-                return blk
-        except (KeyError, TypeError, ValueError):
-            pass   # stale/hand-edited entry: behave as a miss
-    return dispatch.choose_block(dim, _DEFAULT_BLOCK,
-                                 dispatch.sublane_multiple(dtype))
-
-
-def maybe_sweep_lora(x, a, b, lora_ids, lora_scale) -> None:
-    """Sweep block extents for this shape if enabled, concrete, and
-    not already cached (autotune.sweep semantics: cache-hit skip,
-    failures skipped, all-fail negative-cached)."""
-    if not autotune.enabled() or dispatch.is_tracer(x):
-        return
-    bsz, seq, din = x.shape
-    n, _, r = a.shape
-    dout = b.shape[-1]
-    per_token = lora_ids.ndim == 2
-    mode = 'tok' if per_token else 'seq'
-    dim = bsz * seq if per_token else seq
-    tokens = bsz * seq
-    key = _tune_key(mode, tokens, din, r, dout, n, x.dtype)
-
-    def run(cand):
-        if per_token:
-            out = _pallas_grouped(x, a, b, lora_ids, lora_scale, cand)
-        else:
-            out = _pallas_gather(x, a, b, lora_ids, lora_scale, cand)
-        out.block_until_ready()
-
-    autotune.sweep(OP, key, _block_candidates(dim, x.dtype), run,
-                   lambda cand: {'block': cand})
-
-
 # ------------------------------------------------------------ dispatch
 def _vmem_bytes(block: int, din: int, r: int, dout: int,
                 itemsize: int) -> int:
@@ -265,14 +197,16 @@ def grouped_lora_delta(x, a, b, lora_ids, lora_scale) -> jax.Array:
     [B, S] (per-token, ragged mixed packs) int adapter ids;
     lora_scale: alpha/rank per id, same shape as lora_ids. Returns the
     [B, S, out] delta in x's dtype."""
-    maybe_sweep_lora(x, a, b, lora_ids, lora_scale)
     bsz, seq, din = x.shape
-    n, _, r = a.shape
+    r = a.shape[-1]
     dout = b.shape[-1]
     per_token = lora_ids.ndim == 2
     itemsize = jnp.dtype(x.dtype).itemsize
     mult = dispatch.sublane_multiple(x.dtype)
-    tokens = bsz * seq
+    # Per-token ids flatten the tokens; per-sequence ids block the seq.
+    dim = bsz * seq if per_token else seq
+    kernel, floor = ((_pallas_grouped, _xla_grouped) if per_token else
+                     (_pallas_gather, _xla_gather))
 
     # Mosaic kernels cannot be partitioned by GSPMD, and the
     # projections these deltas join are sharded on their in or out
@@ -281,29 +215,16 @@ def grouped_lora_delta(x, a, b, lora_ids, lora_scale) -> jax.Array:
     # the required path ('xla_native'), not a descent.
     mesh = mesh_lib.current_mesh()
     if mesh is not None and mesh.size > 1:
-        floor = _xla_grouped if per_token else _xla_gather
         return dispatch.run_ladder(OP, [('xla_native', functools.partial(
             floor, x, a, b, lora_ids, lora_scale))])
 
+    blk = dispatch.choose_block(dim, _DEFAULT_BLOCK, mult)
     rungs = []
-    if per_token:
-        blk = _tuned_block('tok', tokens, tokens, din, r, dout, n,
-                           x.dtype)
-        if dispatch.block_dim_ok(blk, tokens, mult) and \
-                _vmem_bytes(blk, din, r, dout, itemsize) <= \
-                dispatch.VMEM_BUDGET_BYTES:
-            rungs.append(('pallas', functools.partial(
-                _pallas_grouped, x, a, b, lora_ids, lora_scale, blk)))
-        rungs.append(('xla', functools.partial(
-            _xla_grouped, x, a, b, lora_ids, lora_scale)))
-    else:
-        blk = _tuned_block('seq', seq, tokens, din, r, dout, n,
-                           x.dtype)
-        if dispatch.block_dim_ok(blk, seq, mult) and \
-                _vmem_bytes(blk, din, r, dout, itemsize) <= \
-                dispatch.VMEM_BUDGET_BYTES:
-            rungs.append(('pallas', functools.partial(
-                _pallas_gather, x, a, b, lora_ids, lora_scale, blk)))
-        rungs.append(('xla', functools.partial(
-            _xla_gather, x, a, b, lora_ids, lora_scale)))
+    if dispatch.block_dim_ok(blk, dim, mult) and \
+            _vmem_bytes(blk, din, r, dout, itemsize) <= \
+            dispatch.VMEM_BUDGET_BYTES:
+        rungs.append(('pallas', functools.partial(
+            kernel, x, a, b, lora_ids, lora_scale, blk)))
+    rungs.append(('xla', functools.partial(
+        floor, x, a, b, lora_ids, lora_scale)))
     return dispatch.run_ladder(OP, rungs)

@@ -4,10 +4,9 @@ The collectives benchmark (parallel/collectives.py) prints prose; this
 module turns the same sweep into a *profile* the rest of the system can
 consume: per (op, mesh axis, payload bucket, link class ici|dcn)
 bandwidth/latency entries, classified via ``device.slice_index`` (or an
-explicit ``dcn_axes`` hint on emulated CPU "slices"), persisted with
-the PR 6 autotune-cache discipline — atomic tmp+rename writes, a
-corrupt/foreign/unreadable cache degrades to a cold start, never a
-crash — under ``SKYT_COMMS_CACHE`` (default
+explicit ``dcn_axes`` hint on emulated CPU "slices"), persisted —
+atomic tmp+rename writes, a corrupt/foreign/unreadable cache degrades
+to a cold start, never a crash — under ``SKYT_COMMS_CACHE`` (default
 ``~/.cache/skypilot_tpu/comms_profile.json``).
 
 Consumers (docs/observability.md "Comms plane"):
@@ -82,9 +81,9 @@ def payload_sweep_mb() -> List[float]:
 
 
 class CommsProfileCache:
-    """Thread-safe persistent key -> dict cache with the autotune
-    discipline: atomic writes, corrupt/foreign/unreadable file == cold
-    start (never a crash), unwritable path == in-memory only."""
+    """Thread-safe persistent key -> dict cache: atomic writes,
+    corrupt/foreign/unreadable file == cold start (never a crash),
+    unwritable path == in-memory only."""
 
     def __init__(self, path: str) -> None:
         self.path = path
@@ -105,8 +104,8 @@ class CommsProfileCache:
                 entries = {k: v for k, v in data['entries'].items()
                            if isinstance(v, dict)}
             else:
-                # A foreign file (e.g. an autotune cache pointed at by
-                # a mis-set SKYT_COMMS_CACHE) must not be adopted as a
+                # A foreign file (another JSON cache pointed at by a
+                # mis-set SKYT_COMMS_CACHE) must not be adopted as a
                 # comms profile OR destroyed silently — cold start and
                 # say why; the next put() overwrites it.
                 logger.warning(
@@ -571,7 +570,7 @@ def placement_for(key: str, n_slices: int,
                   profile: Optional[Dict[str, Any]] = None,
                   path: Optional[str] = None) -> List[int]:
     """Cached advisor decision for one (topology, spec) key — computed
-    once per PROFILE, persisted like an autotune winner. The cached
+    once per PROFILE and persisted. The cached
     entry carries the fingerprint of the profile it was scored
     against: a new probe (or an explicitly passed profile) with
     different measurements recomputes and overwrites; an unusable

@@ -178,9 +178,8 @@ def build_hybrid_mesh(ici: MeshSpec, dcn: MeshSpec,
         cheapest ring permutation under the measured comms profile's
         per-pair costs (``profile`` argument, else the cached probe
         for this topology — parallel/comms_profile.py). The winner is
-        cached per (topology, spec) like an autotune entry. Without
-        any profile the permutation is the identity, i.e. exactly the
-        row-major mesh.
+        cached per (topology, spec). Without any profile the
+        permutation is the identity, i.e. exactly the row-major mesh.
     """
     if devices is None:
         devices = jax.devices()

@@ -430,13 +430,13 @@ def main(argv=None) -> None:
                     # un-lowerable shape) is visible in the job log.
                     paths = ops_dispatch.snapshot()
                     if paths:
-                        from skypilot_tpu.ops import flash_attention
+                        # The flash backward is the Pallas kernels,
+                        # always; the words stay for the log's readers.
                         logger.info(
                             'kernel dispatch paths: %s (pallas %s, '
-                            'flash backward %s)', paths,
+                            'flash backward pallas)', paths,
                             'interpreted' if ops_dispatch.interpret_mode()
-                            else 'compiled',
-                            flash_attention.bwd_impl_choice())
+                            else 'compiled')
                     plans = ops_dispatch.flash_plan_snapshot()
                     if plans:
                         # Per kernel: tile extents and, per head, tiles

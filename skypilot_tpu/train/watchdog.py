@@ -84,8 +84,8 @@ def stall_budget(ewma_step_s: Optional[float]) -> float:
 
 def classify_stall(record: Optional[Dict[str, Any]], now: float
                    ) -> Dict[str, Any]:
-    """One-rank stall check (shared by the sentinel and bench.py's
-    hang evidence): {stalled, stalled_for_s, budget_s, phase}."""
+    """One-rank stall check: {stalled, stalled_for_s, budget_s,
+    phase}."""
     if not record or record.get('phase') != 'step':
         return {'stalled': False, 'stalled_for_s': 0.0,
                 'budget_s': stall_budget(None),

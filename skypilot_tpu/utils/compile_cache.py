@@ -1,7 +1,7 @@
 """Where XLA's persistent compile cache lives, and what it did.
 
 Every entry point (infer/server.py, train/sft.py, infer/multihost.py,
-parallel/collectives.py, bench.py, tests/conftest.py) calls
+parallel/collectives.py, tests/conftest.py) calls
 :func:`configure` first thing. The rule is one sentence: where
 ``JAX_COMPILATION_CACHE_DIR`` is set, JAX reads it and this module sets
 nothing; where it is not, the cache is ``<checkout>/.jax_cache``. The

@@ -138,18 +138,6 @@ _var('SKYT_COS_ENDPOINT', 'str',
      'IBM COS S3-compatible endpoint.')
 
 # ------------------------------------------------------------- kernels
-_var('SKYT_OPS_VMEM_BUDGET', 'int', 12 * 1024 * 1024,
-     'VMEM budget (bytes) the dispatch ladder sizes block specs to.')
-_var('SKYT_OPS_FORCE_PATH', 'str', '',
-     'Debug: keep only this dispatch-ladder rung (plus the XLA floor).')
-_var('SKYT_AUTOTUNE', 'bool', False,
-     'Enable kernel block-size autotune sweeps (reads always on).')
-_var('SKYT_AUTOTUNE_CACHE', 'str', '~/.skypilot_tpu/autotune.json',
-     'Persistent autotune cache path.')
-_var('SKYT_AUTOTUNE_REPEATS', 'int', 3,
-     'Timing repeats per autotune candidate.')
-_var('SKYT_FLASH_BWD', 'str', 'pallas',
-     'Flash-attention backward impl: "pallas" or "xla".')
 _var('SKYT_WINDOW_FLASH', 'str', 'off',
      'Opt-in Pallas path for windowed attention ("on" enables).')
 _var('SKYT_PAGED_ATTN', 'str', 'pallas',
@@ -208,7 +196,7 @@ _var('SKYT_COMMS_PROBE_TIMEOUT_S', 'float', 120.0,
 _var('SKYT_COMMS_CACHE', 'str',
      '~/.cache/skypilot_tpu/comms_profile.json',
      'Persistent comms-profile cache path (probe results + placement '
-     'advisor winners; autotune-cache write discipline).')
+     'advisor winners; atomic tmp+rename writes).')
 _var('SKYT_COMMS_PLACEMENT', 'str', 'rowmajor',
      'DCN slice placement of build_hybrid_mesh: "rowmajor" (today\'s '
      'layout) or "measured" (cheapest ring permutation under the '
@@ -518,8 +506,7 @@ _var('SKYT_TICKSTATS_EWMA', 'float', 0.2,
      'active-slot bucket.')
 _var('SKYT_TICKSTATS_ISOLATE', 'bool', False,
      'Isolated-prefill schedule: admit prefill only from ticks with '
-     'no active decode slots (the disaggregation counterfactual '
-     'bench.py\'s interference phase measures against).')
+     'no active decode slots (the disaggregation counterfactual).')
 _var('SKYT_INTERFERENCE_MIN_SAMPLES', 'int', 4,
      'Pure-decode ticks a slot bucket needs before its baseline is '
      'warm enough to attribute mixed-tick excess.')

@@ -1,9 +1,9 @@
 """Dependency-free Prometheus-style metrics registry.
 
 The observability plane the reference never had: the client-side Chrome
-timeline (utils/timeline.py) sees client ops only, and bench.py sees a
-benchmark run only. This registry is the third plane — continuously
-updated Counters/Gauges/Histograms that the inference server exposes at
+timeline (utils/timeline.py) sees client ops only, and the benchmark
+(chipbench/) sees its own runs only. This registry is the third plane —
+continuously updated Counters/Gauges/Histograms that the server exposes at
 GET /metrics (text exposition format 0.0.4, scrapeable by any
 Prometheus), the dashboard renders as a panel, and tests read directly.
 

@@ -42,9 +42,9 @@ from skypilot_tpu.utils import env
 
 logger = log_utils.init_logger(__name__)
 
-# bf16 peak FLOPs per chip (the MFU denominator). Previously a private
-# table in bench.py; owned here so the bench, the trainer's published
-# MFU, and the fleet cost report divide by the same numbers.
+# bf16 peak FLOPs per chip (the MFU denominator): owned here so the
+# trainer's published MFU and the fleet cost report divide by the same
+# numbers.
 PEAK_FLOPS = {
     'TPU v5 lite': 197e12,
     'TPU v5': 459e12,

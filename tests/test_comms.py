@@ -80,7 +80,7 @@ class TestProfileCache:
         assert cache.get('profile|k') == {'entries': {}}
 
     def test_foreign_layout_cold_start(self, comms_cache):
-        # An autotune-format file (valid JSON, no comms kind stamp)
+        # Another cache's file (valid JSON, no comms kind stamp)
         # must read as cold, not as a profile.
         with open(comms_cache, 'w', encoding='utf-8') as f:
             json.dump({'version': 1,

@@ -9,6 +9,7 @@ import dataclasses
 import io
 import logging
 import os
+import re
 
 import flax.linen as nn
 import jax
@@ -269,6 +270,12 @@ def test_sft_trains_the_preset_and_reports_the_routing_counters():
     text = buf.getvalue()
     assert 'moe routing plan: experts=16 held=0-15 k=4 tokens=64 ' \
         'buffer_rows=256' in text
+    # The benchmark's driver reads this line with this expression
+    # (chipbench/train_cell.py) and demands 'pallas' of the last word.
+    paths = re.search(r'kernel dispatch paths: (\{.*?\}) '
+                      r'\(pallas (\w+), flash backward (\w+)\)', text)
+    assert paths.group(2, 3) == ('interpreted', 'pallas')
+    assert "'moe_experts': 'ragged_dot'" in paths.group(1)
     # 2 expert layers x 64 tokens x 4 slots, all held, none dropped
     assert text.count('moe_pairs=512/512') == 3
     assert text.count('moe_dropped=0') == 3

@@ -49,37 +49,52 @@ class TestGroupedOp:
     def teardown_method(self):
         faults.reset()
 
-    def test_per_sequence_byte_identical_to_floor(self):
-        """[B] ids (decode / uniform prefill): the ladder output —
-        whatever rung it takes — must be byte-identical to the XLA
-        gather-einsum floor on CPU (the per-id scale is applied
-        outside every rung, so the final multiply is shared)."""
-        rng = np.random.default_rng(1)
-        x = jnp.asarray(rng.normal(0, 1, (3, 16, 32)), jnp.float32)
-        a, b = _rand_stack(3, 32, 4, 24, seed=2)
-        ids = jnp.asarray([2, 0, 1], jnp.int32)
-        scale = jnp.asarray([2.0, 0.0, 0.5], jnp.float32)
-        out = lora_ops.grouped_lora_delta(x, a, b, ids, scale)
-        ref = lora_ops._xla_gather(x, a, b, ids, scale)  # pylint: disable=protected-access
-        np.testing.assert_array_equal(np.asarray(out),
-                                      np.asarray(ref))
-        # Id 0 rows are exactly zero: the zeros adapter contributes
-        # nothing, bit-for-bit.
-        assert not np.any(np.asarray(out)[1])
-
-    def test_per_token_byte_identical_to_floor(self):
-        """[B, S] ids (ragged packs mixing adapters in one row): the
-        accumulate-over-adapters kernel must match the floor's scan
-        byte-for-byte."""
-        rng = np.random.default_rng(3)
-        x = jnp.asarray(rng.normal(0, 1, (2, 24, 32)), jnp.float32)
-        a, b = _rand_stack(4, 32, 4, 16, seed=4)
-        ids = jnp.asarray(rng.integers(0, 4, (2, 24)), jnp.int32)
+    @pytest.mark.parametrize('per_token,bsz,seq,n_blocks', [
+        (False, 3, 16, 1), (False, 2, 512, 2), (True, 2, 24, 1),
+        (True, 2, 320, 4)],
+        ids=['seq-one-block', 'seq-two-blocks', 'tok-one-block',
+             'tok-four-blocks'])
+    def test_pallas_rung_byte_identical_to_floor(self, monkeypatch,
+                                                 per_token, bsz, seq,
+                                                 n_blocks):
+        """[B] ids (decode / uniform prefill) and [B, S] ids (ragged
+        packs mixing adapters in one row): the Pallas rung, at the one
+        block the shape gives, must be byte-identical to the XLA floor
+        on CPU (gather-einsum, or the scan over adapters; the per-id
+        scale is applied outside every rung, so the final multiply is
+        shared)."""
+        # pylint: disable=protected-access
+        rng = np.random.default_rng(1 + seq)
+        x = jnp.asarray(rng.normal(0, 1, (bsz, seq, 32)), jnp.float32)
+        a, b = _rand_stack(4, 32, 4, 24, seed=2)
+        if per_token:
+            ids = jnp.asarray(rng.integers(0, 4, (bsz, seq)), jnp.int32)
+            name, floor = '_pallas_grouped', lora_ops._xla_grouped
+        else:
+            ids = jnp.asarray([2, 0, 1][:bsz], jnp.int32)
+            name, floor = '_pallas_gather', lora_ops._xla_gather
         scale = jnp.where(ids == 0, 0.0, 1.5).astype(jnp.float32)
+        blocks = []
+        kernel = getattr(lora_ops, name)
+
+        def spy(x, a, b, ids, scale, block):
+            blocks.append(block)
+            return kernel(x, a, b, ids, scale, block)
+
+        monkeypatch.setattr(lora_ops, name, spy)
+        dispatch.reset_for_tests()
         out = lora_ops.grouped_lora_delta(x, a, b, ids, scale)
-        ref = lora_ops._xla_grouped(x, a, b, ids, scale)  # pylint: disable=protected-access
-        np.testing.assert_array_equal(np.asarray(out),
-                                      np.asarray(ref))
+        assert dispatch.snapshot()[lora_ops.OP] == 'pallas'
+        dim = bsz * seq if per_token else seq
+        assert blocks == [dispatch.choose_block(
+            dim, lora_ops._DEFAULT_BLOCK, dispatch.sublane_multiple(x.dtype))]
+        assert dim // blocks[0] == n_blocks
+        np.testing.assert_array_equal(np.asarray(out), np.asarray(
+            floor(x, a, b, ids, scale)))
+        if not per_token:
+            # Id 0 rows are exactly zero: the zeros adapter contributes
+            # nothing, bit-for-bit.
+            assert not np.any(np.asarray(out)[1])
 
     def test_mixed_rank_padded_stack(self):
         """Mixed-rank adapters live in one stack padded to the max

@@ -1,13 +1,12 @@
-"""Kernel dispatch layer: shape-robust block selection, the
-tuned-Pallas -> conservative-Pallas -> XLA fallback ladder, the
-autotune cache, and the ops.lowering chaos path (docs/kernels.md).
+"""Kernel dispatch layer: shape-robust block selection, the tile rule,
+the Pallas -> XLA fallback ladder, and the ops.lowering chaos path
+(docs/kernels.md).
 
 Everything here runs on CPU: the Pallas rungs execute in interpreter
 mode (kernel logic exercised; the Mosaic legality rules are checked
 against the STATIC mirror in ops/dispatch.py, the same predicate jax's
 _check_block_mappings enforces on-chip).
 """
-import json
 import os
 import subprocess
 import sys
@@ -20,9 +19,9 @@ import pytest
 import requests
 
 from skypilot_tpu.ops import attention as attention_ops
-from skypilot_tpu.ops import autotune
 from skypilot_tpu.ops import dispatch
 from skypilot_tpu.ops import flash_attention as flash_lib
+from skypilot_tpu.utils import env
 from skypilot_tpu.utils import faults
 from skypilot_tpu.utils import metrics as metrics_lib
 
@@ -88,17 +87,26 @@ class TestBlockSelection:
         assert not dispatch.flash_vmem_ok(
             dict.fromkeys(every, (8192, 8192)), 256, 4)
 
-    def test_rule_plan_fits_vmem_budget_at_training_shape(self):
-        """What the rule gives (2048, 2048, 128, bf16) is larger than
-        the old 256 x 256 and fits VMEM_BUDGET_BYTES by the per-kernel
-        count; past Mosaic's default scoped VMEM a limit is passed."""
-        plan = dispatch.flash_blocks(2048, 2048, 128, jnp.bfloat16, False)
-        for kernel, (bq, bk) in plan.items():
-            assert bq > 256 and bk > 256, (kernel, bq, bk)
-            need = dispatch.flash_vmem_bytes(kernel, bq, bk, 128, 2)
-            assert need <= dispatch.VMEM_BUDGET_BYTES, (kernel, need)
-            limit = dispatch.flash_vmem_limit(need)
-            assert limit is None or limit >= 2 * need
+    @pytest.mark.parametrize('seq,head', [(2048, 128), (8192, 64)],
+                             ids=['sft-2k', 'sft-moe-8k'])
+    def test_rule_plan_fits_vmem_budget_at_training_shape(self, seq, head):
+        """What the rule gives the two training cells (bf16; sft-2k: S
+        2,048 at head 128; sft-moe-8k: S 8,192 at head 64) is the plan
+        PERF.md section 5 records, and fits VMEM_BUDGET_BYTES by the
+        per-kernel count; past Mosaic's default scoped VMEM a limit is
+        passed."""
+        assert dispatch.VMEM_BUDGET_BYTES == 12 * 1024 * 1024
+        for has_seg in (False, True):
+            plan = dispatch.flash_blocks(seq, seq, head, jnp.bfloat16,
+                                         has_seg)
+            assert plan == {'fwd': (512, 1024), 'dq': (1024, 1024),
+                            'dkv': (512, 512)}
+            for kernel, (bq, bk) in plan.items():
+                need = dispatch.flash_vmem_bytes(kernel, bq, bk, head, 2,
+                                                 has_seg)
+                assert need <= dispatch.VMEM_BUDGET_BYTES, (kernel, need)
+                limit = dispatch.flash_vmem_limit(need)
+                assert limit is None or limit >= 2 * need
         # A score tile the budget cannot hold is halved, not offered.
         wide = dispatch.flash_blocks(65536, 65536, 512, jnp.float32, False)
         for kernel, (bq, bk) in wide.items():
@@ -113,7 +121,10 @@ class TestBlockSelection:
 # Adversarial shapes: (b, sq, sk, hq, hkv, d). Includes the decode
 # shape (4, 32, 8, 256) whose 256-row block once crashed the Mosaic
 # lowering, in BOTH layout readings — [B,Sq,Hq,D] and the [B,Hq,Sq,D]
-# kernel layout it was logged in.
+# kernel layout it was logged in. And lengths between 512 and 1,024,
+# with and without packed segments ('seg'): longer than every tile the
+# rule gives, shorter than what a whole-sequence tile may be, so the
+# rule's own tiles are the only Pallas plan there is for them.
 SHAPE_GRID = [
     (4, 32, 32, 8, 8, 256),     # that shape, API layout
     (4, 8, 8, 32, 32, 256),     # that shape, kernel-layout reading
@@ -121,6 +132,12 @@ SHAPE_GRID = [
     (1, 300, 300, 2, 2, 64),    # non-pow2, non-8-divisible seq
     (1, 48, 48, 4, 4, 64),      # tiny batch, sub-block seq
     (3, 24, 24, 2, 1, 128),     # odd batch + GQA
+    (1, 640, 640, 2, 1, 64),
+    (1, 768, 768, 2, 2, 64),
+    (1, 896, 896, 2, 1, 64),
+    (2, 640, 640, 2, 2, 64, 'seg'),
+    (2, 768, 768, 2, 1, 64, 'seg'),
+    (2, 896, 896, 2, 2, 64, 'seg'),
 ]
 
 
@@ -130,21 +147,28 @@ class TestShapeGrid:
                              ids=['x'.join(map(str, s))
                                   for s in SHAPE_GRID])
     def test_no_shape_raises_and_matches_reference(self, shape):
-        """No grid shape may raise from the public ops entry point;
-        golden numerics vs the XLA reference in interpreter mode."""
-        b, sq, sk, hq, hkv, d = shape
+        """No grid shape may raise from the public ops entry point, and
+        each lands on the Pallas rung; golden numerics vs the XLA
+        reference in interpreter mode."""
+        b, sq, sk, hq, hkv, d = shape[:6]
+        has_seg = shape[6:] == ('seg',)
         q, k, v = _qkv(b, sq, sk, hq, hkv, d)
+        seg = _seg_ids(b, sq) if has_seg else None
         causal = sq == sk   # cross-length decode shapes: plain attn
+        dispatch.reset_for_tests()
         out = attention_ops.attention(q, k, v, causal=causal,
-                                      impl='flash')
-        ref = attention_ops.mha_reference(q, k, v, causal=causal)
+                                      segment_ids=seg, impl='flash')
+        ref = attention_ops.mha_reference(q, k, v, causal=causal,
+                                          segment_ids=seg)
         assert jnp.max(jnp.abs(out - ref)) < 2e-5
+        assert dispatch.snapshot()['flash_attention'] == 'pallas'
         # The grid shape must also be statically LEGAL on the Pallas
         # rung it took (the part interpreter mode cannot prove).
+        mult = dispatch.LANES if has_seg else 8
         for bq, bk in dispatch.flash_blocks(sq, sk, d, q.dtype,
-                                            False).values():
-            assert dispatch.block_dim_ok(bq, sq, 8)
-            assert dispatch.block_dim_ok(bk, sk, 8)
+                                            has_seg).values():
+            assert dispatch.block_dim_ok(bq, sq, mult)
+            assert dispatch.block_dim_ok(bk, sk, mult)
             assert bq <= sq and bk <= sk
 
     def test_bench_r02_shape_lowers_via_flash_impl(self):
@@ -207,6 +231,9 @@ TILE_PLAN_CASES = [
      (256, 128)),
     ('request-128x128-segments', 2, 256, 256, 2, 2, True, True, 0,
      (128, 128)),
+    ('window256-segments-g4', 2, 768, 768, 4, 1, True, True, 256, None),
+    ('segments640-g4', 1, 640, 640, 4, 1, True, True, 0, None),
+    ('window384-896', 1, 896, 896, 2, 1, True, False, 384, None),
 ]
 
 
@@ -249,6 +276,7 @@ class TestTilePlan:
             allowed &= q_pos >= k_pos
         if window > 0:
             allowed &= q_pos - k_pos < window
+        # The backward is the dq and dk/dv kernels, always.
         plans = dispatch.flash_plan_snapshot()
         assert sorted(plans) == sorted(dispatch.FLASH_KERNELS)
         rule = dispatch.flash_blocks(sq, sk, 64, q.dtype, segmented,
@@ -326,17 +354,16 @@ class TestLadder:
         assert c.value('flash_attention', 'xla') == before + 1
 
     def test_where_filter_targets_one_rung(self):
-        """where=path:pallas kills only the default-block rung; the
-        conservative full-array rung (present because at 2,048 the
-        tile rule gives blocks smaller than the sequence) must pick it
-        up — partial degradation, not a collapse to XLA."""
+        """where=path:pallas kills the Pallas rung by name; at 2,048
+        (where the rule's tiles are smaller than the sequence) there is
+        no second Pallas plan to try, so the XLA floor serves it."""
         dispatch.reset_for_tests()
         faults.configure('ops.lowering=error,where=path:pallas')
         q, k, v = _qkv(1, 2048, 2048, 1, 1, 64, seed=13)
         out = attention_ops.attention(q, k, v, impl='flash')
         ref = attention_ops.mha_reference(q, k, v)
-        assert jnp.max(jnp.abs(out - ref)) < 2e-5
-        assert dispatch.snapshot()['flash_attention'] == 'pallas_full'
+        assert jnp.max(jnp.abs(out - ref)) < 1e-6
+        assert dispatch.snapshot()['flash_attention'] == 'xla'
 
     def test_final_rung_never_fault_injected(self):
         """The XLA floor is the correctness guarantee: an armed
@@ -345,136 +372,24 @@ class TestLadder:
         out = dispatch.run_ladder('t_final', [('xla', lambda: 42)])
         assert out == 42
 
-    def test_forced_path_env(self, monkeypatch):
-        monkeypatch.setenv('SKYT_OPS_FORCE_PATH', 'xla')
-        dispatch.reset_for_tests()
-        q, k, v = _qkv(1, 56, 56, 2, 2, 64, seed=17)
-        out = attention_ops.attention(q, k, v, impl='flash')
-        ref = attention_ops.mha_reference(q, k, v)
-        assert jnp.max(jnp.abs(out - ref)) < 1e-6
-        assert dispatch.snapshot()['flash_attention'] == 'xla'
+    def test_one_flash_plan_and_no_setting_beside_it(self, monkeypatch):
+        """The flash ladder is the rule's Pallas plan and the XLA floor,
+        and no setting chooses a kernel, a tile or a budget."""
+        seen = []
+        real = dispatch.run_ladder
 
+        def spy(op, rungs):
+            seen.append((op, [name for name, _ in rungs]))
+            return real(op, rungs)
 
-# -------------------------------------------------------- autotune cache
-class TestAutotune:
-
-    def _arm(self, monkeypatch, tmp_path):
-        path = str(tmp_path / 'autotune.json')
-        monkeypatch.setenv('SKYT_AUTOTUNE', '1')
-        monkeypatch.setenv('SKYT_AUTOTUNE_CACHE', path)
-        monkeypatch.setenv('SKYT_AUTOTUNE_REPEATS', '1')
-        autotune.reset_for_tests()
-        return path
-
-    def teardown_method(self):
-        autotune.reset_for_tests()
-
-    def test_sweep_once_then_cache_hit(self, monkeypatch, tmp_path):
-        """Acceptance: a repeated invocation with the same
-        (device_kind, shape-bucket, dtype) key is a cache HIT — no
-        re-sweep — and the winner survives a 'process restart'
-        (in-memory copy dropped, reloaded from disk)."""
-        path = self._arm(monkeypatch, tmp_path)
-        sweeps = metrics_lib.REGISTRY.counter(
-            'skyt_ops_autotune_sweeps_total',
-            'Autotune block-size sweeps executed', ('op',))
-        hits = metrics_lib.REGISTRY.counter(
-            'skyt_ops_autotune_cache_hits_total',
-            'Autotune cache hits (sweep skipped)', ('op',))
-        s0 = sweeps.value('flash_attention')
-        h0 = hits.value('flash_attention')
-        q, k, v = _qkv(1, 16, 16, 2, 2, 32, seed=19)
+        monkeypatch.setattr(dispatch, 'run_ladder', spy)
+        q, k, v = _qkv(1, 1152, 1152, 1, 1, 64, seed=17)  # fresh shape
         attention_ops.attention(q, k, v, impl='flash')
-        assert sweeps.value('flash_attention') == s0 + 1
-        data = json.load(open(path))
-        assert data['version'] == 1 and data['entries']
-        (key, entry), = data['entries'].items()
-        assert 'flash_attention' in key and 'float32' in key
-        assert entry['block_q'] and entry['block_k']
-
-        # Same key again: hit, no re-sweep (different VALUES, same
-        # shape bucket).
-        q2, k2, v2 = _qkv(1, 16, 16, 2, 2, 32, seed=23)
-        attention_ops.attention(q2, k2, v2, impl='flash')
-        assert sweeps.value('flash_attention') == s0 + 1
-        assert hits.value('flash_attention') == h0 + 1
-
-        # 'New process': drop memory, read back from disk.
-        autotune.get_cache().forget_loaded()
-        got = autotune.lookup_flash(q.shape, k.shape, q.dtype,
-                                    True, False, 0)
-        assert got == (entry['block_q'], entry['block_k'])
-
-    def test_corrupt_cache_degrades_to_cold_start(self, monkeypatch,
-                                                  tmp_path):
-        """Acceptance: a corrupted cache file is a cold start, never a
-        raise — and the next sweep REWRITES it atomically."""
-        path = self._arm(monkeypatch, tmp_path)
-        q, k, v = _qkv(1, 16, 16, 2, 2, 32, seed=29)
-        attention_ops.attention(q, k, v, impl='flash')
-        with open(path, 'w') as f:
-            f.write('{"version": 1, "entries": {trailing garbage')
-        autotune.reset_for_tests()
-        assert autotune.lookup_flash(q.shape, k.shape, q.dtype,
-                                     True, False, 0) is None
-        # Re-tunes and leaves a valid file behind.
-        attention_ops.attention(q, k, v, impl='flash')
-        data = json.load(open(path))
-        assert data['entries']
-
-    def test_unexpected_layouts_are_cold_starts(self, monkeypatch,
-                                                tmp_path):
-        path = self._arm(monkeypatch, tmp_path)
-        for payload in ('[]', '{"version": 99, "entries": {}}',
-                        '{"entries": 3}', ''):
-            with open(path, 'w') as f:
-                f.write(payload)
-            autotune.reset_for_tests()
-            assert autotune.get_cache().get('k') is None
-
-    def test_candidate_failure_is_skipped_not_propagated(
-            self, monkeypatch, tmp_path):
-        self._arm(monkeypatch, tmp_path)
-        calls = []
-
-        def run(cand):
-            calls.append(cand)
-            if cand != 'good':
-                raise RuntimeError('boom')
-
-        entry = autotune.sweep('t_op', 'k1', ['bad1', 'good', 'bad2'],
-                               run, lambda c: {'pick': c})
-        assert entry['pick'] == 'good'
-        assert 'bad2' in calls   # sweep continued past the failure
-
-    def test_all_candidates_failing_returns_none(self, monkeypatch,
-                                                 tmp_path):
-        self._arm(monkeypatch, tmp_path)
-        calls = []
-
-        def run(cand):
-            calls.append(cand)
-            raise RuntimeError('boom')
-
-        assert autotune.sweep('t_op2', 'k2', [1, 2], run,
-                              lambda c: {}) is None
-        # The failure is negative-cached: a later sweep for the same
-        # key must NOT re-run the (minutes-on-device) failing sweep,
-        # and the poisoned entry reads as a miss for block lookups.
-        n = len(calls)
-        assert autotune.sweep('t_op2', 'k2', [1, 2], run,
-                              lambda c: {}) == {'failed': True}
-        assert len(calls) == n   # no candidate re-executed
-        assert autotune.get_cache().get('k2') == {'failed': True}
-
-    def test_disabled_is_a_noop(self, monkeypatch, tmp_path):
-        path = str(tmp_path / 'never.json')
-        monkeypatch.delenv('SKYT_AUTOTUNE', raising=False)
-        monkeypatch.setenv('SKYT_AUTOTUNE_CACHE', path)
-        autotune.reset_for_tests()
-        q, k, v = _qkv(1, 16, 16, 2, 2, 32, seed=31)
-        attention_ops.attention(q, k, v, impl='flash')
-        assert not os.path.exists(path)
+        assert seen == [('flash_attention', ['pallas', 'xla'])]
+        gone = ('SKYT_AUTOTUNE', 'SKYT_AUTOTUNE_CACHE',
+                'SKYT_AUTOTUNE_REPEATS', 'SKYT_FLASH_BWD',
+                'SKYT_OPS_FORCE_PATH', 'SKYT_OPS_VMEM_BUDGET')
+        assert not set(gone) & set(env.registry())
 
 
 # ------------------------------------- chaos: ops.lowering mid-serve

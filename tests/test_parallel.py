@@ -25,7 +25,7 @@ def _qkv(b=2, s=64, hq=4, hkv=2, d=16, seed=0):
 
 class TestFlashKernel:
     """Interpret-mode equivalence with the XLA reference (the same kernel
-    runs compiled on TPU; see bench.py)."""
+    runs compiled on TPU; see tests_tpu/)."""
 
     @pytest.mark.parametrize('causal', [True, False])
     def test_matches_reference(self, causal):

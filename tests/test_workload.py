@@ -4,8 +4,8 @@ byte-identical schedule), arrival-process shape, session-reuse
 mechanics, capacity-search convergence on a closed-form attainment
 model, and the busy-ledger's sums-to-busy-time invariant.
 
-The end-to-end half (real replica + real LB tier) lives in bench.py's
-capacity phase and tests/test_chaos_*.py's flash-crowd drill.
+The end-to-end half (real replica + real LB tier) lives in
+tests/test_chaos_*.py's flash-crowd drill.
 """
 import math
 

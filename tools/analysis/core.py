@@ -183,8 +183,7 @@ def analyze(root: Path, roots: Optional[Sequence[str]] = None,
     relative to ``root``), then project passes over the whole view.
     Returns violations sorted by (path, line, pass)."""
     roots = list(roots) if roots else [
-        'skypilot_tpu', 'tests', 'tools', 'bench.py',
-        '__graft_entry__.py']
+        'skypilot_tpu', 'tests', 'tools', '__graft_entry__.py']
     files: List[FileContext] = []
     for r in roots:
         p = root / r
@@ -211,8 +210,7 @@ def analyze(root: Path, roots: Optional[Sequence[str]] = None,
 def count_files(root: Path,
                 roots: Optional[Sequence[str]] = None) -> int:
     roots = list(roots) if roots else [
-        'skypilot_tpu', 'tests', 'tools', 'bench.py',
-        '__graft_entry__.py']
+        'skypilot_tpu', 'tests', 'tools', '__graft_entry__.py']
     n = 0
     for r in roots:
         p = root / r
