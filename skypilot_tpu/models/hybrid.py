@@ -6,7 +6,8 @@ knob on that body. Here each layer names its token mixer ('attention':
 `LlamaAttention` as it is; 'conv': the gated short convolution below)
 and its feed-forward ('dense': `LlamaMLP`; 'experts': the dropless
 `moe.RoutedExperts`), and the stack is unrolled, each block under its
-own remat. LFM2-MoE (huggingface.co/LiquidAI/LFM2-24B-A2B,
+own remat (which recomputes all of a block but its experts'
+selection). LFM2-MoE (huggingface.co/LiquidAI/LFM2-24B-A2B,
 `model_type` `lfm2_moe`) is the first family written this way: 30 of
 its 40 layers have no attention at all.
 
@@ -146,7 +147,7 @@ class HybridBlock(nn.Module):
         h = llama_lib.RMSNorm(base, name='ffn_norm')(x)
         if ffn == 'dense':
             h, stats = llama_lib.LlamaMLP(base, name='mlp')(h), \
-                jnp.zeros((3,), jnp.int32)
+                jnp.zeros((5,), jnp.int32)
         else:
             h, stats = moe_lib.RoutedExperts(
                 base, self.cfg.experts, name='experts')(h)
@@ -177,14 +178,19 @@ class HybridModel(nn.Module):
         cos, sin = rope.rope_freqs(
             positions, base.head_dim, base.rope_theta,
             use_llama31_scaling=base.use_llama31_rope)
-        block = nn.remat(HybridBlock) if base.remat else HybridBlock
-        routed = fullest = dropped = jnp.zeros((), jnp.int32)
+        # Recompute everything but which experts each token chose
+        # (moe.route has why).
+        block = nn.remat(
+            HybridBlock, policy=jax.checkpoint_policies.save_only_these_names(
+                moe_lib.SELECTED)) if base.remat else HybridBlock
+        routed = fullest = dropped = rows = worst = jnp.zeros(
+            (), jnp.int32)
         for i, kind in enumerate(cfg.layers):
             x, stats = block(cfg, kind, name=f'layer_{i}')(
                 x, cos, sin, segment_ids)
-            routed, fullest, dropped = (
+            routed, fullest, dropped, rows, worst = (
                 routed + stats[0], jnp.maximum(fullest, stats[1]),
-                dropped + stats[2])
+                dropped + stats[2], rows + stats[3], worst + stats[4])
         if cfg.experts is not None:
             ex = cfg.experts
             pairs = b * s * ex.experts_per_token
@@ -194,7 +200,8 @@ class HybridModel(nn.Module):
                     ffn == 'experts' for _, ffn in cfg.layers)),
                 'moe_fullest_over_mean':
                     fullest * (ex.num_experts / pairs),
-                'moe_pairs_dropped': dropped})
+                'moe_pairs_dropped': dropped,
+                'moe_rows': rows, 'moe_rows_worst': worst})
         x = llama_lib.RMSNorm(base, name='final_norm')(x)
         if base.tie_embeddings:
             logits = jnp.einsum('bsd,vd->bsv', x, embed.astype(dtype))

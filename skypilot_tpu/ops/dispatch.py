@@ -260,10 +260,30 @@ def flash_plan_snapshot() -> Dict[str, Dict[str, Any]]:
         return {k: dict(v) for k, v in _flash_plans.items()}
 
 
+def moe_chunk_rows(tokens: int, k: int, held: int, experts: int) -> int:
+    """The chunk rule: rows a trip of the expert layer's loop gathers,
+    multiplies and adds back, from the shape alone: what an even router
+    sends to the held experts, tokens * k * held / experts pairs, with
+    a sixteenth of room, in whole sublane tiles of 8, and no more than
+    the worst case, tokens * min(k, held) rows: 8,704 rows where 16,384
+    tokens choose 4 of 64 experts and 8 are held, the whole worst case
+    where every expert is. A trip's fixed costs outweigh its rows', so
+    such a router's pairs go in one trip; a skewed router takes more
+    trips of the same chunk. Against chunks of 2,048 and 4,096 on the
+    v5e that is within 0.5 ms a layer or faster (10 ms at the worst
+    case) at every count of held pairs but the few hundred just past a
+    multiple of the chunk, where it is 2.2 ms slower at worst (PERF.md
+    §6, PR 32)."""
+    even = -(-tokens * k * held // experts)
+    chunk = -(-(even + even // 16) // 8) * 8
+    return min(chunk, -(-tokens * min(k, held) // 8) * 8)
+
+
 def record_moe_plan(plan: Dict[str, Any]) -> None:
     """Remember the routing plan an expert layer was traced with
-    (experts routed over and held, k, tokens, the row buffer) and stamp
-    it on the current trace span as `ops.moe_plan`."""
+    (experts routed over and held, k, tokens, the worst-case rows and
+    the loop's chunk) and stamp it on the current trace span as
+    `ops.moe_plan`."""
     with _lock:
         _moe_plan.clear()
         _moe_plan.update(plan)
