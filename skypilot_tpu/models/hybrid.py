@@ -3,13 +3,18 @@ table.
 
 `LlamaModel` is one `nn.scan` over identical blocks, and a family is a
 knob on that body. Here each layer names its token mixer ('attention':
-`LlamaAttention` as it is; 'conv': the gated short convolution below)
-and its feed-forward ('dense': `LlamaMLP`; 'experts': the dropless
-`moe.RoutedExperts`), and the stack is unrolled, each block under its
-own remat (which recomputes all of a block but its experts'
-selection). LFM2-MoE (huggingface.co/LiquidAI/LFM2-24B-A2B,
-`model_type` `lfm2_moe`) is the first family written this way: 30 of
-its 40 layers have no attention at all.
+`LlamaAttention` as it is; 'window_attention': the same with a static
+sliding window and a rotary table of its own; 'conv': the gated short
+convolution below) and its feed-forward ('dense': `LlamaMLP`;
+'experts': the dropless `moe.RoutedExperts`), and the stack is
+unrolled, each block under its own remat (which recomputes all of a
+block but its experts' selection). LFM2-MoE
+(huggingface.co/LiquidAI/LFM2-24B-A2B, `model_type` `lfm2_moe`) is the
+first family written this way: 30 of its 40 layers have no attention
+at all. Mellum2
+(huggingface.co/JetBrains/Mellum2-12B-A2.5B-Instruct, `mellum`) is the
+second: three window layers to every full one, YaRN on the full layers
+only, every feed-forward routed, the output head untied.
 
 Training only: there is no cache argument. Serving a convolution-state
 layer is ROADMAP work (the engine's cache holds K/V and nothing else).
@@ -25,7 +30,7 @@ from skypilot_tpu.models import llama as llama_lib
 from skypilot_tpu.models import moe as moe_lib
 from skypilot_tpu.ops import rope
 
-OPERATORS = ('attention', 'conv')
+OPERATORS = ('attention', 'window_attention', 'conv')
 FEED_FORWARDS = ('dense', 'experts')
 
 
@@ -34,11 +39,16 @@ class HybridConfig:
     """`base` carries every width the shared modules read (hidden size,
     heads, the dense feed-forward's `mlp_dim`, norms, rope, vocabulary,
     dtypes, remat); `layers` is the kind table, one (operator,
-    feed-forward) pair per layer."""
+    feed-forward) pair per layer. `window` is what a 'window_attention'
+    layer sees (the query's own position and the window - 1 before it);
+    `yarn` scales the rotary table of the 'attention' layers, and the
+    window layers keep the plain one."""
     base: llama_lib.LlamaConfig
     layers: Tuple[Tuple[str, str], ...]
     conv_kernel: int = 3
     experts: Optional[moe_lib.ExpertsConfig] = None
+    window: int = 0
+    yarn: Optional[rope.Yarn] = None
 
     def __post_init__(self):
         for op, ffn in self.layers:
@@ -47,6 +57,9 @@ class HybridConfig:
         if self.experts is None and any(
                 ffn == 'experts' for _, ffn in self.layers):
             raise ValueError('expert layers need an ExpertsConfig')
+        if self.window <= 0 and any(
+                op == 'window_attention' for op, _ in self.layers):
+            raise ValueError('window layers need a window')
 
     # What sft reads of a model's configuration.
     @property
@@ -73,7 +86,7 @@ class HybridConfig:
                'experts': 0 if ex is None else
                ex.num_held * 3 * d * ex.mlp_dim + d * ex.num_experts +
                (ex.num_experts if ex.scoring == 'sigmoid_bias' else 0)}
-        mix = {'attention': attn, 'conv': conv}
+        mix = {'attention': attn, 'window_attention': attn, 'conv': conv}
         return sum(mix[op] + ffn[f] + 2 * d for op, f in self.layers) + \
             c.vocab_size * d * (1 if c.tie_embeddings else 2) + d
 
@@ -140,6 +153,12 @@ class HybridBlock(nn.Module):
         if operator == 'attention':
             h = llama_lib.LlamaAttention(base, name='attn')(
                 h, cos, sin, segment_ids)
+        elif operator == 'window_attention':
+            # A window of the layer's own, static: no traced gate, so
+            # the flash kernels take it (ops/attention.py).
+            h = llama_lib.LlamaAttention(dataclasses.replace(
+                base, sliding_window=self.cfg.window), name='attn')(
+                    h, cos, sin, segment_ids)
         else:
             h = ShortConv(base, self.cfg.conv_kernel, name='conv')(
                 h, segment_ids)
@@ -165,19 +184,34 @@ class HybridModel(nn.Module):
         cfg, base = self.cfg, self.cfg.base
         dtype = jnp.dtype(base.dtype)
         b, s = tokens.shape
+        # A tied embedding is also the output head and starts small. An
+        # untied one starts at unit variance, the scale every later
+        # input of a block has: at 0.02 the first attention layer's
+        # output (the mean of a window of value vectors, 0.04-0.08 rms)
+        # outweighs the token's own row, every token of a sequence
+        # looks alike to the first router, and the routing follows the
+        # sequence's noise (fullest expert 3.4-3.9 times the mean, the
+        # pairs to a quarter of the experts 71,000-149,000 a step where
+        # an even router sends 131,072; PERF.md §6, PR 33).
         embed = self.param(
             'tok_embed',
-            nn.with_logical_partitioning(
-                nn.initializers.normal(stddev=0.02), ('vocab', 'embed')),
+            nn.with_logical_partitioning(nn.initializers.normal(
+                stddev=0.02 if base.tie_embeddings else 1.0),
+                ('vocab', 'embed')),
             (base.vocab_size, base.dim), jnp.dtype(base.param_dtype))
         x = embed.astype(dtype)[tokens]
         x = nn.with_logical_constraint(
             x, ('act_batch', 'act_seq', 'act_embed'))
         if positions is None:
             positions = rope.positions_from_segment_ids(segment_ids, b, s)
-        cos, sin = rope.rope_freqs(
+        # A rotary table by operator kind: the window layers' is the
+        # plain rule whatever scales the full layers'.
+        operators = {op for op, _ in cfg.layers}
+        tables = {op: rope.rope_freqs(
             positions, base.head_dim, base.rope_theta,
-            use_llama31_scaling=base.use_llama31_rope)
+            use_llama31_scaling=base.use_llama31_rope,
+            yarn=cfg.yarn if op == 'attention' else None)
+            for op in ('attention', 'window_attention') if op in operators}
         # Recompute everything but which experts each token chose
         # (moe.route has why).
         block = nn.remat(
@@ -187,7 +221,7 @@ class HybridModel(nn.Module):
             (), jnp.int32)
         for i, kind in enumerate(cfg.layers):
             x, stats = block(cfg, kind, name=f'layer_{i}')(
-                x, cos, sin, segment_ids)
+                x, *tables.get(kind[0], (None, None)), segment_ids)
             routed, fullest, dropped, rows, worst = (
                 routed + stats[0], jnp.maximum(fullest, stats[1]),
                 dropped + stats[2], rows + stats[3], worst + stats[4])
@@ -227,7 +261,31 @@ def _lfm2(layer_types, num_dense, experts, **base):
         conv_kernel=3, experts=experts)
 
 
+def _mellum(layer_types, experts, window, yarn, **base):
+    """Mellum2's layout: `layer_types` names each layer's attention
+    (`sliding_attention`: a window and the plain rotary table;
+    `full_attention`: YaRN); every feed-forward is routed experts
+    (`mlp_layer_types` all `sparse`); per-head q/k norm, head size
+    given beside the hidden size, the output head untied."""
+    op = {'sliding_attention': 'window_attention',
+          'full_attention': 'attention'}
+    return HybridConfig(
+        base=llama_lib.LlamaConfig(
+            use_llama31_rope=False, norm_eps=1e-6, tie_embeddings=False,
+            qk_norm=True, **base),
+        layers=tuple((op[t], 'experts') for t in layer_types),
+        experts=experts, window=window, yarn=yarn)
+
+
 _LFM2_PERIOD = ('full_attention', 'conv', 'conv', 'conv')
+_MELLUM2_PERIOD = ('sliding_attention',) * 3 + ('full_attention',)
+_MELLUM2_12B = dict(
+    vocab_size=98304, dim=2304, n_heads=32, n_kv_heads=4,
+    head_dim_override=128, mlp_dim=7168, max_seq_len=131072,
+    rope_theta=5e5)
+_MELLUM2_YARN = rope.Yarn(factor=16.0, original_max_position=8192,
+                          beta_fast=32.0, beta_slow=1.0,
+                          attention_factor=1.2772588722239782)
 _LFM2_24B = dict(vocab_size=65536, dim=2048, n_heads=32, n_kv_heads=8,
                  mlp_dim=11776, max_seq_len=128000, rope_theta=1e6)
 
@@ -254,4 +312,34 @@ CONFIGS = {
         moe_lib.ExpertsConfig(64, 4, 1536, scoring='sigmoid_bias',
                               held=(0, 8)),
         **{**_LFM2_24B, 'vocab_size': 8192}),
+    # Tests: the structure of Mellum2 at toy widths (three window
+    # layers of 8 and a full layer under YaRN, whose ramp over the 16
+    # pairs of a head runs from pair 1 to pair 7; all routed experts;
+    # an untied head).
+    'debug-mellum2': _mellum(
+        _MELLUM2_PERIOD,
+        moe_lib.ExpertsConfig(16, 4, 48, scoring='softmax'), 8,
+        rope.Yarn(factor=4.0, original_max_position=16, beta_fast=1.0,
+                  beta_slow=0.05),
+        vocab_size=256, dim=64, n_heads=4, n_kv_heads=2,
+        head_dim_override=32, mlp_dim=128, max_seq_len=128,
+        rope_theta=1e4, dtype='float32', remat=False),
+    # Mellum2-12B-A2.5B-Instruct as published (config.json): 28 layers,
+    # window 1,024 in three of every four; 64 experts of width 896,
+    # eight a token, softmax-routed; head size 128 at hidden 2,304.
+    # `intermediate_size` (mlp_dim) is published and unused: no layer
+    # is dense.
+    'mellum2-12b-a2.5b': _mellum(
+        _MELLUM2_PERIOD * 7,
+        moe_lib.ExpertsConfig(64, 8, 896, scoring='softmax'), 1024,
+        _MELLUM2_YARN, **_MELLUM2_12B),
+    # One chip's share of it with four chips sharing each layer
+    # (chipbench/configs/mellum2-12b-a2.5b-ep4-sft.json): experts 0-15
+    # of 64, a quarter of each vocabulary matrix, one whole period
+    # (published layers 0-3).
+    'mellum2-12b-a2.5b-ep4': _mellum(
+        _MELLUM2_PERIOD,
+        moe_lib.ExpertsConfig(64, 8, 896, scoring='softmax',
+                              held=(0, 16)), 1024,
+        _MELLUM2_YARN, **{**_MELLUM2_12B, 'vocab_size': 24576}),
 }

@@ -13,7 +13,6 @@ import jax.numpy as jnp
 
 from skypilot_tpu.ops import dispatch
 from skypilot_tpu.parallel import sharding as sharding_lib
-from skypilot_tpu.utils import env
 
 NEG_INF = -1e9  # logits are f32 until softmax, so -1e9 never overflows
 
@@ -101,26 +100,21 @@ def _attention(q: jax.Array, k: jax.Array, v: jax.Array,
     choice runs through the fallback ladder (ops/dispatch.py): Pallas
     at the tiles the shape rule gives (`dispatch.flash_blocks`) → XLA
     reference, with the selected path recorded in
-    skyt_ops_kernel_path_total{op,path} and on the current trace span.
+    skyt_ops_kernel_path_total{op,path} and on the current trace span:
+    op `flash_attention` for full attention, `flash_window_attention`
+    for a static sliding window (Mistral, Phi-3, a kind-table model's
+    window layers), whose kernels skip the tiles outside the band
+    (O(S*window) block visits) and are chosen by the same shape rule.
     Soft-capped/rescaled attention (Gemma-2) always takes the XLA path
     — the flash kernel does not implement them, and a silent
-    wrong-math fast path is worse than a slower correct one. A STATIC
-    sliding window (Mistral, Phi-3) has a flash implementation
-    (O(S*window) block visits) behind SKYT_WINDOW_FLASH=on — opt-in
-    until the on-chip gate proves the lowering (the same discipline
-    the paged MQ kernel went through); Gemma-2's per-layer traced
-    window gate (window_active) stays XLA either way (the skip
-    predicate must be static-per-kernel). Explicit impl='flash' with a
-    static window honors the request without the env gate (it IS the
-    opt-in). NOTE: like the other SKYT_* kernel gates, env vars are
-    read at TRACE time — under an outer jit (the model) the choice is
-    baked into the compiled program, so set them before the process
-    builds its engines, not mid-run."""
+    wrong-math fast path is worse than a slower correct one. So does
+    Gemma-2's per-layer traced window gate (window_active): the skip
+    predicate must be static-per-kernel."""
     flash_unsupported = (logit_softcap > 0.0 or
                          softmax_scale is not None or
                          (window > 0 and window_active is not None))
-    impl = _resolve_impl(q, k, impl, window, window_active,
-                         flash_unsupported, segment_ids is not None)
+    impl = _resolve_impl(q, k, impl, window, flash_unsupported,
+                         segment_ids is not None)
 
     def xla():
         return mha_reference(q, k, v, causal=causal,
@@ -155,8 +149,9 @@ def _attention(q: jax.Array, k: jax.Array, v: jax.Array,
         def pallas():
             return sharding_lib.per_shard(kernel, in_axes, q_axes)(*operands)
 
-        return dispatch.run_ladder('flash_attention',
-                                   [('pallas', pallas), ('xla', xla)])
+        return dispatch.run_ladder(
+            'flash_window_attention' if window > 0 else 'flash_attention',
+            [('pallas', pallas), ('xla', xla)])
     # 'xla_native': XLA is the CORRECT path for this op (softcap /
     # scale / traced window / auto-resolved shape), not ladder
     # degradation — keep it distinguishable from the 'xla' floor so
@@ -165,20 +160,20 @@ def _attention(q: jax.Array, k: jax.Array, v: jax.Array,
     return dispatch.run_ladder('attention', [('xla_native', xla)])
 
 
-def _resolve_impl(q, k, impl: str, window: int, window_active,
-                  flash_unsupported: bool, has_seg: bool) -> str:
+def _resolve_impl(q, k, impl: str, window: int, flash_unsupported: bool,
+                  has_seg: bool) -> str:
     """The 'auto' gate: flash or XLA, from the shape and the features
-    asked for."""
+    asked for. A static window is a flash call like any other; what
+    flash cannot do (a soft cap, a scale of the caller's, a traced
+    window gate) is XLA's."""
     if impl != 'auto':
         return impl
-    window_flash = (window > 0 and window_active is None and
-                    env.get('SKYT_WINDOW_FLASH', 'off') == 'on')
-    auto_xla = flash_unsupported or (window > 0 and not window_flash)
-    return ('flash' if not auto_xla and _flash_ok(q, k, has_seg)
-            else 'xla')
+    return ('flash' if not flash_unsupported and
+            _flash_ok(q, k, has_seg, window) else 'xla')
 
 
-def _flash_ok(q: jax.Array, k: jax.Array, has_seg: bool = False) -> bool:
+def _flash_ok(q: jax.Array, k: jax.Array, has_seg: bool = False,
+              window: int = 0) -> bool:
     """Auto-dispatch gate: shapes where the flash kernel is expected
     to WIN on TPU (tile-aligned seqs, MXU-friendly head dim, blocks
     that fit VMEM). Any shape outside this set still works — it takes
@@ -193,11 +188,27 @@ def _flash_ok(q: jax.Array, k: jax.Array, has_seg: bool = False) -> bool:
             d % 64 == 0 and d <= 512):
         return False
     return dispatch.flash_vmem_ok(
-        dispatch.flash_blocks(sq, sk, d, q.dtype, has_seg), d,
+        dispatch.flash_blocks(sq, sk, d, q.dtype, has_seg, window), d,
         jnp.dtype(q.dtype).itemsize, has_seg)
 
 
-# The public name is the jitted function. It keeps the name `_attention`:
-# the compiled kernels are named after it (`_attention.N
-# [tpu_custom_call]` in a device trace), and trace readers match that.
-attention = _attention
+def attention(q: jax.Array, k: jax.Array, v: jax.Array,
+              causal: bool = True,
+              segment_ids: Optional[jax.Array] = None,
+              impl: str = 'auto',
+              window: int = 0,
+              window_active=None,
+              logit_softcap: float = 0.0,
+              softmax_scale: Optional[float] = None) -> jax.Array:
+    """The public entry: `_attention` under the named scope of its kind
+    of call, `flash_window` with a sliding window and `flash_full`
+    without. The scope stands outside the jitted function: the compiled
+    kernels keep its name (`_attention.N [tpu_custom_call]` in a device
+    trace, which trace readers match) and their `tf_op` says which kind
+    of layer called them (`.../flash_window/jit(_attention)/...`)."""
+    with jax.named_scope('flash_window' if window > 0 else 'flash_full'):
+        return _attention(q, k, v, causal=causal, segment_ids=segment_ids,
+                          impl=impl, window=window,
+                          window_active=window_active,
+                          logit_softcap=logit_softcap,
+                          softmax_scale=softmax_scale)

@@ -63,8 +63,14 @@ _lock = threading.Lock()
 # /stats and flight-recorder snapshots.
 _paths: Dict[str, str] = {}
 # flash kernel -> the tile plan of the shape traced last (trace-time);
-# logged by sft after its first step.
+# logged by sft after its first step. A kernel traced with a sliding
+# window is filed as `window_<kernel>`, beside the full-attention plan.
 _flash_plans: Dict[str, Dict[str, Any]] = {}
+# The window `flash_blocks` planned for last on this thread. A flash
+# kernel asks for its tiles and records them in one breath
+# (flash_attention._plan), and only the first of the two calls is told
+# the window.
+_planned = threading.local()
 # The routing plan of the expert layer traced last (trace-time).
 _moe_plan: Dict[str, Any] = {}
 
@@ -153,6 +159,7 @@ def flash_blocks(sq: int, sk: int, d: int, q_dtype, has_seg: bool,
     way and given to all three kernels."""
     import jax.numpy as jnp
     itemsize = jnp.dtype(q_dtype).itemsize
+    _planned.window = window
     plan = {}
     for kernel in FLASH_KERNELS:
         wq, wk = want or _FLASH_MAX_BLOCKS[kernel]
@@ -243,7 +250,11 @@ def record_path(op: str, path: str) -> None:
 def record_flash_plan(kernel: str, plan: Dict[str, Any]) -> None:
     """Remember the tile plan a flash kernel was traced with (extents
     and, per head, tiles visited, masked and skipped) and stamp it on
-    the current trace span, beside `ops.path.flash_attention`."""
+    the current trace span, beside `ops.path.flash_attention`. Planned
+    for a sliding window (the `flash_blocks` call before this one), it
+    is the plan of `window_<kernel>`."""
+    if getattr(_planned, 'window', 0) > 0:
+        kernel = f'window_{kernel}'
     with _lock:
         _flash_plans[kernel] = dict(plan)
     from skypilot_tpu.utils import tracing

@@ -138,8 +138,6 @@ _var('SKYT_COS_ENDPOINT', 'str',
      'IBM COS S3-compatible endpoint.')
 
 # ------------------------------------------------------------- kernels
-_var('SKYT_WINDOW_FLASH', 'str', 'off',
-     'Opt-in Pallas path for windowed attention ("on" enables).')
 _var('SKYT_PAGED_ATTN', 'str', 'pallas',
      'Paged decode attention impl: "pallas" or "xla".')
 _var('SKYT_SPEC_PAGED_ATTN', 'str', 'pallas',

@@ -276,14 +276,17 @@ class TestTilePlan:
             allowed &= q_pos >= k_pos
         if window > 0:
             allowed &= q_pos - k_pos < window
-        # The backward is the dq and dk/dv kernels, always.
+        # The backward is the dq and dk/dv kernels, always. A plan
+        # for a window is filed under a name of its own.
         plans = dispatch.flash_plan_snapshot()
-        assert sorted(plans) == sorted(dispatch.FLASH_KERNELS)
+        prefix = 'window_' if window > 0 else ''
+        assert sorted(plans) == sorted(
+            prefix + kernel for kernel in dispatch.FLASH_KERNELS)
         rule = dispatch.flash_blocks(sq, sk, 64, q.dtype, segmented,
                                      window, want)
         for kernel, plan in plans.items():
             tq, tk = plan['block_q'], plan['block_k']
-            assert (tq, tk) == rule[kernel]
+            assert (tq, tk) == rule[kernel[len(prefix):]]
             tiles = allowed.reshape(sq // tq, tq, sk // tk, tk)
             some = tiles.any(axis=(1, 3))
             every = tiles.all(axis=(1, 3))

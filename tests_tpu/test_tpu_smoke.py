@@ -63,9 +63,10 @@ class TestFlashKernelLowers:
                 atol=5e-2, rtol=5e-2)
 
     def test_windowed_fwd_bwd(self):
-        """Sliding-window flash (Mistral/Phi-3 prefill): Mosaic
-        lowering + parity vs the masked reference at seq 2048, window
-        512 — validates flipping SKYT_WINDOW_FLASH to default-on."""
+        """Sliding-window flash (Mistral/Phi-3 prefill, a kind-table
+        model's window layers): Mosaic lowering + parity vs the masked
+        reference at seq 2048, window 512. A static window is chosen by
+        the shape rule like any other flash call (ops/attention.py)."""
         from skypilot_tpu.ops.attention import mha_reference
         from skypilot_tpu.ops.flash_attention import flash_attention
 
@@ -96,6 +97,64 @@ class TestFlashKernelLowers:
             np.testing.assert_allclose(
                 np.asarray(g, np.float32), np.asarray(gr, np.float32),
                 atol=5e-2, rtol=5e-2)
+
+    def test_windowed_fwd_bwd_at_the_16k_cells_shape(self):
+        """The window layers of `sft-swa-moe-16k`: 32 / 4 heads x 128,
+        S 16,384, window 1,024, through `attention` as the model calls
+        it (the shape rule's tiles, capped at the window). Forward, dq
+        and dk/dv under a random cotangent against the masked reference
+        computed a block of 1,024 queries at a time over the 2,047 keys
+        its band can reach (the whole square is 34 GB in float32)."""
+        from skypilot_tpu.ops import attention, dispatch
+
+        b, s, hq, hkv, d, w, bq = 1, 16384, 32, 4, 128, 1024, 1024
+        q = _rand(0, (b, s, hq, d))
+        k = _rand(1, (b, s, hkv, d))
+        v = _rand(2, (b, s, hkv, d))
+        cot = _rand(3, (b, s, hq, d)).astype(jnp.float32)
+
+        def flash(q, k, v):
+            return attention.attention(q, k, v, causal=True, window=w)
+
+        def blocked(q, k, v):
+            @jax.checkpoint
+            def block(q1, k1, v1, offset):
+                return attention.mha_reference(
+                    q1, k1, v1, causal=True, window=w, q_offset=offset)
+            outs = []
+            for s0 in range(0, s, bq):
+                k0 = max(0, s0 - w + 1)
+                outs.append(block(q[:, s0:s0 + bq], k[:, k0:s0 + bq],
+                                  v[:, k0:s0 + bq], s0 - k0))
+            return jnp.concatenate(outs, axis=1)
+
+        def both(fn):
+            # cot is an argument: closed over, its 268 MB would be a
+            # constant of the compiled program
+            def loss(q, k, v, cot):
+                out = fn(q, k, v)
+                return jnp.sum(out.astype(jnp.float32) * cot), out
+            return jax.jit(jax.value_and_grad(loss, argnums=(0, 1, 2),
+                                              has_aux=True))
+        dispatch.reset_for_tests()
+        (_, out), grads = both(flash)(q, k, v, cot)
+        (_, ref), grefs = both(blocked)(q, k, v, cot)
+        assert dispatch.snapshot() == {'flash_window_attention': 'pallas'}
+        plan = dispatch.flash_plan_snapshot()
+        assert {name: (p['block_q'], p['block_k'])
+                for name, p in plan.items()} == {
+            'window_fwd': (512, 1024), 'window_dq': (1024, 1024),
+            'window_dkv': (512, 512)}
+        # per head: the band's tiles of the 32 x 16 (or 16 x 16, 32 x 32)
+        assert (plan['window_fwd']['visited'],
+                plan['window_dq']['visited'],
+                plan['window_dkv']['visited']) == (62, 31, 93)
+        np.testing.assert_allclose(
+            np.asarray(out, np.float32), np.asarray(ref, np.float32),
+            atol=3e-2, rtol=3e-2)
+        for name, g, gr in zip(('dq', 'dk', 'dv'), grads, grefs):
+            g, gr = (np.asarray(x, np.float32) for x in (g, gr))
+            assert np.linalg.norm(g - gr) / np.linalg.norm(gr) < 3e-2, name
 
     def test_fwd_with_segment_ids(self):
         from skypilot_tpu.ops.flash_attention import flash_attention
