@@ -73,6 +73,8 @@ _flash_plans: Dict[str, Dict[str, Any]] = {}
 _planned = threading.local()
 # The routing plan of the expert layer traced last (trace-time).
 _moe_plan: Dict[str, Any] = {}
+# (form, a, b) of a grouped product -> the tiles it was traced with.
+_grouped_plan: Dict[Tuple[str, int, int], Tuple[int, int, int]] = {}
 
 
 def sublane_multiple(dtype) -> int:
@@ -228,6 +230,95 @@ def flash_vmem_limit(need: int) -> Optional[int]:
     return 2 * need if 2 * need > _MOSAIC_DEFAULT_VMEM_BYTES else None
 
 
+GROUPED_FORMS = ('rows', 'rows_t', 'over_rows')
+
+# What the counted working set of a grouped kernel may come to. The
+# v5e has 128 MiB of VMEM; Mosaic scopes a kernel to the
+# `vmem_limit_bytes` it is given, which is twice the count
+# (`flash_vmem_limit`), so 96 MiB at most.
+GROUPED_VMEM_BUDGET_BYTES = 48 * 1024 * 1024
+
+
+def grouped_vmem_bytes(form: str, tm: int, tk: int, tn: int,
+                       itemsize: int) -> int:
+    """VMEM one invocation of a grouped kernel (ops/grouped_kernel.py)
+    holds, counted from above: every streamed block twice (the
+    pipeline's double buffer) and the float32 product. `rows` and
+    `rows_t`: a row tile [tm, tk], a group's matrix tile [tk, tn], the
+    result tile [tm, tn]; the product [tm, tn], and once more as the
+    accumulator of a tiled contraction. `over_rows`: the row tiles
+    [tm, tk] and [tm, tn], the result [tk, tn] twice, its float32
+    accumulator, and a row tile's float32 temporaries. The smallest
+    `vmem_limit_bytes` each kernel compiles under for the v5e (jaxlib
+    0.9.0, bf16, whole matrices of 2,304 x 896 and 2,048 x 1,536, row
+    tiles of 256 and 512) is 0.7 to 1.0 of this count."""
+    if form == 'over_rows':
+        return (2 * (tm * tk + tm * tn) * itemsize +
+                tk * tn * (2 * itemsize + 4) + tm * max(tk, tn) * 4)
+    if form not in GROUPED_FORMS:
+        raise ValueError(f'unknown grouped product {form!r}')
+    return 2 * (tm * tk + tk * tn + tm * tn) * itemsize + 2 * tm * tn * 4
+
+
+def grouped_blocks(form: str, rows: int, a: int, b: int, groups: int,
+                   dtype) -> Optional[Tuple[int, int, int]]:
+    """The tile rule of the grouped kernels (ops/grouped_kernel.py):
+    (tm, tk, tn) from the shape alone, for a product of `form` over
+    `rows` rows and matrices [groups, a, b], or None where no legal
+    tiles fit (the ladder then stands on `ragged_dot`).
+
+    tm, the row tile: 256, or 128 where 256 does not divide the rows.
+    tk and tn: the whole extents, so that a group's matrix is fetched
+    once and stays in VMEM across the group's row tiles (`rows`: tk = a
+    contracted, tn = b; `rows_t`: tk = b contracted, tn = a; `over_rows`:
+    tk over a, tn over b, the result's tile). Where the count
+    (`grouped_vmem_bytes`) is over `GROUPED_VMEM_BUDGET_BYTES` the
+    larger of the two is halved, the columns on a tie, while a half is
+    whole lanes. Extents that are no whole lanes, and rows that
+    neither row tile divides, have no tiles.
+
+    Fitted on one v5e in bf16 (PERF.md §6, PR 34), ms a call, at
+    `sft-swa-moe-16k`'s calls (34,816 rows, 32,816 of them in 16
+    groups; `over_rows` over 139,264) and `sft-moe-8k`'s (8,704 rows,
+    8,192 in 8 groups; 69,632), `ragged_dot` first:
+
+    | product | ragged_dot | tm 128 | 256 | 512 | 1,024 | 2,048 |
+    | rows 2,304 x 896 | 3.39 | 0.915 | 0.894 | 0.958 | 1.126 | 1.477 |
+    | rows 896 x 2,304 | 3.05 | 0.918 | 0.911 | 0.978 | 1.117 | 1.479 |
+    | rows_t by 2,304 x 896 | 3.06 | 0.932 | 0.920 | 0.972 | 1.130 | 1.483 |
+    | over_rows 2,304 x 896 | 4.03 | - | 1.062 | 1.090 | 1.169 | 1.516 |
+    | rows 2,048 x 1,536 | 0.590 | 0.416 | 0.425 | 0.454 | - | - |
+    | rows_t by 2,048 x 1,536 | 0.597 | 0.417 | 0.416 | 0.462 | - | - |
+    | over_rows 2,048 x 1,536 | 0.667 | - | 0.522 | 0.538 | 0.612 | 0.970 |
+
+    A small row tile wins: a tile that two groups share is multiplied
+    once for each, and the matrix is resident either way (tiles of 256
+    visit 151 for 136 at 2,176 rows a group, 512 visit 83 for 68).
+    Tiling costs: half the columns 0.948 for 0.911 and 0.442 for 0.425
+    (the rows are read twice), half the contraction 1.075 for 0.958 (an
+    accumulator's pass, and another order of the float32 sums), columns
+    of 128 lanes 1.86-2.19 for 0.96. The count of groups does not enter:
+    256 rows win at 2,051 rows a group and at 1,024."""
+    del groups
+    import jax.numpy as jnp
+    itemsize = jnp.dtype(dtype).itemsize
+    tm = next((t for t in (256, 128)
+               if rows % t == 0 and t % sublane_multiple(dtype) == 0), None)
+    if tm is None or a % LANES or b % LANES:
+        return None
+    tk, tn = (b, a) if form == 'rows_t' else (a, b)
+    while grouped_vmem_bytes(form, tm, tk, tn, itemsize) > \
+            GROUPED_VMEM_BUDGET_BYTES:
+        halves = [n % (2 * LANES) == 0 for n in (tk, tn)]
+        if halves[1] and (tn >= tk or not halves[0]):
+            tn //= 2
+        elif halves[0]:
+            tk //= 2
+        else:
+            return None
+    return tm, tk, tn
+
+
 def _counter() -> 'metrics_lib.Counter':
     return metrics_lib.REGISTRY.counter(
         'skyt_ops_kernel_path_total',
@@ -309,6 +400,28 @@ def moe_plan_snapshot() -> Dict[str, Any]:
     """The last traced expert layer's routing plan ({} if none)."""
     with _lock:
         return dict(_moe_plan)
+
+
+def record_grouped_plan(form: str, a: int, b: int,
+                        tiles: Tuple[int, int, int]) -> None:
+    """Remember the tiles a grouped product of the expert layer was
+    traced with, by its form and its matrix's extents, and stamp the
+    plan so far on the current trace span as `ops.grouped_plan`."""
+    with _lock:
+        _grouped_plan[(form, a, b)] = tuple(tiles)
+    from skypilot_tpu.utils import tracing
+    span = tracing.current_span()
+    if span is not None:
+        span.set_attribute('ops.grouped_plan', grouped_plan_line())
+
+
+def grouped_plan_line() -> str:
+    """The grouped products traced last, one `<form> <rows>x<contracted
+    or a>x<columns>` each ('' where the ladder stood on `ragged_dot`):
+    sft's `grouped tile plan:` line."""
+    with _lock:
+        return ', '.join(f'{form} ' + 'x'.join(map(str, tiles))
+                         for (form, _, _), tiles in _grouped_plan.items())
 
 
 def snapshot() -> Dict[str, str]:
@@ -405,3 +518,4 @@ def reset_for_tests() -> None:
         _paths.clear()
         _flash_plans.clear()
         _moe_plan.clear()
+        _grouped_plan.clear()

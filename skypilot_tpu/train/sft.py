@@ -449,6 +449,9 @@ def main(argv=None) -> None:
                     if moe_plan:
                         logger.info('moe routing plan: %s', ' '.join(
                             f'{k}={v}' for k, v in moe_plan.items()))
+                    grouped = ops_dispatch.grouped_plan_line()
+                    if grouped:
+                        logger.info('grouped tile plan: %s', grouped)
                 tokens_seen += args.batch * args.seq * jax.process_count()
                 if hb is not None:
                     live_state['step'] = step

@@ -295,8 +295,50 @@ class TestTrainStepFlash:
 class TestExpertLayerLowers:
     """The dropless expert layer's grouped products (ladder
     `moe_experts`) and the kind-table decoder's train step must lower
-    and run on the chip: the compiler's `ragged-dot` kernel forward and
-    backward, the rows past the groups' total undefined and masked."""
+    and run on the chip: the repo's Pallas kernels forward and
+    backward (ops/grouped_kernel.py, the rung the rule gives here),
+    the rows past the groups' total undefined and masked."""
+
+    @pytest.mark.parametrize('chunk,buffer_rows,d,width,groups,live', [
+        (34816, 139264, 2304, 896, 16, 32816),      # sft-swa-moe-16k
+        (8704, 69632, 2048, 1536, 8, 8192),         # sft-moe-8k
+    ])
+    def test_pallas_forms_match_ragged_dot_at_a_cells_call(
+            self, chunk, buffer_rows, d, width, groups, live):
+        """Each form at the tiles the rule gives, against the
+        compiler's kernel on the same operands: uneven groups, one
+        empty, the rows past `live` in none."""
+        from skypilot_tpu.ops import grouped_matmul
+
+        assert grouped_matmul._resolve_rung(
+            chunk, d, width, groups, jnp.bfloat16) == 'pallas'
+        cut = np.sort(np.random.default_rng(0).choice(
+            live, groups - 2, replace=False))
+        sizes = jnp.asarray(np.diff(np.concatenate(
+            [[0], cut[:3], cut[2:], [live]])), jnp.int32)
+        assert sizes.shape == (groups,) and int(sizes.sum()) == live
+
+        def on(products, form):
+            # a fresh set of products a trace: `_Pallas` keeps the
+            # visits it has made
+            return jax.jit(lambda *a: getattr(products(sizes), form)(*a))
+
+        def off(got, want):
+            got, want = (np.asarray(a, np.float32) for a in (got, want))
+            assert np.isfinite(got).all()
+            return np.linalg.norm(got - want) / np.linalg.norm(want)
+
+        for a, b in ((d, width), (width, d)):
+            w = _rand(1, (groups, a, b)) * a ** -0.5
+            x, y = _rand(2, (chunk, a)), _rand(3, (chunk, b))
+            for form, arg in (('rows', x), ('rows_t', y)):
+                got = on(grouped_matmul._Pallas, form)(arg, w)[:live]
+                want = on(grouped_matmul._Ragged, form)(arg, w)[:live]
+                assert off(got, want) < 1e-3, (form, a, b)
+            x, y = _rand(4, (buffer_rows, a)), _rand(5, (buffer_rows, b))
+            assert off(on(grouped_matmul._Pallas, 'over_rows')(x, y),
+                       on(grouped_matmul._Ragged, 'over_rows')(x, y)) \
+                < 1e-2, (a, b)
 
     def test_grouped_products_match_the_dense_masked_products(self):
         """A chunk as the layer's loop hands it over, 1,000 of its rows
@@ -333,7 +375,7 @@ class TestExpertLayerLowers:
 
         out = jax.jit(grouped_matmul.expert_ffn)(
             x, live, w_gate, w_up, w_down, sizes)
-        assert dispatch.snapshot()['moe_experts'] == 'ragged_dot'
+        assert dispatch.snapshot()['moe_experts'] == 'pallas'
         assert not bool(out[int(sizes.sum()):].any())
         want, pull = jax.vjp(jax.jit(dense), x, w_gate, w_up, w_down)
         close(out, want, 'forward')
@@ -398,7 +440,7 @@ class TestExpertLayerLowers:
         held, _, dropped, worked, worst = stats.tolist()
         assert dropped == 0 and worst == -(-2048 * 4 // chunk) * chunk
         assert 4096 <= held and 2 * chunk <= worked < held + chunk <= worst
-        assert dispatch.snapshot()['moe_experts'] == 'ragged_dot'
+        assert dispatch.snapshot()['moe_experts'] == 'pallas'
         off = {'out': (out, want), 'x': (grads[1], want_grads[1]), **{
             name: (grads[0][name], want_grads[0][name])
             for name in ('router', 'w_gate', 'w_up', 'w_down')}}
@@ -447,7 +489,7 @@ class TestExpertLayerLowers:
         assert 0 < int(metrics['moe_pairs_held']) < int(metrics['moe_pairs'])
         paths = dispatch.snapshot()
         assert paths['flash_attention'].startswith('pallas')
-        assert paths['moe_experts'] == 'ragged_dot'
+        assert paths['moe_experts'] == 'pallas'
 
 
 class TestPagedAttentionLowers:
