@@ -14,11 +14,18 @@ first family written this way: 30 of its 40 layers have no attention
 at all. Mellum2
 (huggingface.co/JetBrains/Mellum2-12B-A2.5B-Instruct, `mellum`) is the
 second: three window layers to every full one, YaRN on the full layers
-only, every feed-forward routed, the output head untied.
+only, every feed-forward routed, the output head untied. SDAR
+(huggingface.co/JetLM/SDAR-30B-A3B-Chat, `sdar_moe`) is the third: the
+Qwen3-MoE block in every layer, trained by block diffusion, which is a
+property of the configuration (`block_diffusion`) and not of a flag:
+the model then reads a row `[x_t | x_0]` of 2L positions under the
+block-diffusion mask and gives logits for the L noised ones
+(train/block_diffusion.py draws the noise and has the loss).
 
 Training only: there is no cache argument. Serving a convolution-state
 layer is ROADMAP work (the engine's cache holds K/V and nothing else).
 """
+import contextlib
 import dataclasses
 from typing import Optional, Tuple
 
@@ -35,6 +42,17 @@ FEED_FORWARDS = ('dense', 'experts')
 
 
 @dataclasses.dataclass(frozen=True)
+class BlockDiffusion:
+    """The block-diffusion objective (SDAR, arXiv:2510.06303; the
+    vectorised pass is BD3-LM's, arXiv:2503.09573): blocks of
+    `block_length` positions, each noised at its own level t, a
+    position masked with probability t (replaced by the mask's id, the
+    last row of the vocabulary held: `HybridConfig.mask_id`), the loss
+    weighted 1 / t (the linear schedule; train/block_diffusion.py)."""
+    block_length: int
+
+
+@dataclasses.dataclass(frozen=True)
 class HybridConfig:
     """`base` carries every width the shared modules read (hidden size,
     heads, the dense feed-forward's `mlp_dim`, norms, rope, vocabulary,
@@ -42,13 +60,17 @@ class HybridConfig:
     feed-forward) pair per layer. `window` is what a 'window_attention'
     layer sees (the query's own position and the window - 1 before it);
     `yarn` scales the rotary table of the 'attention' layers, and the
-    window layers keep the plain one."""
+    window layers keep the plain one. `block_diffusion`, where given,
+    is the objective the model is trained under: its 'attention' layers
+    then run the block-diffusion mask over rows of 2L positions and the
+    head is applied to the first L."""
     base: llama_lib.LlamaConfig
     layers: Tuple[Tuple[str, str], ...]
     conv_kernel: int = 3
     experts: Optional[moe_lib.ExpertsConfig] = None
     window: int = 0
     yarn: Optional[rope.Yarn] = None
+    block_diffusion: Optional[BlockDiffusion] = None
 
     def __post_init__(self):
         for op, ffn in self.layers:
@@ -60,6 +82,9 @@ class HybridConfig:
         if self.window <= 0 and any(
                 op == 'window_attention' for op, _ in self.layers):
             raise ValueError('window layers need a window')
+        if self.block_diffusion is not None and any(
+                op != 'attention' for op, _ in self.layers):
+            raise ValueError('block diffusion needs attention layers only')
 
     # What sft reads of a model's configuration.
     @property
@@ -73,6 +98,18 @@ class HybridConfig:
     @property
     def n_layers(self) -> int:
         return len(self.layers)
+
+    @property
+    def mask_id(self) -> int:
+        """Under block diffusion, the id a masked position holds: a row
+        of the vocabulary held that no data id reaches, the last one."""
+        return self.vocab_size - 1
+
+    @property
+    def data_vocab_size(self) -> int:
+        """Ids the data may hold: those under the mask's under block
+        diffusion."""
+        return self.vocab_size - (self.block_diffusion is not None)
 
     def num_params(self) -> int:
         """Analytic parameter count of what this configuration holds."""
@@ -148,6 +185,12 @@ class HybridBlock(nn.Module):
     @nn.compact
     def __call__(self, x, cos, sin, segment_ids=None):
         base = self.cfg.base
+        if self.cfg.block_diffusion is not None:
+            # What the layers read of the objective: the mask of the
+            # attention.
+            base = dataclasses.replace(
+                base,
+                attn_block_diffusion=self.cfg.block_diffusion.block_length)
         operator, ffn = self.kind
         h = llama_lib.RMSNorm(base, name='op_norm')(x)
         if operator == 'attention':
@@ -177,10 +220,16 @@ class HybridModel(nn.Module):
     cfg: HybridConfig
 
     @nn.compact
-    def __call__(self, tokens, positions=None, segment_ids=None):
+    def __call__(self, tokens, positions=None, segment_ids=None,
+                 loss_of=None):
         """tokens: [B, S] int32 -> logits [B, S, vocab] (compute dtype).
         Sows `moe_stats`, the routing counters of trainer.MOE_STAT_KEYS
-        over all expert layers, for the trainer's step metrics."""
+        over all expert layers, for the trainer's step metrics. Under
+        block diffusion the row is `[x_t | x_0]` (S = 2L, `positions`
+        the same ids for both halves) and the logits are [B, L, vocab],
+        of the noised half; `loss_of`, where given, is applied to them
+        beside the head and its result returned in their place, so that
+        head and loss stand under one scope of a trace."""
         cfg, base = self.cfg, self.cfg.base
         dtype = jnp.dtype(base.dtype)
         b, s = tokens.shape
@@ -236,15 +285,24 @@ class HybridModel(nn.Module):
                     fullest * (ex.num_experts / pairs),
                 'moe_pairs_dropped': dropped,
                 'moe_rows': rows, 'moe_rows_worst': worst})
-        x = llama_lib.RMSNorm(base, name='final_norm')(x)
-        if base.tie_embeddings:
-            logits = jnp.einsum('bsd,vd->bsv', x, embed.astype(dtype))
-        else:
-            logits = llama_lib._dense(
-                base.vocab_size, ('embed', 'vocab'), 'lm_head',
-                base.param_dtype, dtype)(x)
-        return nn.with_logical_constraint(
-            logits, ('act_batch', 'act_seq', 'act_vocab'))
+        with contextlib.ExitStack() as scopes:
+            if cfg.block_diffusion is not None:
+                # The objective's part of the pass: the head over the
+                # noised half alone, and the loss the caller gives
+                # (train/block_diffusion.loss_given_noise).
+                scopes.enter_context(jax.named_scope('bd_objective'))
+                scopes.enter_context(jax.named_scope('bd_loss'))
+                x = x[:, :s // 2]
+            x = llama_lib.RMSNorm(base, name='final_norm')(x)
+            if base.tie_embeddings:
+                logits = jnp.einsum('bsd,vd->bsv', x, embed.astype(dtype))
+            else:
+                logits = llama_lib._dense(
+                    base.vocab_size, ('embed', 'vocab'), 'lm_head',
+                    base.param_dtype, dtype)(x)
+            logits = nn.with_logical_constraint(
+                logits, ('act_batch', 'act_seq', 'act_vocab'))
+            return logits if loss_of is None else loss_of(logits)
 
 
 def _lfm2(layer_types, num_dense, experts, **base):
@@ -277,6 +335,19 @@ def _mellum(layer_types, experts, window, yarn, **base):
         experts=experts, window=window, yarn=yarn)
 
 
+def _sdar(n_layers, experts, block_length, **base):
+    """SDAR-MoE's layout: the Qwen3-MoE block in every layer (full
+    attention with per-head q/k norm, routed experts; `mlp_only_layers`
+    empty, `decoder_sparse_step` 1), the output head untied, the plain
+    rotary rule; trained by block diffusion."""
+    return HybridConfig(
+        base=llama_lib.LlamaConfig(
+            use_llama31_rope=False, norm_eps=1e-6, tie_embeddings=False,
+            qk_norm=True, **base),
+        layers=(('attention', 'experts'),) * n_layers, experts=experts,
+        block_diffusion=BlockDiffusion(block_length))
+
+
 _LFM2_PERIOD = ('full_attention', 'conv', 'conv', 'conv')
 _MELLUM2_PERIOD = ('sliding_attention',) * 3 + ('full_attention',)
 _MELLUM2_12B = dict(
@@ -286,6 +357,9 @@ _MELLUM2_12B = dict(
 _MELLUM2_YARN = rope.Yarn(factor=16.0, original_max_position=8192,
                           beta_fast=32.0, beta_slow=1.0,
                           attention_factor=1.2772588722239782)
+_SDAR_30B = dict(vocab_size=151936, dim=2048, n_heads=32, n_kv_heads=4,
+                 head_dim_override=128, mlp_dim=6144, max_seq_len=32768,
+                 rope_theta=1e6)
 _LFM2_24B = dict(vocab_size=65536, dim=2048, n_heads=32, n_kv_heads=8,
                  mlp_dim=11776, max_seq_len=128000, rope_theta=1e6)
 
@@ -342,4 +416,26 @@ CONFIGS = {
         moe_lib.ExpertsConfig(64, 8, 896, scoring='softmax',
                               held=(0, 16)), 1024,
         _MELLUM2_YARN, **{**_MELLUM2_12B, 'vocab_size': 24576}),
+    # Tests: the structure of SDAR-MoE at toy widths (two layers of full
+    # attention and softmax-routed experts, an untied head, blocks of 4).
+    'debug-sdar': _sdar(
+        2, moe_lib.ExpertsConfig(16, 4, 48, scoring='softmax'), 4,
+        vocab_size=256, dim=64, n_heads=4, n_kv_heads=2,
+        head_dim_override=32, mlp_dim=128, max_seq_len=128,
+        rope_theta=1e6, dtype='float32', remat=False),
+    # SDAR-30B-A3B-Chat as published (config.json): 48 layers, 128
+    # experts of width 768, eight a token, softmax-routed; head size 128
+    # at hidden 2,048. `intermediate_size` (mlp_dim) is published and
+    # unused: no layer is dense. The block length is the family's
+    # (config.json has no key for it).
+    'sdar-30b-a3b': _sdar(
+        48, moe_lib.ExpertsConfig(128, 8, 768, scoring='softmax'), 4,
+        **_SDAR_30B),
+    # One chip's share of it with eight chips sharing each layer
+    # (chipbench/configs/sdar-30b-a3b-ep8-sft.json): experts 0-15 of
+    # 128, an eighth of each vocabulary matrix, six layers.
+    'sdar-30b-a3b-ep8': _sdar(
+        6, moe_lib.ExpertsConfig(128, 8, 768, scoring='softmax',
+                                 held=(0, 16)), 4,
+        **{**_SDAR_30B, 'vocab_size': 18992}),
 }

@@ -88,6 +88,10 @@ class LlamaConfig:
     # an I/O-only knob (models/weights.py splits on load, fuses on
     # save); the module math is identical.
     hf_layout: str = 'llama'
+    # Block diffusion (models/hybrid.py sets it from its objective):
+    # > 0 is the block length of the block-diffusion mask over a row
+    # `[x_t | x_0]`, in place of the causal one (ops/attention.py).
+    attn_block_diffusion: int = 0
 
     @property
     def head_dim(self) -> int:
@@ -633,7 +637,8 @@ class LlamaAttention(nn.Module):
                 impl=cfg.attn_impl, window=window,
                 window_active=window_active,
                 logit_softcap=cfg.attn_softcap,
-                softmax_scale=cfg.attn_scale or None)
+                softmax_scale=cfg.attn_scale or None,
+                block_diffusion=cfg.attn_block_diffusion)
         out = out.reshape(b, s, h * hd)
         out = proj('wo', cfg.dim, ('heads', 'embed'), out)
         return nn.with_logical_constraint(

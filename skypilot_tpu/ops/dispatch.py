@@ -64,13 +64,16 @@ _lock = threading.Lock()
 _paths: Dict[str, str] = {}
 # flash kernel -> the tile plan of the shape traced last (trace-time);
 # logged by sft after its first step. A kernel traced with a sliding
-# window is filed as `window_<kernel>`, beside the full-attention plan.
+# window is filed as `window_<kernel>`, one traced with the
+# block-diffusion mask as `bd_<kernel>`, beside the full-attention plan.
 _flash_plans: Dict[str, Dict[str, Any]] = {}
-# The window `flash_blocks` planned for last on this thread. A flash
-# kernel asks for its tiles and records them in one breath
-# (flash_attention._plan), and only the first of the two calls is told
-# the window.
+# The mask `flash_blocks` planned for last on this thread ('window_',
+# 'bd_' or ''). A flash kernel asks for its tiles and records them in
+# one breath (flash_attention._plan), and only the first of the two
+# calls is told the mask.
 _planned = threading.local()
+# The block-diffusion objective a train step was traced with.
+_bd_plan: Dict[str, Any] = {}
 # The routing plan of the expert layer traced last (trace-time).
 _moe_plan: Dict[str, Any] = {}
 # (form, a, b) of a grouped product -> the tiles it was traced with.
@@ -147,7 +150,8 @@ def clamp_flash_blocks(sq: int, sk: int, want_q: int, want_k: int,
 
 def flash_blocks(sq: int, sk: int, d: int, q_dtype, has_seg: bool,
                  window: int = 0,
-                 want: Optional[Tuple[int, int]] = None
+                 want: Optional[Tuple[int, int]] = None,
+                 block_diffusion: bool = False
                  ) -> Dict[str, Tuple[int, int]]:
     """The tile rule: kernel ('fwd', 'dq', 'dkv') -> (block_q, block_k).
 
@@ -158,10 +162,15 @@ def flash_blocks(sq: int, sk: int, d: int, q_dtype, has_seg: bool,
     (short, odd and decode shapes get a legal divisor or the full dim,
     never a tile larger than the sequence). A request (`want`: the
     tests' `flash_attention(block_q=, block_k=)`) is clamped the same
-    way and given to all three kernels."""
+    way and given to all three kernels.
+
+    Under the block-diffusion mask (sq = sk = 2L) an extent divides L,
+    so that a tile lies in one quadrant of the square: the same maxima,
+    clamped to a legal divisor of L, or the whole 2L where L has none."""
     import jax.numpy as jnp
     itemsize = jnp.dtype(q_dtype).itemsize
-    _planned.window = window
+    _planned.prefix = ('bd_' if block_diffusion else
+                       'window_' if window > 0 else '')
     plan = {}
     for kernel in FLASH_KERNELS:
         wq, wk = want or _FLASH_MAX_BLOCKS[kernel]
@@ -178,6 +187,14 @@ def flash_blocks(sq: int, sk: int, d: int, q_dtype, has_seg: bool,
                     wq //= 2
                 else:
                     wk //= 2
+        if block_diffusion:
+            bq, bk = clamp_flash_blocks(sq // 2, sk // 2, wq, wk, q_dtype,
+                                        has_seg, kernel)
+            legal = clamp_flash_blocks(sq, sk, bq, bk, q_dtype, has_seg,
+                                       kernel)
+            plan[kernel] = (bq if legal[0] == bq else sq,
+                            bk if legal[1] == bk else sk)
+            continue
         plan[kernel] = clamp_flash_blocks(sq, sk, wq, wk, q_dtype,
                                           has_seg, kernel)
     return plan
@@ -342,10 +359,10 @@ def record_flash_plan(kernel: str, plan: Dict[str, Any]) -> None:
     """Remember the tile plan a flash kernel was traced with (extents
     and, per head, tiles visited, masked and skipped) and stamp it on
     the current trace span, beside `ops.path.flash_attention`. Planned
-    for a sliding window (the `flash_blocks` call before this one), it
-    is the plan of `window_<kernel>`."""
-    if getattr(_planned, 'window', 0) > 0:
-        kernel = f'window_{kernel}'
+    for a sliding window or the block-diffusion mask (the `flash_blocks`
+    call before this one), it is the plan of `window_<kernel>` or
+    `bd_<kernel>`."""
+    kernel = getattr(_planned, 'prefix', '') + kernel
     with _lock:
         _flash_plans[kernel] = dict(plan)
     from skypilot_tpu.utils import tracing
@@ -381,19 +398,38 @@ def moe_chunk_rows(tokens: int, k: int, held: int, experts: int) -> int:
     return min(chunk, -(-tokens * min(k, held) // 8) * 8)
 
 
+def _record_plan(store: Dict[str, Any], attribute: str,
+                 plan: Dict[str, Any]) -> None:
+    with _lock:
+        store.clear()
+        store.update(plan)
+    from skypilot_tpu.utils import tracing
+    span = tracing.current_span()
+    if span is not None:
+        span.set_attribute(
+            attribute, ' '.join(f'{k}={v}' for k, v in plan.items()))
+
+
 def record_moe_plan(plan: Dict[str, Any]) -> None:
     """Remember the routing plan an expert layer was traced with
     (experts routed over and held, k, tokens, the worst-case rows and
     the loop's chunk) and stamp it on the current trace span as
     `ops.moe_plan`."""
+    _record_plan(_moe_plan, 'ops.moe_plan', plan)
+
+
+def record_bd_plan(plan: Dict[str, Any]) -> None:
+    """Remember the block-diffusion objective a train step was traced
+    with (block length, the data's length, the positions of the pass,
+    the allowed pairs a head, the mask id) and stamp it on the current
+    trace span as `ops.bd_plan`."""
+    _record_plan(_bd_plan, 'ops.bd_plan', plan)
+
+
+def bd_plan_snapshot() -> Dict[str, Any]:
+    """The last traced block-diffusion objective ({} if none)."""
     with _lock:
-        _moe_plan.clear()
-        _moe_plan.update(plan)
-    from skypilot_tpu.utils import tracing
-    span = tracing.current_span()
-    if span is not None:
-        span.set_attribute(
-            'ops.moe_plan', ' '.join(f'{k}={v}' for k, v in plan.items()))
+        return dict(_bd_plan)
 
 
 def moe_plan_snapshot() -> Dict[str, Any]:
@@ -518,4 +554,5 @@ def reset_for_tests() -> None:
         _paths.clear()
         _flash_plans.clear()
         _moe_plan.clear()
+        _bd_plan.clear()
         _grouped_plan.clear()

@@ -30,6 +30,17 @@ or the window's edge crosses it: iota, compare, select) or *plain*
 (wholly allowed: none of that). With segment ids every visited tile is
 masked. The plan is recorded at trace time (dispatch.record_flash_plan).
 
+The block-diffusion mask (`bd = (L, B)`: the row is `[x_t | x_0]`, L
+noised positions then their L clean copies, in blocks of B) is the one
+mask whose visited tiles are not one run a row of tiles: a noised query
+block visits its own noised blocks and, after a gap, the clean blocks
+before it; a clean key block is visited by the noised query blocks after
+it and by the clean ones from it on. The grid is the same; the index
+maps name, for a skipped step, the nearer end of the run before or
+after it (`_two_runs`), so nothing is copied in for it. The extents the
+tile rule gives divide L (or are the whole 2L), so a tile lies in one
+quadrant of the square.
+
 Kernel conventions follow /opt/skills/guides/pallas_guide.md (block
 specs, scratch via pl.pallas_call scratch_shapes, MXU-aligned tiles).
 """
@@ -56,13 +67,42 @@ _NT = (((1,), (1,)), ((), ()))    # a @ b^T: contract the last dims
 _NN = (((1,), (0,)), ((), ()))    # a @ b
 
 
+def _shift(x, n):
+    """x // n for non-negative x (a shift where n is a power of two)."""
+    if n & (n - 1) == 0:
+        return x >> (n.bit_length() - 1)
+    return jax.lax.div(x, jnp.int32(n))
+
+
+def _bd_codes(start, n, axis, bd, query):
+    """The block-diffusion rule as two compares: a key's code is its
+    block, plus L where it is noised; a query allows the codes under
+    `below` (the clean blocks before its own if it is noised, up to its
+    own if it is clean) and the code `own` (its own noised block; none
+    for a clean query). Computed on a column or a row of the tile."""
+    half, block = bd
+    g = start + jax.lax.broadcasted_iota(
+        jnp.int32, (n, 1) if axis == 0 else (1, n), axis)
+    noised = g < half
+    blk = _shift(jnp.where(noised, g, g - half), block)
+    if not query:
+        return jnp.where(noised, blk + half, blk)
+    return jnp.where(noised, blk, blk + 1), jnp.where(noised, blk + half, -1)
+
+
 def _block_mask(s, q_start, k_start, causal, window, q_seg, k_seg,
-                q_axis=0):
-    """Apply causal / sliding-window / segment masking to a score
-    block whose queries run along `q_axis` (0: [block_q, block_k]; 1:
-    the dk/dv kernel's transposed [block_k, block_q]). window > 0
-    (Mistral, every other Gemma-2 layer, Phi-3): query p also requires
-    p - k_pos < window. Returns the masked scores."""
+                q_axis=0, bd=()):
+    """Apply causal / sliding-window / segment / block-diffusion
+    masking to a score block whose queries run along `q_axis` (0:
+    [block_q, block_k]; 1: the dk/dv kernel's transposed [block_k,
+    block_q]). window > 0 (Mistral, every other Gemma-2 layer, Phi-3):
+    query p also requires p - k_pos < window. Returns the masked
+    scores."""
+    if bd:
+        below, own = _bd_codes(q_start, s.shape[q_axis], q_axis, bd, True)
+        code = _bd_codes(k_start, s.shape[1 - q_axis], 1 - q_axis, bd,
+                         False)
+        s = jnp.where((code < below) | (code == own), s, NEG_INF)
     if causal or window > 0:
         # q_pos - k_pos of every entry.
         rel = (jax.lax.broadcasted_iota(jnp.int32, s.shape, q_axis) -
@@ -79,11 +119,38 @@ def _block_mask(s, q_start, k_start, causal, window, q_seg, k_seg,
     return s
 
 
-def _tile_visited(qi, ki, block_q, block_k, causal, window):
+def _bd_tile(qi, ki, block_q, block_k, bd):
+    """(visited, plain) of a tile under the block-diffusion mask: has
+    it any allowed entry, and is every entry allowed. A tile lies in
+    one quadrant (its extents divide L), or an extent is the whole 2L
+    and every tile is visited and masked. Python ints or traced."""
+    half, block = bd
+    if block_q > half or block_k > half:
+        return True, False
+    q0, k0 = qi * block_q, ki * block_k
+    q_noised, q_clean = q0 < half, q0 >= half
+    k_noised, k_clean = k0 < half, k0 >= half
+    # First and last block of the tile's queries and of its keys.
+    qa, qz = (q0 % half) // block, (q0 % half + block_q - 1) // block
+    ka, kz = (k0 % half) // block, (k0 % half + block_k - 1) // block
+    k_first, k_last = k0 % half, k0 % half + block_k - 1
+    visited = ((q_noised & k_noised & (qa <= kz) & (ka <= qz)) |
+               (q_noised & k_clean & (k_first < qz * block)) |
+               (q_clean & k_clean & (k_first < (qz + 1) * block)))
+    plain = ((q_noised & k_noised & (qa == qz) & (ka == kz) & (qa == ka)) |
+             (q_noised & k_clean & (k_last < qa * block)) |
+             (q_clean & k_clean & (k_last < (qa + 1) * block)))
+    return visited, plain
+
+
+def _tile_visited(qi, ki, block_q, block_k, causal, window, bd=()):
     """Does this (q block, k block) pair contain ANY unmasked (q, k)
-    entry under causal+window? The others are skipped whole: above the
-    diagonal (causal) and, with a window, entirely below it. Python
-    ints (the trace-time tile count) or traced scalars (the kernels)."""
+    entry under causal+window, or under the block-diffusion mask? The
+    others are skipped whole: above the diagonal (causal) and, with a
+    window, entirely below it. Python ints (the trace-time tile count)
+    or traced scalars (the kernels)."""
+    if bd:
+        return _bd_tile(qi, ki, block_q, block_k, bd)[0]
     cond = True
     if causal:
         cond = cond & (ki * block_k < (qi + 1) * block_q)
@@ -94,9 +161,12 @@ def _tile_visited(qi, ki, block_q, block_k, causal, window):
     return cond
 
 
-def _tile_crossed(qi, ki, block_q, block_k, causal, window):
+def _tile_crossed(qi, ki, block_q, block_k, causal, window, bd=()):
     """Does the pair contain ANY masked entry under causal+window: does
-    the diagonal, or the window's lower edge, cross the tile?"""
+    the diagonal, or the window's lower edge, cross the tile? Under the
+    block-diffusion mask: is any entry of it not allowed?"""
+    if bd:
+        return _bd_tile(qi, ki, block_q, block_k, bd)[1] ^ True
     cond = False
     if causal:
         cond = cond | ((ki + 1) * block_k - 1 > qi * block_q)
@@ -129,38 +199,112 @@ def _clamp(i, lo, hi):
     return jnp.minimum(jnp.maximum(i, lo), hi)
 
 
-def tile_counts(sq, sk, block_q, block_k, causal, window, has_seg):
-    """Tiles of one head the plan visits, masks and skips."""
+def _two_runs(i, lo1, hi1, lo2, hi2):
+    """Block `i` of a walk that visits [lo1, hi1] and then [lo2, hi2]
+    (either may be empty, lo > hi, not both): itself where it is
+    visited, else the nearer end of the run before or after it, which
+    is resident then."""
+    lo1, hi1, lo2, hi2 = (jnp.where(lo1 > hi1, lo2, lo1),
+                          jnp.where(lo1 > hi1, hi2, hi1),
+                          jnp.where(lo2 > hi2, lo1, lo2),
+                          jnp.where(lo2 > hi2, hi1, hi2))
+    return jnp.where(i < lo2, _clamp(i, lo1, hi1), _clamp(i, lo2, hi2))
+
+
+def _bd_k_block(qi, ki, block_q, block_k, bd):
+    """The k block a grid step of q block `qi` names under the
+    block-diffusion mask (traced). A noised query block visits the
+    noised blocks of its own positions, then the clean blocks before
+    its last one; a clean query block the clean blocks up to its last
+    one."""
+    half, block = bd
+    if block_q > half or block_k > half:
+        return ki
+    per_half = half // block_k
+    q0 = qi * block_q
+    first = q0 % half
+    last_blk = (first + block_q - 1) // block
+    noised = q0 < half
+    own_lo = first // block * block // block_k
+    own_hi = ((last_blk + 1) * block - 1) // block_k
+    # Clean keys a query of the tile may see: positions under `upto`.
+    upto = jnp.where(noised, last_blk * block, (last_blk + 1) * block)
+    clean_hi = per_half + (upto - 1) // block_k    # upto 0: under per_half
+    return _two_runs(ki, jnp.where(noised, own_lo, 1),
+                     jnp.where(noised, own_hi, 0), per_half, clean_hi)
+
+
+def _bd_q_block(ki, qi, block_q, block_k, bd):
+    """The q block a grid step of k block `ki` names under the
+    block-diffusion mask (traced). A noised key block is visited by the
+    noised blocks of its own positions; a clean one by the noised
+    blocks after its first block, and by the clean ones from it on."""
+    half, block = bd
+    if block_q > half or block_k > half:
+        return qi
+    per_half = half // block_q
+    k0 = ki * block_k
+    first = k0 % half
+    first_blk = first // block
+    noised = k0 < half
+    own_lo = first_blk * block // block_q
+    own_hi = (((first + block_k - 1) // block + 1) * block - 1) // block_q
+    after = (first_blk + 1) * block // block_q     # past the half: none
+    return _two_runs(
+        qi, jnp.where(noised, own_lo, after),
+        jnp.where(noised, own_hi, per_half - 1),
+        jnp.where(noised, 1, per_half + own_lo),
+        jnp.where(noised, 0, 2 * per_half - 1))
+
+
+def allowed_pairs(half: int, block: int) -> int:
+    """(query, key) pairs a head computes under the block-diffusion
+    mask: clean to clean L(L + B)/2, noised to clean L(L - B)/2, a
+    noised block to itself L * B."""
+    return half * half + half * block
+
+
+def tile_counts(sq, sk, block_q, block_k, causal, window, has_seg,
+                bd=()):
+    """Tiles of one head the plan visits, masks and skips; under the
+    block-diffusion mask also `needed`, the allowed pairs in tiles."""
     visited = masked = 0
     nq, nk = sq // block_q, sk // block_k
     for qi in range(nq):
         for ki in range(nk):
-            if _tile_visited(qi, ki, block_q, block_k, causal, window):
+            if _tile_visited(qi, ki, block_q, block_k, causal, window, bd):
                 visited += 1
                 masked += bool(has_seg or _tile_crossed(
-                    qi, ki, block_q, block_k, causal, window))
-    return {'visited': visited, 'masked': masked,
-            'skipped': nq * nk - visited}
+                    qi, ki, block_q, block_k, causal, window, bd))
+    counts = {'visited': visited, 'masked': masked,
+              'skipped': nq * nk - visited}
+    if bd:
+        counts['needed'] = round(allowed_pairs(*bd) / (block_q * block_k), 2)
+    return counts
 
 
-def _for_tile(qi, ki, block_q, block_k, causal, window, has_seg, tile):
+def _for_tile(qi, ki, block_q, block_k, causal, window, has_seg, tile,
+              bd=()):
     """Run `tile(masked)` for the grid step's tile: not at all where it
     is skipped, with the mask work only where a mask can bite."""
-    if not (causal or window > 0):
+    if not (causal or window > 0 or bd):
         tile(has_seg)
         return
-    visited = _tile_visited(qi, ki, block_q, block_k, causal, window)
+    visited = _tile_visited(qi, ki, block_q, block_k, causal, window, bd)
+    if visited is True:     # block diffusion at a whole-sequence extent
+        tile(True)
+        return
     if has_seg:
         pl.when(visited)(lambda: tile(True))
         return
-    crossed = _tile_crossed(qi, ki, block_q, block_k, causal, window)
+    crossed = _tile_crossed(qi, ki, block_q, block_k, causal, window, bd)
     pl.when(visited & crossed)(lambda: tile(True))
     pl.when(visited & jnp.logical_not(crossed))(lambda: tile(False))
 
 
 def _fwd_kernel(*refs, scale: float, causal: bool, window: int,
                 block_q: int, block_k: int, num_k_blocks: int,
-                has_seg: bool):
+                has_seg: bool, bd: tuple = ()):
     if has_seg:
         (q_ref, k_ref, v_ref, q_seg_ref, k_seg_ref,
          o_ref, lse_ref, m_scr, l_scr, acc_scr) = refs
@@ -184,7 +328,7 @@ def _fwd_kernel(*refs, scale: float, causal: bool, window: int,
             s = _block_mask(
                 s, qi * block_q, ki * block_k, causal, window,
                 q_seg_ref[0, 0] if has_seg else None,
-                k_seg_ref[0, 0] if has_seg else None)
+                k_seg_ref[0, 0] if has_seg else None, bd=bd)
         m_prev = m_scr[:]                 # [bq, 1]
         m_cur = jnp.max(s, axis=1, keepdims=True)
         m_new = jnp.maximum(m_prev, m_cur)
@@ -205,7 +349,7 @@ def _fwd_kernel(*refs, scale: float, causal: bool, window: int,
         m_scr[:] = m_new
         l_scr[:] = l_new
 
-    _for_tile(qi, ki, block_q, block_k, causal, window, has_seg, _tile)
+    _for_tile(qi, ki, block_q, block_k, causal, window, has_seg, _tile, bd)
 
     @pl.when(ki == num_k_blocks - 1)
     def _finalize():
@@ -220,7 +364,7 @@ def _fwd_kernel(*refs, scale: float, causal: bool, window: int,
 
 def _dq_kernel(*refs, scale: float, causal: bool, window: int,
                block_q: int, block_k: int, num_k_blocks: int,
-               has_seg: bool):
+               has_seg: bool, bd: tuple = ()):
     if has_seg:
         (q_ref, k_ref, v_ref, do_ref, o_ref, lse_ref,
          q_seg_ref, k_seg_ref, dq_ref, dq_scr, delta_scr) = refs
@@ -247,7 +391,7 @@ def _dq_kernel(*refs, scale: float, causal: bool, window: int,
             s = _block_mask(
                 s, qi * block_q, ki * block_k, causal, window,
                 q_seg_ref[0, 0] if has_seg else None,
-                k_seg_ref[0, 0] if has_seg else None)
+                k_seg_ref[0, 0] if has_seg else None, bd=bd)
         lse = lse_ref[0, 0][:, :1]        # [bq, 1] (lane-replicated)
         p = jnp.exp(s - lse)              # [bq, bk]
         dp = jax.lax.dot_general(
@@ -258,7 +402,7 @@ def _dq_kernel(*refs, scale: float, causal: bool, window: int,
             ds.astype(k.dtype), k, _NN,
             preferred_element_type=jnp.float32)
 
-    _for_tile(qi, ki, block_q, block_k, causal, window, has_seg, _tile)
+    _for_tile(qi, ki, block_q, block_k, causal, window, has_seg, _tile, bd)
 
     @pl.when(ki == num_k_blocks - 1)
     def _finalize():
@@ -267,7 +411,7 @@ def _dq_kernel(*refs, scale: float, causal: bool, window: int,
 
 def _dkv_kernel(*refs, scale: float, causal: bool, window: int,
                 block_q: int, block_k: int, num_q_blocks: int,
-                has_seg: bool):
+                has_seg: bool, bd: tuple = ()):
     if has_seg:
         (q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
          q_seg_ref, k_seg_ref, dk_ref, dv_ref, dk_scr, dv_scr) = refs
@@ -295,7 +439,7 @@ def _dkv_kernel(*refs, scale: float, causal: bool, window: int,
             st = _block_mask(
                 st, qi * block_q, ki * block_k, causal, window,
                 q_seg_ref[0, 0] if has_seg else None,
-                k_seg_ref[0, 0] if has_seg else None, q_axis=1)
+                k_seg_ref[0, 0] if has_seg else None, q_axis=1, bd=bd)
         pt = jnp.exp(st - lse_ref[0, 0])  # [bk, bq] - [1, bq]
         dv_scr[:] += jax.lax.dot_general(
             pt.astype(do.dtype), do, _NN,
@@ -308,7 +452,7 @@ def _dkv_kernel(*refs, scale: float, causal: bool, window: int,
             dst.astype(q.dtype), q, _NN,
             preferred_element_type=jnp.float32)  # [bk, d]
 
-    _for_tile(qi, ki, block_q, block_k, causal, window, has_seg, _tile)
+    _for_tile(qi, ki, block_q, block_k, causal, window, has_seg, _tile, bd)
 
     @pl.when(qi == num_q_blocks - 1)
     def _finalize():
@@ -335,7 +479,8 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array,
                     segment_ids: Optional[jax.Array] = None,
                     block_q: Optional[int] = None,
                     block_k: Optional[int] = None,
-                    window: int = 0) -> jax.Array:
+                    window: int = 0,
+                    block_diffusion: int = 0) -> jax.Array:
     """q: [B, Sq, Hq, D]; k, v: [B, Sk, Hkv, D] -> [B, Sq, Hq, D].
 
     With no block_q/block_k the tile rule (dispatch.flash_blocks)
@@ -352,19 +497,36 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array,
     window: sliding-window attention (> 0: query p sees k in
     (p - window, p]). Out-of-window tiles are skipped like the tiles
     above the causal diagonal: no compute and no fetch.
+    block_diffusion: the block length B (> 0) of the block-diffusion
+    mask over a row `[x_t | x_0]` of Sq = Sk = 2L positions (the module
+    docstring and ops/attention.block_diffusion_allowed have the rule);
+    `causal` is not read, and segment ids and a window do not combine
+    with it.
     """
+    bd = ()
+    if block_diffusion > 0:
+        half = q.shape[1] // 2
+        if (segment_ids is not None or window > 0 or
+                q.shape[1] != k.shape[1] or q.shape[1] % 2 or
+                half % block_diffusion):
+            raise ValueError(
+                f'the block-diffusion mask needs Sq = Sk = 2L with L a '
+                f'multiple of the block {block_diffusion}, no segment '
+                f'ids and no window; got Sq {q.shape[1]}, Sk {k.shape[1]}')
+        bd, causal = (half, block_diffusion), False
     return _flash(q, k, v, segment_ids, causal, block_q, block_k,
-                  window)
+                  window, bd)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7))
-def _flash(q, k, v, segment_ids, causal, block_q, block_k, window):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7, 8))
+def _flash(q, k, v, segment_ids, causal, block_q, block_k, window, bd=()):
     out, _ = _flash_fwd_impl(q, k, v, segment_ids, causal, block_q,
-                             block_k, window)
+                             block_k, window, bd)
     return out
 
 
-def _plan(q, k, block_q, block_k, has_seg, causal, window, kernels):
+def _plan(q, k, block_q, block_k, has_seg, causal, window, kernels,
+          bd=()):
     """Shape checks, then (tile plan, `vmem_limit_bytes`) of `kernels`
     (docs/kernels.md): extents from the shape rule, or the requested
     blocks CLAMPED through the divisibility-safe selector — to a
@@ -383,7 +545,8 @@ def _plan(q, k, block_q, block_k, has_seg, causal, window, kernels):
     want = None
     if block_q is not None or block_k is not None:
         want = (block_q or sq, block_k or sk)
-    plan = dispatch.flash_blocks(sq, sk, d, q.dtype, has_seg, window, want)
+    plan = dispatch.flash_blocks(sq, sk, d, q.dtype, has_seg, window, want,
+                                 block_diffusion=bool(bd))
     limits = {}
     for kernel in kernels:
         bq, bk = plan[kernel]
@@ -399,7 +562,7 @@ def _plan(q, k, block_q, block_k, has_seg, causal, window, kernels):
         limits[kernel] = dispatch.flash_vmem_limit(need)
         dispatch.record_flash_plan(kernel, {
             'block_q': bq, 'block_k': bk,
-            **tile_counts(sq, sk, bq, bk, causal, window, has_seg)})
+            **tile_counts(sq, sk, bq, bk, causal, window, has_seg, bd)})
     return plan, limits
 
 
@@ -411,10 +574,10 @@ def _params(vmem_limit):
 
 
 def _flash_fwd_impl(q, k, v, segment_ids, causal, block_q, block_k,
-                    window=0):
+                    window=0, bd=()):
     has_seg = segment_ids is not None
     plan, limits = _plan(q, k, block_q, block_k, has_seg, causal, window,
-                         ('fwd',))
+                         ('fwd',), bd)
     block_q, block_k = plan['fwd']
     b, sq, hq, d = q.shape
     sk, hkv = k.shape[1:3]
@@ -429,11 +592,13 @@ def _flash_fwd_impl(q, k, v, segment_ids, causal, block_q, block_k,
     kernel = functools.partial(
         _fwd_kernel, scale=d ** -0.5, causal=causal, window=window,
         block_q=block_q, block_k=block_k, num_k_blocks=nk,
-        has_seg=has_seg)
+        has_seg=has_seg, bd=bd)
 
     def kc(qi, ki):
         # A skipped step names the nearest visited k block: already
         # resident, so nothing is copied in for it.
+        if bd:
+            return _bd_k_block(qi, ki, block_q, block_k, bd)
         return _clamp(ki, *_visited_k_blocks(qi, block_q, block_k, nk,
                                              causal, window))
 
@@ -482,17 +647,18 @@ def _flash_fwd_impl(q, k, v, segment_ids, causal, block_q, block_k,
     return out.transpose(0, 2, 1, 3), lse
 
 
-def _fwd_rule(q, k, v, segment_ids, causal, block_q, block_k, window):
+def _fwd_rule(q, k, v, segment_ids, causal, block_q, block_k, window,
+              bd=()):
     out, lse = _flash_fwd_impl(q, k, v, segment_ids, causal, block_q,
-                               block_k, window)
+                               block_k, window, bd)
     return out, (q, k, v, segment_ids, out, lse)
 
 
-def _bwd_rule(causal, block_q, block_k, window, res, g):
+def _bwd_rule(causal, block_q, block_k, window, bd, res, g):
     q, k, v, segment_ids, out, lse = res
     has_seg = segment_ids is not None
     plan, limits = _plan(q, k, block_q, block_k, has_seg, causal, window,
-                         ('dq', 'dkv'))
+                         ('dq', 'dkv'), bd)
     b, sq, hq, d = q.shape
     sk, hkv = k.shape[1:3]
     group = hq // hkv
@@ -510,6 +676,8 @@ def _bwd_rule(causal, block_q, block_k, window, res, g):
     nq, nk = sq // bq, sk // bk
 
     def kc(qi, ki):
+        if bd:
+            return _bd_k_block(qi, ki, bq, bk, bd)
         return _clamp(ki, *_visited_k_blocks(qi, bq, bk, nk, causal,
                                              window))
 
@@ -532,7 +700,8 @@ def _bwd_rule(causal, block_q, block_k, window, res, g):
     dqt = pl.pallas_call(
         functools.partial(
             _dq_kernel, scale=scale, causal=causal, window=window,
-            block_q=bq, block_k=bk, num_k_blocks=nk, has_seg=has_seg),
+            block_q=bq, block_k=bk, num_k_blocks=nk, has_seg=has_seg,
+            bd=bd),
         grid=(b, hq, nq, nk),
         in_specs=in_specs,
         out_specs=q_spec,
@@ -553,6 +722,8 @@ def _bwd_rule(causal, block_q, block_k, window, res, g):
                  ot.astype(jnp.float32)).sum(-1)[:, :, None, :]
 
     def qc(ki, qi):
+        if bd:
+            return _bd_q_block(ki, qi, bq, bk, bd)
         return _clamp(qi, *_visited_q_blocks(ki, bq, bk, nq, causal,
                                              window))
 
@@ -576,7 +747,8 @@ def _bwd_rule(causal, block_q, block_k, window, res, g):
     dkt, dvt = pl.pallas_call(
         functools.partial(
             _dkv_kernel, scale=scale, causal=causal, window=window,
-            block_q=bq, block_k=bk, num_q_blocks=nq, has_seg=has_seg),
+            block_q=bq, block_k=bk, num_q_blocks=nq, has_seg=has_seg,
+            bd=bd),
         grid=(b, hq, nk, nq),
         in_specs=in_specs,
         out_specs=[dkv_spec, dkv_spec],

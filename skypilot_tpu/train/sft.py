@@ -80,17 +80,30 @@ def _comms_report(step_fn, state, batch, mesh, dcn_axes, lowered,
         return None
 
 
+def _rows(arr: np.ndarray, shift: bool) -> Dict[str, np.ndarray]:
+    """A batch of a [B, S + 1] array of ids for next-token training
+    (targets one to the right of tokens), or of a [B, S] one as it is:
+    rows of the data for an objective that makes its own inputs and
+    targets of them (train/block_diffusion.py)."""
+    if shift:
+        return {'tokens': arr[:, :-1], 'targets': arr[:, 1:]}
+    return {'tokens': arr}
+
+
 def synthetic_batches(vocab_size: int, batch: int, seq: int,
-                      seed: int = 0) -> Iterator[Dict[str, np.ndarray]]:
+                      seed: int = 0, shift: bool = True
+                      ) -> Iterator[Dict[str, np.ndarray]]:
     rng = np.random.default_rng(seed)
     while True:
-        toks = rng.integers(0, vocab_size, (batch, seq + 1), dtype=np.int32)
-        yield {'tokens': toks[:, :-1], 'targets': toks[:, 1:]}
+        yield _rows(rng.integers(0, vocab_size, (batch, seq + shift),
+                                 dtype=np.int32), shift)
 
 
 def jsonl_batches(path: str, vocab_size: int, batch: int, seq: int,
-                  tokenizer=None) -> Iterator[Dict[str, np.ndarray]]:
-    """Pack {'text' or 'tokens'} JSONL rows into fixed [B,S] batches.
+                  tokenizer=None, shift: bool = True
+                  ) -> Iterator[Dict[str, np.ndarray]]:
+    """Pack {'text' or 'tokens'} JSONL rows into fixed [B,S] batches
+    (`_rows` has what `shift` says).
 
     tokenizer: optional infer.tokenizer instance (--data-tokenizer
     points at a checkpoint dir's tokenizer.json) used for 'text' rows —
@@ -123,9 +136,8 @@ def jsonl_batches(path: str, vocab_size: int, batch: int, seq: int,
     stream = _tokens()
     while True:
         flat = np.fromiter(stream, dtype=np.int32,
-                           count=batch * (seq + 1))
-        arr = flat.reshape(batch, seq + 1)
-        yield {'tokens': arr[:, :-1], 'targets': arr[:, 1:]}
+                           count=batch * (seq + shift))
+        yield _rows(flat.reshape(batch, seq + shift), shift)
 
 
 def main(argv=None) -> None:
@@ -223,6 +235,7 @@ def main(argv=None) -> None:
     from skypilot_tpu.models import moe
     from skypilot_tpu.models import registry
     from skypilot_tpu.parallel import mesh as mesh_lib
+    from skypilot_tpu.train import block_diffusion
     from skypilot_tpu.train import trainer
 
     try:
@@ -231,6 +244,10 @@ def main(argv=None) -> None:
         raise SystemExit(f'unknown model {args.model}; choose from '
                          f'{registry.names()}') from None
     moe_cfg = getattr(model, 'moe', None)   # MixtralModel's MoeConfig
+    # The objective is the model's. Under block diffusion --seq is the
+    # data's length L: the model reads 2L positions, a batch is rows of
+    # L ids with no shift, drawn from the ids under the mask's.
+    bd = block_diffusion.objective_of(model)
     if args.base_checkpoint and not isinstance(cfg, llama.LlamaConfig):
         raise SystemExit(f'--base-checkpoint: models/weights.py has no '
                          f'loader for --model {args.model!r}')
@@ -265,7 +282,8 @@ def main(argv=None) -> None:
     tcfg = trainer.TrainerConfig(learning_rate=args.lr,
                                  total_steps=args.steps)
     tx = trainer.make_optimizer(tcfg)
-    sample = jnp.zeros((args.batch, args.seq), jnp.int32)
+    sample = jnp.zeros((args.batch, args.seq * (2 if bd else 1)),
+                       jnp.int32)
     state, _ = trainer.create_sharded_state(model, tx, mesh, sample,
                                             jax.random.PRNGKey(0))
 
@@ -335,6 +353,10 @@ def main(argv=None) -> None:
             logger.info('loaded base checkpoint %s', args.base_checkpoint)
 
         lora_cfg = None
+        if args.lora_rank > 0 and bd is not None:
+            raise SystemExit(f'--lora-rank: --model {args.model!r} trains '
+                             f'by block diffusion, which the LoRA step '
+                             f'does not compute')
         if args.lora_rank > 0:
             from skypilot_tpu.train import lora as lora_lib
             lora_cfg = lora_lib.LoRAConfig(rank=args.lora_rank,
@@ -363,10 +385,13 @@ def main(argv=None) -> None:
         if args.data and args.data_tokenizer:
             from skypilot_tpu.infer import tokenizer as tokenizer_lib
             data_tok = tokenizer_lib.load_tokenizer(args.data_tokenizer)
-        batches = (jsonl_batches(args.data, cfg.vocab_size, args.batch,
-                                 args.seq, tokenizer=data_tok)
+        data_vocab = getattr(cfg, 'data_vocab_size', cfg.vocab_size)
+        batches = (jsonl_batches(args.data, data_vocab, args.batch,
+                                 args.seq, tokenizer=data_tok,
+                                 shift=bd is None)
                    if args.data else
-                   synthetic_batches(cfg.vocab_size, args.batch, args.seq))
+                   synthetic_batches(data_vocab, args.batch, args.seq,
+                                     shift=bd is None))
 
         from skypilot_tpu.utils import profiling
         prof = profiling.StepProfiler()   # no-op unless SKYT_PROFILE_DIR set
@@ -441,10 +466,13 @@ def main(argv=None) -> None:
                     if plans:
                         # Per kernel: tile extents and, per head, tiles
                         # visited / masked / skipped.
+                        # (`bd_` plans add the tiles the allowed
+                        # pairs would fill).
                         logger.info('flash tile plan: %s', ', '.join(
                             '{} {block_q}x{block_k} {visited}/{masked}/'
-                            '{skipped}'.format(k, **p)
-                            for k, p in plans.items()))
+                            '{skipped}'.format(k, **p) +
+                            (f' needed {p["needed"]}' if 'needed' in p
+                             else '') for k, p in plans.items()))
                     moe_plan = ops_dispatch.moe_plan_snapshot()
                     if moe_plan:
                         logger.info('moe routing plan: %s', ' '.join(
@@ -452,6 +480,10 @@ def main(argv=None) -> None:
                     grouped = ops_dispatch.grouped_plan_line()
                     if grouped:
                         logger.info('grouped tile plan: %s', grouped)
+                    bd_plan = ops_dispatch.bd_plan_snapshot()
+                    if bd_plan:
+                        logger.info('block diffusion plan: %s', ' '.join(
+                            f'{k}={v}' for k, v in bd_plan.items()))
                 tokens_seen += args.batch * args.seq * jax.process_count()
                 if hb is not None:
                     live_state['step'] = step
@@ -544,11 +576,12 @@ def main(argv=None) -> None:
                         tokens_per_sec=tokens_seen / dt,
                         steps=n_window, mfu=mfu_val)
                     last_t = now
-                    logger.info('step %d/%d loss=%.4f tokens/s=%.0f%s',
+                    logger.info('step %d/%d loss=%.4f tokens/s=%.0f%s%s',
                                 step + 1, args.steps,
                                 host.get('loss', float('nan')),
                                 tokens_seen / dt,
-                                trainer.format_moe_stats(host))
+                                trainer.format_moe_stats(host),
+                                block_diffusion.format_stats(host))
         except SystemExit:
             raise
         except Exception:

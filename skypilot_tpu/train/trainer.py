@@ -18,6 +18,7 @@ import optax
 from jax.sharding import Mesh
 
 from skypilot_tpu.parallel import sharding as sharding_lib
+from skypilot_tpu.train import block_diffusion
 from skypilot_tpu.utils import metrics as metrics_lib
 from skypilot_tpu.utils import tracing
 
@@ -142,7 +143,7 @@ class DeferredMetrics:
 
     def __init__(self, publisher: 'TrainMetricsPublisher',
                  keys: Tuple[str, ...] = ('loss', 'grad_norm')
-                 + MOE_STAT_KEYS,
+                 + MOE_STAT_KEYS + block_diffusion.BD_STAT_KEYS,
                  tracer: Optional['tracing.Tracer'] = None) -> None:
         self._pub = publisher
         self._keys = keys
@@ -308,8 +309,16 @@ def make_train_step(model: nn.Module, tx, mesh: Mesh,
     """Returns jitted (state, batch) -> (state, metrics).
 
     batch: {'tokens': [B,S], 'targets': [B,S], optional 'segment_ids'}.
+
+    The objective is the model's: a model whose configuration names a
+    block-diffusion objective (train/block_diffusion.py) takes
+    batch['tokens'] as rows x_0 of the data, reads no targets, and draws
+    the step's noise on the device from `block_diffusion.NOISE_KEY`
+    folded with `state.step`, so that a resumed state continues its
+    noise; every other model trains next-token.
     """
     batch_axes = ('act_batch', 'act_seq')
+    bd = block_diffusion.objective_of(model)
 
     def step_fn(state: TrainStateS, batch):
         # Constrain batch leaves onto the data axes (works for any subset
@@ -317,12 +326,24 @@ def make_train_step(model: nn.Module, tx, mesh: Mesh,
         batch = {k: sharding_lib.constrain(v, mesh, batch_axes, rules)
                  for k, v in batch.items()}
 
+        stats = {}
+        if bd is not None:
+            x_t, masked, level, stats = block_diffusion.step_noise(
+                batch['tokens'], jax.random.fold_in(
+                    jax.random.PRNGKey(block_diffusion.NOISE_KEY),
+                    state.step), bd)
+
         def loss_fn(params):
-            logits, mutated = model.apply(
-                {'params': params}, batch['tokens'],
-                segment_ids=batch.get('segment_ids'),
-                mutable=['intermediates'])
-            loss, n_tok = cross_entropy_loss(logits, batch['targets'])
+            if bd is not None:
+                loss, mutated = block_diffusion.loss_given_noise(
+                    model, params, batch['tokens'], x_t, masked, level)
+                n_tok = jnp.float32(batch['tokens'].size)
+            else:
+                logits, mutated = model.apply(
+                    {'params': params}, batch['tokens'],
+                    segment_ids=batch.get('segment_ids'),
+                    mutable=['intermediates'])
+                loss, n_tok = cross_entropy_loss(logits, batch['targets'])
             # Aux losses sown by the model (MoE load-balance/z-loss).
             for aux in jax.tree.leaves(
                     mutated.get('intermediates', {}).get(
@@ -330,7 +351,7 @@ def make_train_step(model: nn.Module, tx, mesh: Mesh,
                 loss = loss + aux
             # Routing counters sown by a model with routed experts.
             sown = mutated.get('intermediates', {}).get('moe_stats', ())
-            return loss, (n_tok, sown[0] if sown else {})
+            return loss, (n_tok, {**stats, **(sown[0] if sown else {})})
 
         (loss, (n_tok, moe_stats)), grads = jax.value_and_grad(
             loss_fn, has_aux=True)(state.params)

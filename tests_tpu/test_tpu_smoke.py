@@ -156,6 +156,45 @@ class TestFlashKernelLowers:
             g, gr = (np.asarray(x, np.float32) for x in (g, gr))
             assert np.linalg.norm(g - gr) / np.linalg.norm(gr) < 3e-2, name
 
+    def test_block_diffusion_fwd_bwd_at_the_8k_cells_shape(self):
+        """The attention layers of `sft-bd-moe-8k`: 32 / 4 heads x 128,
+        2L = 16,384 positions, blocks of 4, through `attention` as the
+        model calls it (the shape rule's tiles, which divide L).
+        Forward, dq and dk/dv under a random cotangent against the XLA
+        rung, which computes the same mask from the same rule a block of
+        queries at a time (the whole square is 34 GB in float32)."""
+        from skypilot_tpu.ops import attention, dispatch
+
+        b, s, hq, hkv, d, blk = 1, 16384, 32, 4, 128, 4
+        q = _rand(0, (b, s, hq, d))
+        k = _rand(1, (b, s, hkv, d))
+        v = _rand(2, (b, s, hkv, d))
+        cot = _rand(3, (b, s, hq, d)).astype(jnp.float32)
+
+        def both(impl):
+            def loss(q, k, v, cot):
+                out = attention.attention(q, k, v, impl=impl,
+                                          block_diffusion=blk)
+                return jnp.sum(out.astype(jnp.float32) * cot), out
+            return jax.jit(jax.value_and_grad(loss, argnums=(0, 1, 2),
+                                              has_aux=True))
+        dispatch.reset_for_tests()
+        (_, out), grads = both('auto')(q, k, v, cot)
+        assert dispatch.snapshot() == {
+            'flash_block_diffusion_attention': 'pallas'}
+        plan = dispatch.flash_plan_snapshot()
+        assert {name: (p['block_q'], p['block_k'], p['visited'],
+                       p['masked']) for name, p in plan.items()} == {
+            'bd_fwd': (512, 1024, 160, 48), 'bd_dq': (1024, 1024, 80, 24),
+            'bd_dkv': (512, 512, 288, 48)}
+        (_, ref), grefs = both('xla')(q, k, v, cot)
+        np.testing.assert_allclose(
+            np.asarray(out, np.float32), np.asarray(ref, np.float32),
+            atol=3e-2, rtol=3e-2)
+        for name, g, gr in zip(('dq', 'dk', 'dv'), grads, grefs):
+            g, gr = (np.asarray(x, np.float32) for x in (g, gr))
+            assert np.linalg.norm(g - gr) / np.linalg.norm(gr) < 3e-2, name
+
     def test_fwd_with_segment_ids(self):
         from skypilot_tpu.ops.flash_attention import flash_attention
 
