@@ -2,53 +2,64 @@
 
 Blockwise online-softmax attention (Flash-Attention-2 schedule):
 
-* forward: grid over (batch, q_heads, q_blocks, k_blocks) with the k axis
-  innermost so the VMEM scratch accumulators (running max m, running sum
-  l, output acc) persist across k iterations of one q block; also emits
-  the per-row logsumexp L for the backward. GQA is folded into the k/v
-  index_map (head h reads kv head h // group). Segment ids (packed
+* forward: a q tile's k tiles in turn, so the VMEM scratch accumulators
+  (running max m, running sum l, output acc) persist across them; also
+  emits the per-row logsumexp L for the backward. GQA is folded into the
+  k/v index_map (head h reads kv head h // group). Segment ids (packed
   sequences) are masked in-kernel.
 * backward: two kernels, both recomputing p = exp(s - L) blockwise from
   the saved residuals (q, k, v, out, L) — no O(S^2) materialization:
-    - dq kernel: same grid as forward (k innermost), accumulates
-      dq += ds @ k in VMEM scratch; delta = rowsum(dO*O) is made once
-      per q block, in the kernel, as the column it is used as;
-    - dk/dv kernel: grid (batch, q_heads, k_blocks, q_blocks) with q
-      innermost, computed in the transposed orientation (s^T = k q^T,
-      [block_k, block_q]) so that no product contracts dimension 0 of
-      an operand and L and delta are read as compact [1, block_q] rows;
-      accumulates dk/dv per *query* head; the GQA group sum down to kv
-      heads happens outside the kernel (one cheap XLA reduce), avoiding
-      non-contiguous output revisits.
+    - dq kernel: the forward's walk, accumulates dq += ds @ k in VMEM
+      scratch; delta = rowsum(dO*O) is made once per q tile, in the
+      kernel, as the column it is used as;
+    - dk/dv kernel: a k tile's q tiles in turn, computed in the
+      transposed orientation (s^T = k q^T, [block_k, block_q]) so that
+      no product contracts dimension 0 of an operand and L and delta
+      are read as compact [1, block_q] rows; accumulates dk/dv per
+      *query* head; the GQA group sum down to kv heads happens outside
+      the kernel (one cheap XLA reduce), avoiding non-contiguous output
+      revisits.
 
 The tile plan (docs/kernels.md): each kernel gets its own (block_q,
-block_k) from the shape (dispatch.flash_blocks). Under a causal or
-sliding-window mask a tile is *skipped* (no allowed entry: no compute,
-and the index_maps clamp the streamed block index to the nearest
-visited tile, so nothing is copied in for it), *masked* (the diagonal
-or the window's edge crosses it: iota, compare, select) or *plain*
+block_k) from the shape (dispatch.flash_blocks). Under a mask a tile is
+*skipped* (no allowed entry), *masked* (the diagonal, the window's edge
+or a block's boundary crosses it: iota, compare, select) or *plain*
 (wholly allowed: none of that). With segment ids every visited tile is
 masked. The plan is recorded at trace time (dispatch.record_flash_plan).
+
+The grid is the list of visited tiles: (batch, q heads, steps), where a
+step is one tile with an allowed entry and a skipped tile has no step.
+Every mask here is static, so the list is a constant made in numpy at
+trace time (`_walk`: `_tile_visited` and `_tile_crossed` over all tiles
+at once) and prefetched as scalars, an int32 a step: the q tile, the k
+tile, and whether the step is the first or the last of its row of tiles
+(zero the accumulators, store the result) and plain or masked. The
+index maps read the step's tile from it; consecutive steps of a row
+name the same block of the row's operands and results, which stay
+resident. One walk serves the causal, window, segment and
+block-diffusion masks and no mask at all (the whole grid, in the old
+order); a row of tiles that visits nothing keeps one step that computes
+nothing, so its zeros are stored.
 
 The block-diffusion mask (`bd = (L, B)`: the row is `[x_t | x_0]`, L
 noised positions then their L clean copies, in blocks of B) is the one
 mask whose visited tiles are not one run a row of tiles: a noised query
 block visits its own noised blocks and, after a gap, the clean blocks
 before it; a clean key block is visited by the noised query blocks after
-it and by the clean ones from it on. The grid is the same; the index
-maps name, for a skipped step, the nearer end of the run before or
-after it (`_two_runs`), so nothing is copied in for it. The extents the
-tile rule gives divide L (or are the whole 2L), so a tile lies in one
-quadrant of the square.
+it and by the clean ones from it on. The list holds both runs, one
+after the other. The extents the tile rule gives divide L (or are the
+whole 2L), so a tile lies in one quadrant of the square.
 
 Kernel conventions follow /opt/skills/guides/pallas_guide.md (block
 specs, scratch via pl.pallas_call scratch_shapes, MXU-aligned tiles).
 """
 import functools
-from typing import Optional
+import types
+from typing import NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -123,7 +134,7 @@ def _bd_tile(qi, ki, block_q, block_k, bd):
     """(visited, plain) of a tile under the block-diffusion mask: has
     it any allowed entry, and is every entry allowed. A tile lies in
     one quadrant (its extents divide L), or an extent is the whole 2L
-    and every tile is visited and masked. Python ints or traced."""
+    and every tile is visited and masked. Python ints or arrays."""
     half, block = bd
     if block_q > half or block_k > half:
         return True, False
@@ -147,8 +158,8 @@ def _tile_visited(qi, ki, block_q, block_k, causal, window, bd=()):
     """Does this (q block, k block) pair contain ANY unmasked (q, k)
     entry under causal+window, or under the block-diffusion mask? The
     others are skipped whole: above the diagonal (causal) and, with a
-    window, entirely below it. Python ints (the trace-time tile count)
-    or traced scalars (the kernels)."""
+    window, entirely below it. Python ints, or arrays over every tile
+    (`_walk`, the one reader: the list of a call's grid)."""
     if bd:
         return _bd_tile(qi, ki, block_q, block_k, bd)[0]
     cond = True
@@ -175,88 +186,6 @@ def _tile_crossed(qi, ki, block_q, block_k, causal, window, bd=()):
     return cond
 
 
-def _visited_k_blocks(qi, block_q, block_k, num_k_blocks, causal, window):
-    """(first, last) k block that q block `qi` visits (traced)."""
-    lo, hi = 0, num_k_blocks - 1
-    if causal:
-        hi = jnp.minimum(hi, ((qi + 1) * block_q - 1) // block_k)
-    if window > 0:
-        lo = jnp.maximum(qi * block_q - window + 1, 0) // block_k
-    return lo, hi
-
-
-def _visited_q_blocks(ki, block_q, block_k, num_q_blocks, causal, window):
-    """(first, last) q block that k block `ki` visits (traced)."""
-    lo, hi = 0, num_q_blocks - 1
-    if causal:
-        lo = (ki * block_k) // block_q
-    if window > 0:
-        hi = jnp.minimum(hi, ((ki + 1) * block_k + window - 2) // block_q)
-    return lo, hi
-
-
-def _clamp(i, lo, hi):
-    return jnp.minimum(jnp.maximum(i, lo), hi)
-
-
-def _two_runs(i, lo1, hi1, lo2, hi2):
-    """Block `i` of a walk that visits [lo1, hi1] and then [lo2, hi2]
-    (either may be empty, lo > hi, not both): itself where it is
-    visited, else the nearer end of the run before or after it, which
-    is resident then."""
-    lo1, hi1, lo2, hi2 = (jnp.where(lo1 > hi1, lo2, lo1),
-                          jnp.where(lo1 > hi1, hi2, hi1),
-                          jnp.where(lo2 > hi2, lo1, lo2),
-                          jnp.where(lo2 > hi2, hi1, hi2))
-    return jnp.where(i < lo2, _clamp(i, lo1, hi1), _clamp(i, lo2, hi2))
-
-
-def _bd_k_block(qi, ki, block_q, block_k, bd):
-    """The k block a grid step of q block `qi` names under the
-    block-diffusion mask (traced). A noised query block visits the
-    noised blocks of its own positions, then the clean blocks before
-    its last one; a clean query block the clean blocks up to its last
-    one."""
-    half, block = bd
-    if block_q > half or block_k > half:
-        return ki
-    per_half = half // block_k
-    q0 = qi * block_q
-    first = q0 % half
-    last_blk = (first + block_q - 1) // block
-    noised = q0 < half
-    own_lo = first // block * block // block_k
-    own_hi = ((last_blk + 1) * block - 1) // block_k
-    # Clean keys a query of the tile may see: positions under `upto`.
-    upto = jnp.where(noised, last_blk * block, (last_blk + 1) * block)
-    clean_hi = per_half + (upto - 1) // block_k    # upto 0: under per_half
-    return _two_runs(ki, jnp.where(noised, own_lo, 1),
-                     jnp.where(noised, own_hi, 0), per_half, clean_hi)
-
-
-def _bd_q_block(ki, qi, block_q, block_k, bd):
-    """The q block a grid step of k block `ki` names under the
-    block-diffusion mask (traced). A noised key block is visited by the
-    noised blocks of its own positions; a clean one by the noised
-    blocks after its first block, and by the clean ones from it on."""
-    half, block = bd
-    if block_q > half or block_k > half:
-        return qi
-    per_half = half // block_q
-    k0 = ki * block_k
-    first = k0 % half
-    first_blk = first // block
-    noised = k0 < half
-    own_lo = first_blk * block // block_q
-    own_hi = (((first + block_k - 1) // block + 1) * block - 1) // block_q
-    after = (first_blk + 1) * block // block_q     # past the half: none
-    return _two_runs(
-        qi, jnp.where(noised, own_lo, after),
-        jnp.where(noised, own_hi, per_half - 1),
-        jnp.where(noised, 1, per_half + own_lo),
-        jnp.where(noised, 0, 2 * per_half - 1))
-
-
 def allowed_pairs(half: int, block: int) -> int:
     """(query, key) pairs a head computes under the block-diffusion
     mask: clean to clean L(L + B)/2, noised to clean L(L - B)/2, a
@@ -264,56 +193,95 @@ def allowed_pairs(half: int, block: int) -> int:
     return half * half + half * block
 
 
-def tile_counts(sq, sk, block_q, block_k, causal, window, has_seg,
-                bd=()):
-    """Tiles of one head the plan visits, masks and skips; under the
-    block-diffusion mask also `needed`, the allowed pairs in tiles."""
-    visited = masked = 0
+# A grid step's tile and what to do there, in one int32: the q tile and
+# the k tile (13 bits each: 8,192 tiles a side), then four flags. An
+# empty row's one step has neither `plain` nor `masked`: not computed.
+_TILE_BITS = 13
+MAX_TILES = 1 << _TILE_BITS
+_FIRST, _LAST, _PLAIN, _MASKED = (1 << (2 * _TILE_BITS + i) for i in range(4))
+
+# The longest list a call may prefetch (docs/kernels.md): it lives in
+# SMEM whole, 1 MiB on a v5e; 261,051 steps compiled and 261,173 did not,
+# for every kernel (the compiler for a described chip, PR 36).
+MAX_STEPS = 252 * 1024
+
+
+def _decode(code):
+    """(q tile, k tile, first, last, plain, masked) of a step's entry:
+    a traced scalar in the kernels and index maps, an array in tests."""
+    tile = MAX_TILES - 1
+    return (code & tile, (code >> _TILE_BITS) & tile, (code & _FIRST) != 0,
+            (code & _LAST) != 0, (code & _PLAIN) != 0, (code & _MASKED) != 0)
+
+
+@functools.lru_cache(maxsize=None)
+def _walk(sq, sk, block_q, block_k, causal, window, has_seg, bd, by_k):
+    """(the list of visited tiles, the counts of one head) of a call:
+    `_tile_visited` and `_tile_crossed` over every tile at once, in
+    numpy at trace time, cached for the call sites of an unrolled
+    stack. The walk is over rows of tiles (q tiles; k tiles with
+    `by_k`, the dk/dv kernel), each row's visited tiles in rising
+    order; a row that visits nothing gets one step that computes
+    nothing, so that its zeros are stored."""
     nq, nk = sq // block_q, sk // block_k
-    for qi in range(nq):
-        for ki in range(nk):
-            if _tile_visited(qi, ki, block_q, block_k, causal, window, bd):
-                visited += 1
-                masked += bool(has_seg or _tile_crossed(
-                    qi, ki, block_q, block_k, causal, window, bd))
-    counts = {'visited': visited, 'masked': masked,
-              'skipped': nq * nk - visited}
+    qi, ki = np.arange(nq)[:, None], np.arange(nk)[None, :]
+    mask = (block_q, block_k, causal, window, bd)
+    visited = np.broadcast_to(_tile_visited(qi, ki, *mask), (nq, nk))
+    masked = visited & (has_seg | np.broadcast_to(
+        _tile_crossed(qi, ki, *mask), (nq, nk)))
+    counts = {'visited': int(visited.sum()), 'masked': int(masked.sum()),
+              'skipped': int((~visited).sum())}
+    if by_k:
+        visited, masked = visited.T, masked.T
+    # A row that visits nothing steps once, on its first tile.
+    stepped = visited.copy()
+    stepped[~visited.any(axis=1), 0] = True
+    row, col = np.nonzero(stepped)        # row-major, columns rising
+    kind = np.select([masked[row, col], visited[row, col]],
+                     [_MASKED, _PLAIN], 0)
+    edge = np.concatenate([[True], row[1:] != row[:-1], [True]])
+    q_tile, k_tile = (col, row) if by_k else (row, col)
+    codes = (q_tile | k_tile << _TILE_BITS | edge[:-1] * _FIRST |
+             edge[1:] * _LAST | kind).astype(np.int32)
+    codes.flags.writeable = False
+    counts['steps'] = len(codes)
     if bd:
         counts['needed'] = round(allowed_pairs(*bd) / (block_q * block_k), 2)
-    return counts
+    return codes, counts
 
 
-def _for_tile(qi, ki, block_q, block_k, causal, window, has_seg, tile,
-              bd=()):
-    """Run `tile(masked)` for the grid step's tile: not at all where it
-    is skipped, with the mask work only where a mask can bite."""
-    if not (causal or window > 0 or bd):
-        tile(has_seg)
-        return
-    visited = _tile_visited(qi, ki, block_q, block_k, causal, window, bd)
-    if visited is True:     # block diffusion at a whole-sequence extent
-        tile(True)
-        return
-    if has_seg:
-        pl.when(visited)(lambda: tile(True))
-        return
-    crossed = _tile_crossed(qi, ki, block_q, block_k, causal, window, bd)
-    pl.when(visited & crossed)(lambda: tile(True))
-    pl.when(visited & jnp.logical_not(crossed))(lambda: tile(False))
+def tile_counts(sq, sk, block_q, block_k, causal, window, has_seg,
+                bd=(), by_k=False):
+    """Tiles of one head the plan visits, masks and skips, and the grid
+    steps it takes (the visited tiles, and one for a row of tiles that
+    visits nothing); under the block-diffusion mask also `needed`, the
+    allowed pairs in tiles."""
+    return dict(_walk(sq, sk, block_q, block_k, causal, window, has_seg,
+                      bd, by_k)[1])
+
+
+def _for_tile(kind, kinds, tile):
+    """Run `tile(masked)` for the grid step's tile, with the mask work
+    only where a mask can bite. `kind`: the step's (plain, masked)
+    flags; `kinds`: which of the two the call's list holds at all (the
+    other body is not traced)."""
+    for masked in (False, True):
+        if kinds[masked]:
+            pl.when(kind[masked])(functools.partial(tile, masked))
 
 
 def _fwd_kernel(*refs, scale: float, causal: bool, window: int,
-                block_q: int, block_k: int, num_k_blocks: int,
+                block_q: int, block_k: int, kinds: tuple,
                 has_seg: bool, bd: tuple = ()):
     if has_seg:
-        (q_ref, k_ref, v_ref, q_seg_ref, k_seg_ref,
+        (visits_ref, q_ref, k_ref, v_ref, q_seg_ref, k_seg_ref,
          o_ref, lse_ref, m_scr, l_scr, acc_scr) = refs
     else:
-        q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr, acc_scr = refs
-    qi = pl.program_id(2)
-    ki = pl.program_id(3)
+        (visits_ref, q_ref, k_ref, v_ref, o_ref, lse_ref,
+         m_scr, l_scr, acc_scr) = refs
+    qi, ki, first, last, *kind = _decode(visits_ref[pl.program_id(2)])
 
-    @pl.when(ki == 0)
+    @pl.when(first)
     def _init():
         m_scr[:] = jnp.full_like(m_scr, NEG_INF)
         l_scr[:] = jnp.zeros_like(l_scr)
@@ -349,9 +317,9 @@ def _fwd_kernel(*refs, scale: float, causal: bool, window: int,
         m_scr[:] = m_new
         l_scr[:] = l_new
 
-    _for_tile(qi, ki, block_q, block_k, causal, window, has_seg, _tile, bd)
+    _for_tile(kind, kinds, _tile)
 
-    @pl.when(ki == num_k_blocks - 1)
+    @pl.when(last)
     def _finalize():
         l = l_scr[:]
         safe_l = jnp.where(l == 0.0, 1.0, l)  # fully-masked rows -> out 0
@@ -363,18 +331,17 @@ def _fwd_kernel(*refs, scale: float, causal: bool, window: int,
 
 
 def _dq_kernel(*refs, scale: float, causal: bool, window: int,
-               block_q: int, block_k: int, num_k_blocks: int,
+               block_q: int, block_k: int, kinds: tuple,
                has_seg: bool, bd: tuple = ()):
     if has_seg:
-        (q_ref, k_ref, v_ref, do_ref, o_ref, lse_ref,
+        (visits_ref, q_ref, k_ref, v_ref, do_ref, o_ref, lse_ref,
          q_seg_ref, k_seg_ref, dq_ref, dq_scr, delta_scr) = refs
     else:
-        (q_ref, k_ref, v_ref, do_ref, o_ref, lse_ref,
+        (visits_ref, q_ref, k_ref, v_ref, do_ref, o_ref, lse_ref,
          dq_ref, dq_scr, delta_scr) = refs
-    qi = pl.program_id(2)
-    ki = pl.program_id(3)
+    qi, ki, first, last, *kind = _decode(visits_ref[pl.program_id(2)])
 
-    @pl.when(ki == 0)
+    @pl.when(first)
     def _init():
         dq_scr[:] = jnp.zeros_like(dq_scr)
         # delta_i = sum_d dO_i * O_i, the softmax-grad row correction.
@@ -402,26 +369,25 @@ def _dq_kernel(*refs, scale: float, causal: bool, window: int,
             ds.astype(k.dtype), k, _NN,
             preferred_element_type=jnp.float32)
 
-    _for_tile(qi, ki, block_q, block_k, causal, window, has_seg, _tile, bd)
+    _for_tile(kind, kinds, _tile)
 
-    @pl.when(ki == num_k_blocks - 1)
+    @pl.when(last)
     def _finalize():
         dq_ref[0, 0] = dq_scr[:].astype(dq_ref.dtype)
 
 
 def _dkv_kernel(*refs, scale: float, causal: bool, window: int,
-                block_q: int, block_k: int, num_q_blocks: int,
+                block_q: int, block_k: int, kinds: tuple,
                 has_seg: bool, bd: tuple = ()):
     if has_seg:
-        (q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
+        (visits_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
          q_seg_ref, k_seg_ref, dk_ref, dv_ref, dk_scr, dv_scr) = refs
     else:
-        (q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
+        (visits_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
          dk_ref, dv_ref, dk_scr, dv_scr) = refs
-    ki = pl.program_id(2)
-    qi = pl.program_id(3)
+    qi, ki, first, last, *kind = _decode(visits_ref[pl.program_id(2)])
 
-    @pl.when(qi == 0)
+    @pl.when(first)
     def _init():
         dk_scr[:] = jnp.zeros_like(dk_scr)
         dv_scr[:] = jnp.zeros_like(dv_scr)
@@ -452,9 +418,9 @@ def _dkv_kernel(*refs, scale: float, causal: bool, window: int,
             dst.astype(q.dtype), q, _NN,
             preferred_element_type=jnp.float32)  # [bk, d]
 
-    _for_tile(qi, ki, block_q, block_k, causal, window, has_seg, _tile, bd)
+    _for_tile(kind, kinds, _tile)
 
-    @pl.when(qi == num_q_blocks - 1)
+    @pl.when(last)
     def _finalize():
         dk_ref[0, 0] = dk_scr[:].astype(dk_ref.dtype)
         dv_ref[0, 0] = dv_scr[:].astype(dv_ref.dtype)
@@ -496,7 +462,7 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array,
     in-kernel (forward and backward).
     window: sliding-window attention (> 0: query p sees k in
     (p - window, p]). Out-of-window tiles are skipped like the tiles
-    above the causal diagonal: no compute and no fetch.
+    above the causal diagonal: no grid step.
     block_diffusion: the block length B (> 0) of the block-diffusion
     mask over a row `[x_t | x_0]` of Sq = Sk = 2L positions (the module
     docstring and ops/attention.block_diffusion_allowed have the rule);
@@ -525,15 +491,25 @@ def _flash(q, k, v, segment_ids, causal, block_q, block_k, window, bd=()):
     return out
 
 
+class _Tiles(NamedTuple):
+    """What `_plan` fixes for one kernel of a call."""
+    block_q: int
+    block_k: int
+    vmem_limit: Optional[int]
+    visits: np.ndarray      # the grid: an int32 a step (`_walk`)
+    kinds: tuple            # has the list (plain, masked) tiles at all
+
+
 def _plan(q, k, block_q, block_k, has_seg, causal, window, kernels,
           bd=()):
-    """Shape checks, then (tile plan, `vmem_limit_bytes`) of `kernels`
+    """Shape checks, then kernel -> `_Tiles` for `kernels`
     (docs/kernels.md): extents from the shape rule, or the requested
     blocks CLAMPED through the divisibility-safe selector — to a
     tile-aligned divisor of the seq dim, or to the full dim (always
     legal) — so any legal input shape lowers, decode shapes like
-    (4, 32, 8, 256) included. A block pair whose VMEM working set
-    cannot fit is refused at TRACE time (a ValueError the dispatch
+    (4, 32, 8, 256) included; and the list of tiles the grid visits. A
+    block pair whose VMEM working set cannot fit, or a list longer than
+    SMEM takes, is refused at TRACE time (a ValueError the dispatch
     ladder catches), because the Mosaic compile error it would become
     is not catchable. What is traced is recorded, with its tile counts
     (dispatch.record_flash_plan)."""
@@ -547,103 +523,112 @@ def _plan(q, k, block_q, block_k, has_seg, causal, window, kernels,
         want = (block_q or sq, block_k or sk)
     plan = dispatch.flash_blocks(sq, sk, d, q.dtype, has_seg, window, want,
                                  block_diffusion=bool(bd))
-    limits = {}
+    tiles = {}
     for kernel in kernels:
         bq, bk = plan[kernel]
         need = dispatch.flash_vmem_bytes(
             kernel, bq, bk, d, jnp.dtype(q.dtype).itemsize, has_seg)
-        if not dispatch.interpret_mode() and \
-                need > dispatch.VMEM_BUDGET_BYTES:
+        if max(sq // bq, sk // bk) > MAX_TILES:
             raise ValueError(
-                f'flash {kernel} blocks {plan[kernel]} x d={d} need '
-                f'{need}B of VMEM, over the budget '
-                f'({dispatch.VMEM_BUDGET_BYTES}B) — refusing a certain '
-                'Mosaic compile failure')
-        limits[kernel] = dispatch.flash_vmem_limit(need)
-        dispatch.record_flash_plan(kernel, {
-            'block_q': bq, 'block_k': bk,
-            **tile_counts(sq, sk, bq, bk, causal, window, has_seg, bd)})
-    return plan, limits
+                f'flash {kernel} blocks {plan[kernel]} cut ({sq}, {sk}) '
+                f'into more than {MAX_TILES} tiles a side')
+        visits, counts = _walk(sq, sk, bq, bk, causal, window, has_seg, bd,
+                               kernel == 'dkv')
+        if not dispatch.interpret_mode():
+            if need > dispatch.VMEM_BUDGET_BYTES:
+                raise ValueError(
+                    f'flash {kernel} blocks {plan[kernel]} x d={d} need '
+                    f'{need}B of VMEM, over the budget '
+                    f'({dispatch.VMEM_BUDGET_BYTES}B) — refusing a '
+                    'certain Mosaic compile failure')
+            if len(visits) > MAX_STEPS:
+                raise ValueError(
+                    f'flash {kernel} blocks {plan[kernel]} visit '
+                    f'{len(visits)} tiles of ({sq}, {sk}), a list longer '
+                    f'than SMEM takes ({MAX_STEPS}) — refusing a certain '
+                    'Mosaic compile failure')
+        tiles[kernel] = _Tiles(
+            bq, bk, dispatch.flash_vmem_limit(need), visits,
+            (counts['masked'] < counts['visited'], counts['masked'] > 0))
+        dispatch.record_flash_plan(
+            kernel, {'block_q': bq, 'block_k': bk, **counts})
+    return tiles
 
 
-def _params(vmem_limit):
-    return pltpu.CompilerParams(
-        dimension_semantics=('parallel', 'parallel', 'parallel',
-                             'arbitrary'),
-        vmem_limit_bytes=vmem_limit)
+def _specs(tiles, d, group):
+    """The block specs of a call's operands. Every index map reads the
+    step's tile from the prefetched list: one scalar load and a shift.
+    Consecutive steps of a row of tiles name the same block of the
+    row's operands and results, which stay resident."""
+    bq, bk = tiles.block_q, tiles.block_k
+
+    def spec(block, index):
+        return pl.BlockSpec(block, lambda bi, hi, s, visits: index(
+            bi, hi, *_decode(visits[s])[:2]))
+
+    return types.SimpleNamespace(
+        q=spec((1, 1, bq, d), lambda bi, hi, qi, ki: (bi, hi, qi, 0)),
+        kv=spec((1, 1, bk, d),
+                lambda bi, hi, qi, ki: (bi, hi // group, ki, 0)),
+        # dk and dv of a *query* head.
+        dkv=spec((1, 1, bk, d), lambda bi, hi, qi, ki: (bi, hi, ki, 0)),
+        col=spec((1, 1, bq, LANES), lambda bi, hi, qi, ki: (bi, hi, qi, 0)),
+        row=spec((1, 1, 1, bq), lambda bi, hi, qi, ki: (bi, hi, 0, qi)),
+        # [b, 1, s] so the seq extent rides the LANE axis of the block
+        # ((1, 1, block) passes the Mosaic last-two-dims rule for any
+        # batch; the old [b, s] layout put the batch in the sublane
+        # slot, where a 1-extent block is illegal whenever b > 1).
+        segs=[spec((1, 1, bq), lambda bi, hi, qi, ki: (bi, 0, qi)),
+              spec((1, 1, bk), lambda bi, hi, qi, ki: (bi, 0, ki))])
+
+
+def _call(body, tiles, heads, in_specs, out_specs, out_shape, scratch,
+          operands):
+    """One kernel over the grid (batch, q heads, the list's steps)."""
+    return pl.pallas_call(
+        body,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(*heads, len(tiles.visits)),
+            in_specs=in_specs, out_specs=out_specs,
+            scratch_shapes=scratch),
+        out_shape=out_shape,
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=('parallel', 'parallel', 'arbitrary'),
+            vmem_limit_bytes=tiles.vmem_limit),
+        interpret=dispatch.interpret_mode(),
+    )(jnp.asarray(tiles.visits), *operands)
 
 
 def _flash_fwd_impl(q, k, v, segment_ids, causal, block_q, block_k,
                     window=0, bd=()):
     has_seg = segment_ids is not None
-    plan, limits = _plan(q, k, block_q, block_k, has_seg, causal, window,
-                         ('fwd',), bd)
-    block_q, block_k = plan['fwd']
+    tiles = _plan(q, k, block_q, block_k, has_seg, causal, window,
+                  ('fwd',), bd)['fwd']
     b, sq, hq, d = q.shape
-    sk, hkv = k.shape[1:3]
-    group = hq // hkv
-    nq, nk = sq // block_q, sk // block_k
+    hkv = k.shape[2]
+    specs = _specs(tiles, d, hq // hkv)
 
     # Kernel layout: [B, H, S, D] (head-major so blocks are contiguous).
-    qt = q.transpose(0, 2, 1, 3)
-    kt = k.transpose(0, 2, 1, 3)
-    vt = v.transpose(0, 2, 1, 3)
-
-    kernel = functools.partial(
-        _fwd_kernel, scale=d ** -0.5, causal=causal, window=window,
-        block_q=block_q, block_k=block_k, num_k_blocks=nk,
-        has_seg=has_seg, bd=bd)
-
-    def kc(qi, ki):
-        # A skipped step names the nearest visited k block: already
-        # resident, so nothing is copied in for it.
-        if bd:
-            return _bd_k_block(qi, ki, block_q, block_k, bd)
-        return _clamp(ki, *_visited_k_blocks(qi, block_q, block_k, nk,
-                                             causal, window))
-
-    q_spec = pl.BlockSpec((1, 1, block_q, d),
-                          lambda bi, hi, qi, ki: (bi, hi, qi, 0))
-    kv_spec = pl.BlockSpec(
-        (1, 1, block_k, d),
-        lambda bi, hi, qi, ki: (bi, hi // group, kc(qi, ki), 0))
-    in_specs = [q_spec, kv_spec, kv_spec]
-    operands = [qt, kt, vt]
+    operands = [q.transpose(0, 2, 1, 3), k.transpose(0, 2, 1, 3),
+                v.transpose(0, 2, 1, 3)]
+    in_specs = [specs.q, specs.kv, specs.kv]
     if has_seg:
-        # [b, 1, s] so the seq extent rides the LANE axis of the block
-        # ((1, 1, block) passes the Mosaic last-two-dims rule for any
-        # batch; the old [b, s] layout put the batch in the sublane
-        # slot, where a 1-extent block is illegal whenever b > 1).
-        seg = segment_ids.astype(jnp.int32)[:, None, :]
-        in_specs += [
-            pl.BlockSpec((1, 1, block_q),
-                         lambda bi, hi, qi, ki: (bi, 0, qi)),
-            pl.BlockSpec((1, 1, block_k),
-                         lambda bi, hi, qi, ki: (bi, 0, kc(qi, ki))),
-        ]
-        operands += [seg, seg]
+        in_specs += specs.segs
+        operands += [segment_ids.astype(jnp.int32)[:, None, :]] * 2
 
-    out, lse = pl.pallas_call(
-        kernel,
-        grid=(b, hq, nq, nk),
-        in_specs=in_specs,
-        out_specs=[
-            q_spec,
-            pl.BlockSpec((1, 1, block_q, LANES),
-                         lambda bi, hi, qi, ki: (bi, hi, qi, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((b, hq, sq, d), q.dtype),
-            jax.ShapeDtypeStruct((b, hq, sq, LANES), jnp.float32),
-        ],
-        scratch_shapes=[
-            pltpu.VMEM((block_q, 1), jnp.float32),   # running max
-            pltpu.VMEM((block_q, 1), jnp.float32),   # running sum
-            pltpu.VMEM((block_q, d), jnp.float32),   # output accumulator
-        ],
-        compiler_params=_params(limits['fwd']),
-        interpret=dispatch.interpret_mode(),
-    )(*operands)
+    out, lse = _call(
+        functools.partial(
+            _fwd_kernel, scale=d ** -0.5, causal=causal, window=window,
+            block_q=tiles.block_q, block_k=tiles.block_k,
+            kinds=tiles.kinds, has_seg=has_seg, bd=bd),
+        tiles, (b, hq), in_specs, [specs.q, specs.col],
+        [jax.ShapeDtypeStruct((b, hq, sq, d), q.dtype),
+         jax.ShapeDtypeStruct((b, hq, sq, LANES), jnp.float32)],
+        [pltpu.VMEM((tiles.block_q, 1), jnp.float32),   # running max
+         pltpu.VMEM((tiles.block_q, 1), jnp.float32),   # running sum
+         pltpu.VMEM((tiles.block_q, d), jnp.float32)],  # output accumulator
+        operands)
     return out.transpose(0, 2, 1, 3), lse
 
 
@@ -657,112 +642,58 @@ def _fwd_rule(q, k, v, segment_ids, causal, block_q, block_k, window,
 def _bwd_rule(causal, block_q, block_k, window, bd, res, g):
     q, k, v, segment_ids, out, lse = res
     has_seg = segment_ids is not None
-    plan, limits = _plan(q, k, block_q, block_k, has_seg, causal, window,
-                         ('dq', 'dkv'), bd)
+    plan = _plan(q, k, block_q, block_k, has_seg, causal, window,
+                 ('dq', 'dkv'), bd)
     b, sq, hq, d = q.shape
     sk, hkv = k.shape[1:3]
     group = hq // hkv
-    scale = d ** -0.5
+    static = dict(scale=d ** -0.5, causal=causal, window=window,
+                  has_seg=has_seg, bd=bd)
 
     qt = q.transpose(0, 2, 1, 3)
     kt = k.transpose(0, 2, 1, 3)
     vt = v.transpose(0, 2, 1, 3)
     dot = g.transpose(0, 2, 1, 3)         # dO, [b, hq, sq, d]
     ot = out.transpose(0, 2, 1, 3)
-    seg = segment_ids.astype(jnp.int32)[:, None, :] if has_seg else None
+    segs = [segment_ids.astype(jnp.int32)[:, None, :]] * 2 if has_seg \
+        else []
 
-    # dq: the forward's grid; q, dO, O and L stay for a q block's k loop.
-    bq, bk = plan['dq']
-    nq, nk = sq // bq, sk // bk
+    # dq: the forward's walk; q, dO, O and L stay for a q tile's k tiles.
+    tiles = plan['dq']
+    specs = _specs(tiles, d, group)
+    dqt = _call(
+        functools.partial(_dq_kernel, block_q=tiles.block_q,
+                          block_k=tiles.block_k, kinds=tiles.kinds,
+                          **static),
+        tiles, (b, hq),
+        [specs.q, specs.kv, specs.kv, specs.q, specs.q, specs.col] +
+        specs.segs[:len(segs)],
+        specs.q, jax.ShapeDtypeStruct((b, hq, sq, d), q.dtype),
+        [pltpu.VMEM((tiles.block_q, d), jnp.float32),    # dq
+         pltpu.VMEM((tiles.block_q, 1), jnp.float32)],   # delta
+        [qt, kt, vt, dot, ot, lse] + segs)
 
-    def kc(qi, ki):
-        if bd:
-            return _bd_k_block(qi, ki, bq, bk, bd)
-        return _clamp(ki, *_visited_k_blocks(qi, bq, bk, nk, causal,
-                                             window))
-
-    q_spec = pl.BlockSpec((1, 1, bq, d),
-                          lambda bi, hi, qi, ki: (bi, hi, qi, 0))
-    kv_spec = pl.BlockSpec(
-        (1, 1, bk, d),
-        lambda bi, hi, qi, ki: (bi, hi // group, kc(qi, ki), 0))
-    in_specs = [q_spec, kv_spec, kv_spec, q_spec, q_spec,
-                pl.BlockSpec((1, 1, bq, LANES),
-                             lambda bi, hi, qi, ki: (bi, hi, qi, 0))]
-    operands = [qt, kt, vt, dot, ot, lse]
-    if has_seg:
-        in_specs += [
-            pl.BlockSpec((1, 1, bq), lambda bi, hi, qi, ki: (bi, 0, qi)),
-            pl.BlockSpec((1, 1, bk),
-                         lambda bi, hi, qi, ki: (bi, 0, kc(qi, ki))),
-        ]
-        operands += [seg, seg]
-    dqt = pl.pallas_call(
-        functools.partial(
-            _dq_kernel, scale=scale, causal=causal, window=window,
-            block_q=bq, block_k=bk, num_k_blocks=nk, has_seg=has_seg,
-            bd=bd),
-        grid=(b, hq, nq, nk),
-        in_specs=in_specs,
-        out_specs=q_spec,
-        out_shape=jax.ShapeDtypeStruct((b, hq, sq, d), q.dtype),
-        scratch_shapes=[pltpu.VMEM((bq, d), jnp.float32),    # dq
-                        pltpu.VMEM((bq, 1), jnp.float32)],   # delta
-        compiler_params=_params(limits['dq']),
-        interpret=dispatch.interpret_mode(),
-    )(*operands)
-
-    # dk/dv per *query* head: the kernel walks q blocks innermost for a
-    # fixed k block; the kv-head (GQA group) reduction is one XLA sum.
-    # Its row statistics cross HBM compact, as [b, hq, 1, sq] rows.
-    bq, bk = plan['dkv']
-    nq, nk = sq // bq, sk // bk
+    # dk/dv per *query* head: the kernel walks a k tile's q tiles; the
+    # kv-head (GQA group) reduction is one XLA sum. Its row statistics
+    # cross HBM compact, as [b, hq, 1, sq] rows.
+    tiles = plan['dkv']
+    specs = _specs(tiles, d, group)
     lse_row = lse[..., 0][:, :, None, :]
     delta_row = (dot.astype(jnp.float32) *
                  ot.astype(jnp.float32)).sum(-1)[:, :, None, :]
-
-    def qc(ki, qi):
-        if bd:
-            return _bd_q_block(ki, qi, bq, bk, bd)
-        return _clamp(qi, *_visited_q_blocks(ki, bq, bk, nq, causal,
-                                             window))
-
-    q_spec = pl.BlockSpec((1, 1, bq, d),
-                          lambda bi, hi, ki, qi: (bi, hi, qc(ki, qi), 0))
-    kv_spec = pl.BlockSpec((1, 1, bk, d),
-                           lambda bi, hi, ki, qi: (bi, hi // group, ki, 0))
-    row_spec = pl.BlockSpec((1, 1, 1, bq),
-                            lambda bi, hi, ki, qi: (bi, hi, 0, qc(ki, qi)))
-    dkv_spec = pl.BlockSpec((1, 1, bk, d),
-                            lambda bi, hi, ki, qi: (bi, hi, ki, 0))
-    in_specs = [q_spec, kv_spec, kv_spec, q_spec, row_spec, row_spec]
-    operands = [qt, kt, vt, dot, lse_row, delta_row]
-    if has_seg:
-        in_specs += [
-            pl.BlockSpec((1, 1, bq),
-                         lambda bi, hi, ki, qi: (bi, 0, qc(ki, qi))),
-            pl.BlockSpec((1, 1, bk), lambda bi, hi, ki, qi: (bi, 0, ki)),
-        ]
-        operands += [seg, seg]
-    dkt, dvt = pl.pallas_call(
-        functools.partial(
-            _dkv_kernel, scale=scale, causal=causal, window=window,
-            block_q=bq, block_k=bk, num_q_blocks=nq, has_seg=has_seg,
-            bd=bd),
-        grid=(b, hq, nk, nq),
-        in_specs=in_specs,
-        out_specs=[dkv_spec, dkv_spec],
-        out_shape=[
-            jax.ShapeDtypeStruct((b, hq, sk, d), k.dtype),
-            jax.ShapeDtypeStruct((b, hq, sk, d), v.dtype),
-        ],
-        scratch_shapes=[
-            pltpu.VMEM((bk, d), jnp.float32),
-            pltpu.VMEM((bk, d), jnp.float32),
-        ],
-        compiler_params=_params(limits['dkv']),
-        interpret=dispatch.interpret_mode(),
-    )(*operands)
+    dkt, dvt = _call(
+        functools.partial(_dkv_kernel, block_q=tiles.block_q,
+                          block_k=tiles.block_k, kinds=tiles.kinds,
+                          **static),
+        tiles, (b, hq),
+        [specs.q, specs.kv, specs.kv, specs.q, specs.row, specs.row] +
+        specs.segs[:len(segs)],
+        [specs.dkv, specs.dkv],
+        [jax.ShapeDtypeStruct((b, hq, sk, d), k.dtype),
+         jax.ShapeDtypeStruct((b, hq, sk, d), v.dtype)],
+        [pltpu.VMEM((tiles.block_k, d), jnp.float32),
+         pltpu.VMEM((tiles.block_k, d), jnp.float32)],
+        [qt, kt, vt, dot, lse_row, delta_row] + segs)
 
     if group > 1:
         dkt = dkt.reshape(b, hkv, group, sk, d).sum(2)

@@ -465,12 +465,12 @@ def main(argv=None) -> None:
                     plans = ops_dispatch.flash_plan_snapshot()
                     if plans:
                         # Per kernel: tile extents and, per head, tiles
-                        # visited / masked / skipped.
-                        # (`bd_` plans add the tiles the allowed
+                        # visited / masked / skipped and the grid's
+                        # steps (`bd_` plans add the tiles the allowed
                         # pairs would fill).
                         logger.info('flash tile plan: %s', ', '.join(
                             '{} {block_q}x{block_k} {visited}/{masked}/'
-                            '{skipped}'.format(k, **p) +
+                            '{skipped} steps {steps}'.format(k, **p) +
                             (f' needed {p["needed"]}' if 'needed' in p
                              else '') for k, p in plans.items()))
                     moe_plan = ops_dispatch.moe_plan_snapshot()

@@ -30,6 +30,8 @@ from skypilot_tpu.ops import flash_attention
 from skypilot_tpu.train import block_diffusion
 from skypilot_tpu.train import trainer
 
+from flash_walk_helpers import check_walk
+
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 LAYERS = ('layer_0', 'layer_1')
 
@@ -255,7 +257,8 @@ def test_the_flash_kernels_compute_the_mask_forward_and_backward(
     """Interpreted Pallas against `mha_reference` given the dense mask by
     enumeration, grouped heads; L = 20 and 24 are no multiple of 16 (at
     20 no legal tile divides L and the tile is the whole row); and the
-    tile counts of the plan are those of an enumeration."""
+    tile counts of the plan and the list of tiles the grid visits are
+    those of an enumeration."""
     dense = _dense_mask(length, block)
     key = jax.random.PRNGKey(length + block)
     q = jax.random.normal(key, (1, 2 * length, 4, 16))
@@ -281,30 +284,20 @@ def test_the_flash_kernels_compute_the_mask_forward_and_backward(
     assert jnp.max(jnp.abs(xla - ref)) < 2e-5
     plans = dispatch.flash_plan_snapshot()
     assert sorted(plans) == ['bd_dkv', 'bd_dq', 'bd_fwd']
-    for plan in plans.values():
+    for kernel, plan in plans.items():
         bq, bk = plan['block_q'], plan['block_k']
         assert all(e == 2 * length or length % e == 0 for e in (bq, bk))
         assert (plan['visited'], plan['masked']) == \
             _count_tiles(dense, bq, bk)
         assert plan['needed'] == round(dense.sum() / (bq * bk), 2)
-        # the index maps: a visited tile names itself, a skipped one a
-        # visited tile, and the walk never turns back
-        for qi in range(2 * length // bq):
-            row = [int(flash_attention._bd_k_block(
-                qi, ki, bq, bk, (length, block)))
-                for ki in range(2 * length // bk)]
-            seen = [ki for ki in range(len(row)) if dense[
-                qi * bq:(qi + 1) * bq, ki * bk:(ki + 1) * bk].any()]
-            assert [row[ki] for ki in seen] == seen and \
-                set(row) == set(seen) and row == sorted(row), (qi, row)
-        for ki in range(2 * length // bk):
-            col = [int(flash_attention._bd_q_block(
-                ki, qi, bq, bk, (length, block)))
-                for qi in range(2 * length // bq)]
-            seen = [qi for qi in range(len(col)) if dense[
-                qi * bq:(qi + 1) * bq, ki * bk:(ki + 1) * bk].any()]
-            assert [col[qi] for qi in seen] == seen and \
-                set(col) == set(seen) and col == sorted(col), (ki, col)
+        # the grid: each tile with an allowed entry once, in walk order,
+        # both runs of a row; no step for a skipped tile, no empty row
+        by_k = kernel == 'bd_dkv'
+        codes, _ = flash_attention._walk(
+            2 * length, 2 * length, bq, bk, False, 0, False,
+            (length, block), by_k)
+        assert len(codes) == plan['steps'] == plan['visited']
+        assert check_walk(codes, dense, bq, bk, by_k) == 0
 
 
 def test_the_xla_rung_works_a_block_of_queries_at_a_time(monkeypatch):
@@ -338,7 +331,7 @@ def test_the_mask_goes_to_flash_by_the_shape_rule(monkeypatch):
     counts = flash_attention.tile_counts(16384, 16384, 512, 1024, False, 0,
                                          False, (8192, 4))
     assert counts == {'visited': 160, 'masked': 48, 'skipped': 352,
-                      'needed': 128.06}
+                      'steps': 160, 'needed': 128.06}
     with pytest.raises(ValueError, match='block-diffusion'):
         attention_ops.attention(jnp.ones((1, 16, 2, 16)),
                                 jnp.ones((1, 16, 2, 16)),

@@ -15,6 +15,7 @@ import time
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
 import requests
 
@@ -24,6 +25,8 @@ from skypilot_tpu.ops import flash_attention as flash_lib
 from skypilot_tpu.utils import env
 from skypilot_tpu.utils import faults
 from skypilot_tpu.utils import metrics as metrics_lib
+
+from flash_walk_helpers import check_walk
 
 
 def _qkv(b, sq, sk, hq, hkv, d, dtype=jnp.float32, seed=0):
@@ -237,6 +240,23 @@ TILE_PLAN_CASES = [
 ]
 
 
+# name, sq, sk, causal, window, segment ids, requested blocks, rows of
+# tiles that visit nothing (forward and dq's walk, dk/dv's walk)
+WALK_CASES = [
+    ('causal-2k', 2048, 2048, True, 0, False, None, (0, 0)),
+    ('causal-request', 1024, 1024, True, 0, False, (128, 256), (0, 0)),
+    ('mellum2-window1024', 2048, 2048, True, 1024, False, None, (0, 0)),
+    ('window-request', 2048, 2048, True, 300, False, (256, 128), (0, 0)),
+    ('window-noncausal', 1024, 1024, False, 300, False, (128, 128), (0, 0)),
+    ('causal-sq-under-sk', 512, 1024, True, 0, False, (128, 128), (0, 4)),
+    ('causal-sq-over-sk', 1024, 512, True, 0, False, (128, 128), (0, 0)),
+    ('window-sq-over-sk', 1024, 512, True, 200, False, (128, 128), (2, 0)),
+    ('segments', 1024, 1024, True, 0, True, (128, 128), (0, 0)),
+    ('segments-window', 768, 768, True, 256, True, None, (0, 0)),
+    ('no-mask', 512, 1024, False, 0, False, (128, 256), (0, 0)),
+]
+
+
 class TestTilePlan:
 
     @pytest.mark.parametrize('case', TILE_PLAN_CASES,
@@ -294,15 +314,120 @@ class TestTilePlan:
             assert plan['skipped'] == int((~some).sum()), kernel
             assert plan['masked'] == int(
                 (some if segmented else some & ~every).sum()), kernel
+            # the grid is the visited tiles, and one step for a row of
+            # tiles that visits nothing (none in these cases)
+            assert plan['steps'] == plan['visited'], kernel
 
     def test_training_shape_counts(self):
         """The numbers PERF.md quotes for sft-2k, per head."""
         assert flash_lib.tile_counts(2048, 2048, 256, 256, True, 0,
                                      False) == {
-            'visited': 36, 'masked': 8, 'skipped': 28}
+            'visited': 36, 'masked': 8, 'skipped': 28, 'steps': 36}
         assert flash_lib.tile_counts(2048, 2048, 512, 512, True, 0,
                                      False) == {
-            'visited': 10, 'masked': 4, 'skipped': 6}
+            'visited': 10, 'masked': 4, 'skipped': 6, 'steps': 10}
+
+    @pytest.mark.parametrize('case', WALK_CASES, ids=[c[0] for c in
+                                                      WALK_CASES])
+    def test_the_grid_is_the_list_of_visited_tiles(self, case):
+        """The list a call prefetches against a dense enumeration of
+        its mask, for each kernel's walk: every tile with an allowed
+        entry once, rows of tiles in turn and a row's tiles rising,
+        first and last flagged once a row, masked where some entry is
+        not allowed (with segment ids: everywhere); `steps` of the
+        recorded plan is its length. Mellum2's shapes are
+        test_hybrid_mellum2.py's, the cell's its traffic's."""
+        _, sq, sk, causal, window, segmented, blocks, empty_rows = case
+        q_pos, k_pos = np.arange(sq)[:, None], np.arange(sk)[None, :]
+        dense = np.ones((sq, sk), bool)
+        if causal:
+            dense &= q_pos >= k_pos
+        if window > 0:
+            dense &= q_pos - k_pos < window
+        dispatch.reset_for_tests()
+        q = jax.ShapeDtypeStruct((1, sq, 2, 64), jnp.float32)
+        k = jax.ShapeDtypeStruct((1, sk, 1, 64), jnp.float32)
+        seg = jax.ShapeDtypeStruct((1, sq), jnp.int32) if segmented \
+            else None
+        bq, bk = blocks or (None, None)
+        jax.eval_shape(jax.grad(lambda q_, k_, v_, seg_: (
+            flash_lib.flash_attention(
+                q_, k_, v_, causal=causal, window=window, segment_ids=seg_,
+                block_q=bq, block_k=bk).sum()), (0, 1, 2)), q, k, k, seg)
+        plans = dispatch.flash_plan_snapshot()
+        assert len(plans) == 3
+        for kernel, plan in plans.items():
+            by_k = kernel.endswith('dkv')
+            codes, _ = flash_lib._walk(
+                sq, sk, plan['block_q'], plan['block_k'], causal, window,
+                segmented, (), by_k)
+            assert not codes.flags.writeable and codes.dtype == np.int32
+            assert check_walk(
+                codes, dense, plan['block_q'], plan['block_k'], by_k,
+                segmented) == plan['steps'] - plan['visited'] == (
+                    empty_rows[by_k])
+            assert len(codes) == plan['steps']
+            assert plan['visited'] + plan['skipped'] == (
+                sq // plan['block_q']) * (sk // plan['block_k'])
+
+    def test_a_row_of_tiles_that_visits_nothing_stores_zeros(self):
+        """A window over Sq > Sk leaves the last q tiles no key (query
+        p sees k in (p - window, p], and there is no key past Sk):
+        their one step stores zeros and an `lse` of 0, their gradients
+        are zero, nothing is NaN, and the rows that do see keys are
+        the reference's."""
+        q, k, v = _qkv(1, 512, 128, 2, 1, 64, seed=5)
+        w = jax.random.normal(jax.random.PRNGKey(2), q.shape)
+
+        def flash(q_, k_, v_):
+            return flash_lib.flash_attention(
+                q_, k_, v_, causal=True, window=64, block_q=128,
+                block_k=128)
+
+        dispatch.reset_for_tests()
+        out, vjp = jax.vjp(flash, q, k, v)
+        dq, dk, dv = vjp(w)
+        plans = dispatch.flash_plan_snapshot()
+        # q tiles 2 and 3 (queries 256..511) see no key under 128
+        for kernel, visited, steps in (('fwd', 2, 4), ('dq', 2, 4),
+                                       ('dkv', 2, 2)):
+            assert (plans[f'window_{kernel}']['visited'],
+                    plans[f'window_{kernel}']['steps']) == (visited, steps)
+        assert not any(bool(jnp.isnan(x).any()) for x in (out, dq, dk, dv))
+        # queries from 128 + 63 on see no key at all
+        assert float(jnp.abs(out[:, 191:]).max()) == 0.0
+        assert float(jnp.abs(dq[:, 191:]).max()) == 0.0
+        seen = slice(0, 191)
+        ref, ref_vjp = jax.vjp(lambda *a: attention_ops.mha_reference(
+            *a, causal=True, window=64)[:, seen], q, k, v)
+        assert jnp.max(jnp.abs(out[:, seen] - ref)) < 2e-5
+        for name, got, exp in zip(('dq', 'dk', 'dv'), (dq, dk, dv),
+                                  ref_vjp(w[:, seen])):
+            assert jnp.max(jnp.abs(got - exp)) < 1e-4, name
+
+    def test_the_list_is_held_to_what_smem_takes(self, monkeypatch):
+        """Compiled, a list longer than SMEM takes is refused at trace
+        time like a tile pair over the VMEM budget (a ValueError, which
+        the ladder catches); S 65,536 causal at the rule's extents is
+        far under it."""
+        monkeypatch.setattr(dispatch, 'interpret_mode', lambda: False)
+        dispatch.reset_for_tests()
+        long = jax.ShapeDtypeStruct((1, 65536, 4, 128), jnp.bfloat16)
+        tiles = flash_lib._plan(long, long, None, None, False, True, 0,
+                                dispatch.FLASH_KERNELS)
+        assert {n: len(t.visits) for n, t in tiles.items()} == {
+            'fwd': 4160, 'dq': 2080, 'dkv': 8256}
+        assert max(p['steps'] for p in
+                   dispatch.flash_plan_snapshot().values()) == 8256 < \
+            flash_lib.MAX_STEPS
+        longer = jax.ShapeDtypeStruct((1, 131072, 4, 128), jnp.bfloat16)
+        with pytest.raises(ValueError, match='longer than SMEM takes'):
+            flash_lib._plan(longer, longer, 128, 128, False, True, 0,
+                            ('fwd',))
+        # and through the ladder's eyes: the same refusal, not a crash
+        with pytest.raises(ValueError, match='longer than SMEM takes'):
+            jax.eval_shape(lambda x: flash_lib.flash_attention(
+                x, x, x, block_q=128, block_k=128), longer)
 
     def test_fwd_lse_shape_and_values(self):
         """Ring attention's entry: [B, Hq, Sq] float32 logsumexp of the
@@ -328,7 +453,8 @@ class TestTilePlan:
         with tracing.Tracer('test').start_span('trace-flash') as span:
             flash_lib.flash_attention(q, k, v, block_q=128, block_k=256)
         assert span.attributes['ops.flash_plan.fwd'] == (
-            'block_q=128 block_k=256 visited=6 masked=4 skipped=2')
+            'block_q=128 block_k=256 visited=6 masked=4 skipped=2 '
+            'steps=6')
         assert dispatch.flash_plan_snapshot()['fwd']['visited'] == 6
 
 

@@ -149,6 +149,8 @@ class TestFlashKernelLowers:
         assert (plan['window_fwd']['visited'],
                 plan['window_dq']['visited'],
                 plan['window_dkv']['visited']) == (62, 31, 93)
+        # the grid is those tiles: no step for a skipped one
+        assert all(p['steps'] == p['visited'] for p in plan.values())
         np.testing.assert_allclose(
             np.asarray(out, np.float32), np.asarray(ref, np.float32),
             atol=3e-2, rtol=3e-2)
@@ -187,6 +189,7 @@ class TestFlashKernelLowers:
                        p['masked']) for name, p in plan.items()} == {
             'bd_fwd': (512, 1024, 160, 48), 'bd_dq': (1024, 1024, 80, 24),
             'bd_dkv': (512, 512, 288, 48)}
+        assert all(p['steps'] == p['visited'] for p in plan.values())
         (_, ref), grefs = both('xla')(q, k, v, cot)
         np.testing.assert_allclose(
             np.asarray(out, np.float32), np.asarray(ref, np.float32),
