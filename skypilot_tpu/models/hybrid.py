@@ -295,7 +295,9 @@ class HybridModel(nn.Module):
                 x = x[:, :s // 2]
             x = llama_lib.RMSNorm(base, name='final_norm')(x)
             if base.tie_embeddings:
-                logits = jnp.einsum('bsd,vd->bsv', x, embed.astype(dtype))
+                with jax.named_scope('lm_head'):   # as the untied head
+                    logits = jnp.einsum('bsd,vd->bsv', x,
+                                        embed.astype(dtype))
             else:
                 logits = llama_lib._dense(
                     base.vocab_size, ('embed', 'vocab'), 'lm_head',

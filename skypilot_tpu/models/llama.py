@@ -898,7 +898,10 @@ class LlamaModel(nn.Module):
             x = jnp.take_along_axis(
                 x, logit_positions[:, :, None], axis=1)
         if cfg.tie_embeddings:
-            logits = jnp.einsum('bsd,vd->bsv', x, embed.astype(dtype))
+            # Under the untied head's name, so that a profile finds the
+            # head's product by it either way.
+            with jax.named_scope('lm_head'):
+                logits = jnp.einsum('bsd,vd->bsv', x, embed.astype(dtype))
         else:
             logits = _dense(cfg.vocab_size, ('embed', 'vocab'), 'lm_head',
                             cfg.param_dtype, dtype, cfg.quant)(x)

@@ -23,7 +23,6 @@ import threading
 import time
 from typing import Any, Callable, Dict, Optional
 
-from skypilot_tpu.utils import metrics as metrics_lib
 from skypilot_tpu.utils import env
 
 ENV_FILE = 'SKYT_HEARTBEAT_FILE'
@@ -65,7 +64,7 @@ class HeartbeatWriter:
     step (cheap — a few float ops under a lock), flushed to ``path``
     at most once per interval.
 
-    ``path=None`` keeps the metrics/in-memory side live without file
+    ``path=None`` keeps the in-memory side live without file
     IO (bench and single-process runs outside a gang).
     """
 
@@ -73,7 +72,6 @@ class HeartbeatWriter:
                  clock: Callable[[], float] = time.time,
                  interval_s: Optional[float] = None,
                  ewma_alpha: float = 0.2,
-                 registry: Optional['metrics_lib.MetricsRegistry'] = None,
                  device_kind: Optional[str] = None) -> None:
         self.path = path
         self.rank = int(rank)
@@ -92,16 +90,6 @@ class HeartbeatWriter:
         self._progress_t = clock()
         self._last_write = float('-inf')
         self._device_kind = device_kind
-        reg = registry or metrics_lib.REGISTRY
-        self._m_step = reg.gauge(
-            'skyt_train_heartbeat_step',
-            'Latest training step this rank heartbeated', ('rank',))
-        # Shared with trainer.TrainMetricsPublisher (same name/help →
-        # same registry family): the heartbeat refreshes it per step
-        # instead of only at log boundaries.
-        self._m_step_s = reg.gauge(
-            'skyt_train_step_seconds',
-            'Wall time of the most recent training step')
 
     # ------------------------------------------------------------ updates
     def mark_phase(self, phase: str) -> None:
@@ -135,13 +123,6 @@ class HeartbeatWriter:
             if tokens_per_sec is not None:
                 self._tokens_per_sec = float(tokens_per_sec)
             rec = self._record_locked(now)
-            # Lock-discipline fix (skyanalyze): capture under the
-            # lock — the sentinel thread calls snapshot() while the
-            # training thread updates the EWMA here.
-            ewma = self._ewma
-        self._m_step.labels(str(self.rank)).set(float(step))
-        if ewma is not None:
-            self._m_step_s.set(ewma)
         self._write(rec, now)
 
     # ------------------------------------------------------------- views
@@ -200,7 +181,8 @@ def writer_from_env(rank: Optional[int] = None,
                     ) -> Optional[HeartbeatWriter]:
     """The sft entry point: None when SKYT_WATCHDOG=0 (zero-overhead
     path), else a writer targeting SKYT_HEARTBEAT_FILE (the per-host
-    agent exports it per rank; unset → metrics-only heartbeat)."""
+    agent exports it per rank; unset → an in-memory heartbeat, for the
+    sentinel and the bundles)."""
     if not enabled():
         return None
     if rank is None:

@@ -22,11 +22,17 @@ Contracts:
     data bug fails the step loop, not a silent stall.
   * close() always unblocks and joins the producer, whether it is
     blocked on a full queue or mid-iteration.
+  * in a device profile the producer thread's line holds
+    `prefetch.build` (the source's next) and `prefetch.place` (the
+    device_put) spans. The consumer's wait is the caller's to time
+    (sft's `input_wait_ms=`): a next() that finds a batch staged is a
+    queue pop.
 """
 import queue
 import threading
 from typing import Any, Callable, Dict, Iterator, Optional
 
+import jax
 import numpy as np
 
 from skypilot_tpu.utils import log_utils
@@ -45,7 +51,6 @@ def make_sharded_placer(mesh, rules=None) -> Optional[
     is process-local, and a device_put to a non-addressable sharding
     is not well defined — jit's own transfer handles that case the way
     it always has)."""
-    import jax
     if mesh is None or mesh.empty or jax.process_count() > 1:
         return None
     from skypilot_tpu.parallel import sharding as sharding_lib
@@ -96,11 +101,17 @@ class Prefetcher:
     # ------------------------------------------------------------ producer
     def _run(self) -> None:
         try:
-            for batch in self._source:
+            source = iter(self._source)
+            while True:
+                with jax.profiler.TraceAnnotation('prefetch.build'):
+                    batch = next(source, _DONE)
+                if batch is _DONE:
+                    break
                 if self._stop.is_set():
                     return
                 if self._place is not None:
-                    batch = self._place(batch)
+                    with jax.profiler.TraceAnnotation('prefetch.place'):
+                        batch = self._place(batch)
                 if not self._offer(batch):
                     return
             self._offer(_DONE)

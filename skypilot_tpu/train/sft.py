@@ -7,21 +7,92 @@ contract, sharded Llama/Mixtral, async Orbax checkpoint/resume (the
 preemption-recovery half the managed-jobs controller needs), JSONL or
 synthetic data.
 """
-import argparse
-import json
 import time
-from typing import Dict, Iterator, Optional
 
-import jax
-import jax.numpy as jnp
-import numpy as np
+_T_FIRST_LINE = time.perf_counter()   # set-up's `imports` phase starts here
 
-from skypilot_tpu.utils import compile_cache
-from skypilot_tpu.utils import faults
-from skypilot_tpu.utils import log_utils
-from skypilot_tpu.utils import env
+import argparse  # noqa: E402
+import json  # noqa: E402
+from typing import Dict, Iterator, Optional  # noqa: E402
+
+import jax  # noqa: E402
+import jax.numpy as jnp  # noqa: E402
+import numpy as np  # noqa: E402
+
+from skypilot_tpu.utils import compile_cache  # noqa: E402
+from skypilot_tpu.utils import faults  # noqa: E402
+from skypilot_tpu.utils import log_utils  # noqa: E402
+from skypilot_tpu.utils import env  # noqa: E402
 
 logger = log_utils.init_logger(__name__)
+
+_IMPORTS_S = time.perf_counter() - _T_FIRST_LINE
+
+
+class _SetupPhases:
+    """Where a run's set-up went, measured where it happens: consecutive
+    phases from main()'s start, each ended by `mark(<its name>)`, and
+    the module's own imports before them. After the first log boundary
+    sft prints them as one line, `setup phases: ...`, whose parts sum to
+    its `total` (docs/observability.md "Device profiles"; a profile
+    starts after set-up, so the line is the record)."""
+
+    def __init__(self) -> None:
+        self.seconds = {'imports': _IMPORTS_S}
+        self._last = time.perf_counter()
+
+    def mark(self, name: str) -> None:
+        now = time.perf_counter()
+        self.seconds[name] = now - self._last
+        self._last = now
+
+    def line(self, before: Dict, after: Dict) -> str:
+        """`before`, `after`: compile_cache.snapshot() around the first
+        step_fn call: its tracing, lowering, and backend compile or
+        cache read, in brackets behind `first_step`."""
+        stages = ' '.join(
+            f'{name}={after[key] - before[key]:.3f}' for name, key in (
+                ('trace', 'trace_seconds'), ('lower', 'lower_seconds'),
+                ('compile_or_read', 'compile_seconds')))
+        parts = ' '.join(
+            f'{name}={s:.3f}' + (f' ({stages})' if name == 'first_step'
+                                 else '') for name, s in self.seconds.items())
+        return f'{parts} total={sum(self.seconds.values()):.3f}'
+
+
+def _log_kernel_plans(ops_dispatch) -> None:
+    """After the first step traced and compiled the model: say which
+    kernel ladder rung each op landed on, so a run silently degraded to
+    the XLA reference (e.g. an un-lowerable shape) is visible in the job
+    log, and the plans fixed at trace time."""
+    paths = ops_dispatch.snapshot()
+    if paths:
+        # The flash backward is the Pallas kernels, always; the words
+        # stay for the log's readers.
+        logger.info('kernel dispatch paths: %s (pallas %s, flash backward '
+                    'pallas)', paths, 'interpreted'
+                    if ops_dispatch.interpret_mode() else 'compiled')
+    plans = ops_dispatch.flash_plan_snapshot()
+    if plans:
+        # Per kernel: tile extents and, per head, tiles visited / masked
+        # / skipped and the grid's steps (`bd_` plans add the tiles the
+        # allowed pairs would fill).
+        logger.info('flash tile plan: %s', ', '.join(
+            '{} {block_q}x{block_k} {visited}/{masked}/'
+            '{skipped} steps {steps}'.format(k, **p) +
+            (f' needed {p["needed"]}' if 'needed' in p else '')
+            for k, p in plans.items()))
+    moe_plan = ops_dispatch.moe_plan_snapshot()
+    if moe_plan:
+        logger.info('moe routing plan: %s', ' '.join(
+            f'{k}={v}' for k, v in moe_plan.items()))
+    grouped = ops_dispatch.grouped_plan_line()
+    if grouped:
+        logger.info('grouped tile plan: %s', grouped)
+    bd_plan = ops_dispatch.bd_plan_snapshot()
+    if bd_plan:
+        logger.info('block diffusion plan: %s', ' '.join(
+            f'{k}={v}' for k, v in bd_plan.items()))
 
 
 def parse_mesh(spec: Optional[str], n_devices: int):
@@ -45,7 +116,7 @@ def _comms_report(step_fn, state, batch, mesh, dcn_axes, lowered,
     "Comms plane"): census the step's collectives, multiply by the
     CACHED link profile (sft never probes — the probe runs in bench/
     validation or `python -m skypilot_tpu.parallel.collectives`), log
-    the per-axis breakdown next to MFU, attach it to train.steps spans
+    the per-axis breakdown, attach it to train.steps spans
     and the postmortem live state. Never raises; returns the report
     dict or None when the plane is off."""
     from skypilot_tpu.parallel import comms_census
@@ -141,6 +212,7 @@ def jsonl_batches(path: str, vocab_size: int, batch: int, seq: int,
 
 
 def main(argv=None) -> None:
+    phases = _SetupPhases()
     compile_cache.configure()
     parser = argparse.ArgumentParser()
     parser.add_argument('--model', default='llama3-1b')
@@ -198,6 +270,7 @@ def main(argv=None) -> None:
     logger.info('process %d/%d, %d local / %d global devices',
                 jax.process_index(), jax.process_count(),
                 jax.local_device_count(), jax.device_count())
+    phases.mark('runtime')      # the first jax.devices(): the TPU's start
     from skypilot_tpu.ops import dispatch as ops_dispatch
     logger.info('device: %s', json.dumps(ops_dispatch.device_info()))
 
@@ -282,10 +355,14 @@ def main(argv=None) -> None:
     tcfg = trainer.TrainerConfig(learning_rate=args.lr,
                                  total_steps=args.steps)
     tx = trainer.make_optimizer(tcfg)
+    phases.mark('build')
     sample = jnp.zeros((args.batch, args.seq * (2 if bd else 1)),
                        jnp.int32)
     state, _ = trainer.create_sharded_state(model, tx, mesh, sample,
                                             jax.random.PRNGKey(0))
+    # The host's share: the init program traced, compiled or read, and
+    # enqueued. The device fills the state while what follows runs.
+    phases.mark('state_init')
 
     from skypilot_tpu.train import checkpoint as ckpt_lib
     # Preemption-safe exit: SIGTERM/SIGINT requests a checkpoint at
@@ -393,29 +470,14 @@ def main(argv=None) -> None:
                    synthetic_batches(data_vocab, args.batch, args.seq,
                                      shift=bd is None))
 
+        from skypilot_tpu.parallel import comms_census
         from skypilot_tpu.utils import profiling
         prof = profiling.StepProfiler()   # no-op unless SKYT_PROFILE_DIR set
-        mpub = trainer.TrainMetricsPublisher()
-
-        # MFU source (docs/observability.md "Fleet plane"): FLOPs per
-        # step from the step's own HLO cost analysis at the LOWERED
-        # stage — global (pre-SPMD-partition, matching the global-peak
-        # denominator) and compile-free (no mid-run stall) — with the
-        # analytic 6ND-style count only as the fallback. Resolved
-        # lazily at the first log boundary; SKYT_TRAIN_MFU=0 skips it.
-        def _analytic_flops():
-            per_tok = 6 * cfg.num_params() + \
-                12 * cfg.n_layers * cfg.dim * args.seq
-            return per_tok * args.batch * args.seq * \
-                jax.process_count()
-
-        flops_state = None      # resolved -> (flops_per_step, source)
         comms_rep = None        # resolved -> comms census report dict
-        first_boundary_done = False
         # Deferred metrics: publish() pulls step k-1's loss/grad-norm while
         # step k runs — the log boundary never syncs the step chain's head
         # (logged loss lags one step; see trainer.DeferredMetrics).
-        dmetrics = trainer.DeferredMetrics(mpub)
+        dmetrics = trainer.DeferredMetrics()
 
         # Overlap layer: assemble + device_put the next batches on a
         # background thread while the current step runs (train/prefetch.py).
@@ -439,149 +501,127 @@ def main(argv=None) -> None:
             # First loop iteration traces + compiles; the watchdog's
             # stall budget must not apply until real steps flow.
             hb.mark_phase('compile')
+        phases.mark('load')
         t0 = time.perf_counter()
         last_t = t0
         tokens_seen = 0
+        # The time the loop waited for its input, next(batches): with a
+        # prefetcher a queue pop unless the device outran the host.
+        wait_s = last_wait_s = 0.0
+        # In a step-window profile (SKYT_PROFILE_DIR) each iteration is
+        # a `train.step` span on the host's line, on the device trace's
+        # clock, with the calls below as its children
+        # (docs/observability.md "Device profiles").
+        span = jax.profiler.TraceAnnotation
         try:
             for step in range(start_step, args.steps):
                 prof.on_step(step - start_step)
-                batch = next(batches)
-                state, metrics = step_fn(state, batch)
-                dmetrics.on_step(metrics)   # device refs only — no sync
-                if step == start_step:
-                    # First step traced+compiled the model: say which
-                    # kernel ladder rung each op landed on, so a run
-                    # silently degraded to the XLA reference (e.g. an
-                    # un-lowerable shape) is visible in the job log.
-                    paths = ops_dispatch.snapshot()
-                    if paths:
-                        # The flash backward is the Pallas kernels,
-                        # always; the words stay for the log's readers.
-                        logger.info(
-                            'kernel dispatch paths: %s (pallas %s, '
-                            'flash backward pallas)', paths,
-                            'interpreted' if ops_dispatch.interpret_mode()
-                            else 'compiled')
-                    plans = ops_dispatch.flash_plan_snapshot()
-                    if plans:
-                        # Per kernel: tile extents and, per head, tiles
-                        # visited / masked / skipped and the grid's
-                        # steps (`bd_` plans add the tiles the allowed
-                        # pairs would fill).
-                        logger.info('flash tile plan: %s', ', '.join(
-                            '{} {block_q}x{block_k} {visited}/{masked}/'
-                            '{skipped} steps {steps}'.format(k, **p) +
-                            (f' needed {p["needed"]}' if 'needed' in p
-                             else '') for k, p in plans.items()))
-                    moe_plan = ops_dispatch.moe_plan_snapshot()
-                    if moe_plan:
-                        logger.info('moe routing plan: %s', ' '.join(
-                            f'{k}={v}' for k, v in moe_plan.items()))
-                    grouped = ops_dispatch.grouped_plan_line()
-                    if grouped:
-                        logger.info('grouped tile plan: %s', grouped)
-                    bd_plan = ops_dispatch.bd_plan_snapshot()
-                    if bd_plan:
-                        logger.info('block diffusion plan: %s', ' '.join(
-                            f'{k}={v}' for k, v in bd_plan.items()))
-                tokens_seen += args.batch * args.seq * jax.process_count()
-                if hb is not None:
-                    live_state['step'] = step
-                    hb.on_step(step + 1,
-                               tokens_per_sec=tokens_seen /
-                               max(time.perf_counter() - t0, 1e-9))
-                saved = ckpt.save(step + 1, state) \
-                    if ckpt is not None else False
-                # Chaos hook: kind=preempt here SIGTERMs this process, so
-                # the guard path below runs deterministically in tests;
-                # kind=hang (rank-targetable via `where=rank:R`) wedges
-                # the step loop so the watchdog/postmortem plane can be
-                # drilled on CPU (docs/robustness.md fault catalog).
-                faults.inject('train.step', step=step, rank=rank)
-                if guard.requested:
+                first = step == start_step
+                with jax.profiler.StepTraceAnnotation('train.step',
+                                                      step_num=step):
+                    with span('train.input_wait'):
+                        t_in = time.perf_counter()
+                        batch = next(batches)
+                        wait_s += time.perf_counter() - t_in
+                    if first:
+                        phases.mark('first_batch')
+                        compiled_before = compile_cache.snapshot()
+                    with span('train.dispatch'):
+                        state, metrics = step_fn(state, batch)
+                    dmetrics.on_step(metrics)   # device refs only — no sync
+                    if first:
+                        phases.mark('first_step')
+                        compiled_after = compile_cache.snapshot()
+                        _log_kernel_plans(ops_dispatch)
+                    tokens_seen += args.batch * args.seq * \
+                        jax.process_count()
                     if hb is not None:
-                        # SIGTERM path of the bundle contract: the dump
-                        # is cheap and the evidence free (the preempted
-                        # run is one operators ask questions about).
-                        postmortem_lib.dump_bundle(
-                            'preempt', rank=rank,
-                            heartbeat=hb.snapshot(),
-                            train_state=train_state_reader())
-                    if ckpt is not None:
-                        if not saved:
-                            ckpt.save(step + 1, state, force=True)
-                        ckpt.wait()   # async write must land before exit
-                        logger.info('preemption: checkpoint saved at '
-                                    'step %d', step + 1)
-                    logger.info(
-                        'preemption requested (signal %s); exiting with '
-                        'code %d for controller recovery', guard.signum,
-                        guard.EXIT_CODE)
-                    raise SystemExit(guard.EXIT_CODE)
-                if (step + 1) % args.log_every == 0:
-                    now = time.perf_counter()
-                    dt = now - t0
-                    # Step time averaged over the logging window; the only
-                    # device pull here is DeferredMetrics' step-(k-1) read,
-                    # which overlaps step k's device compute.
-                    n_window = min(args.log_every, step + 1 - start_step)
-                    step_time = (now - last_t) / max(1, n_window)
-                    if not first_boundary_done:
-                        first_boundary_done = True
-                        from skypilot_tpu.parallel import comms_census
-                        # MFU is a device metric: off the TPU there
-                        # is no peak to divide by, so none is published.
-                        mfu_on = env.get_bool('SKYT_TRAIN_MFU', True) \
-                            and jax.default_backend() == 'tpu'
-                        census_on = comms_census.census_mode() != 'off'
-                        # One lowering feeds BOTH the MFU cost
-                        # analysis and the comms census (same stage,
-                        # no backend compile — docs/observability.md
-                        # "Comms plane").
-                        lowered = None
-                        if mfu_on or census_on:
+                        live_state['step'] = step
+                        hb.on_step(step + 1,
+                                   tokens_per_sec=tokens_seen /
+                                   max(time.perf_counter() - t0, 1e-9))
+                    saved = ckpt.save(step + 1, state) \
+                        if ckpt is not None else False
+                    # Chaos hook: kind=preempt here SIGTERMs this process,
+                    # so the guard path below runs deterministically in
+                    # tests; kind=hang (rank-targetable via `where=rank:R`)
+                    # wedges the step loop so the watchdog/postmortem plane
+                    # can be drilled on CPU (docs/robustness.md fault
+                    # catalog).
+                    faults.inject('train.step', step=step, rank=rank)
+                    if guard.requested:
+                        if hb is not None:
+                            # SIGTERM path of the bundle contract: the dump
+                            # is cheap and the evidence free (the preempted
+                            # run is one operators ask questions about).
+                            postmortem_lib.dump_bundle(
+                                'preempt', rank=rank,
+                                heartbeat=hb.snapshot(),
+                                train_state=train_state_reader())
+                        if ckpt is not None:
+                            if not saved:
+                                ckpt.save(step + 1, state, force=True)
+                            ckpt.wait()   # async write must land before exit
+                            logger.info('preemption: checkpoint saved at '
+                                        'step %d', step + 1)
+                        logger.info(
+                            'preemption requested (signal %s); exiting '
+                            'with code %d for controller recovery',
+                            guard.signum, guard.EXIT_CODE)
+                        raise SystemExit(guard.EXIT_CODE)
+                    if (step + 1) % args.log_every != 0:
+                        continue
+                    with span('train.log'):   # train.pull stands inside it
+                        now = time.perf_counter()
+                        dt = now - t0
+                        # Step time averaged over the logging window; the
+                        # only device pull here is DeferredMetrics'
+                        # step-(k-1) read, which overlaps step k's device
+                        # compute.
+                        n_window = min(args.log_every,
+                                       step + 1 - start_step)
+                        step_time = (now - last_t) / max(1, n_window)
+                        if 'first_boundary' not in phases.seconds and \
+                                comms_census.census_mode() != 'off':
+                            # The census reads the step's lowering (same
+                            # stage, no backend compile —
+                            # docs/observability.md "Comms plane").
+                            lowered = None
                             try:
                                 lowered = step_fn.lower(state, batch)
                             except Exception as e:  # pylint: disable=broad-except
-                                logger.warning('step lowering failed '
-                                               '(%r)', e)
-                        if mfu_on:
-                            flops_state = profiling.train_step_flops(
-                                step_fn, state, batch,
-                                analytic=_analytic_flops,
-                                lowered=lowered)
-                            logger.info('train FLOPs/step: %s (%s)',
-                                        f'{flops_state[0]:.3e}'
-                                        if flops_state[0] else
-                                        'unknown', flops_state[1])
-                        if census_on:
+                                logger.warning('step lowering failed (%r)',
+                                               e)
                             comms_rep = _comms_report(
                                 step_fn, state, batch, mesh, dcn_axes,
                                 lowered, dmetrics, live_state)
-                    if comms_rep and comms_rep.get('axes'):
-                        # Per-window publication: the bytes counter
-                        # grows with the steps the census covers, the
-                        # per-step seconds gauge just refreshes.
-                        from skypilot_tpu.parallel import comms_census
-                        comms_census.publish_metrics(comms_rep,
-                                                     steps=n_window)
-                    mfu_val = None
-                    if flops_state and flops_state[0]:
-                        denom = profiling.peak_flops(
-                            jax.devices()[0]) * jax.device_count()
-                        mfu_val = flops_state[0] / \
-                            max(step_time, 1e-9) / denom
-                    host = dmetrics.publish(
-                        step_time_s=step_time,
-                        tokens_per_sec=tokens_seen / dt,
-                        steps=n_window, mfu=mfu_val)
-                    last_t = now
-                    logger.info('step %d/%d loss=%.4f tokens/s=%.0f%s%s',
-                                step + 1, args.steps,
-                                host.get('loss', float('nan')),
-                                tokens_seen / dt,
-                                trainer.format_moe_stats(host),
-                                block_diffusion.format_stats(host))
+                        if comms_rep and comms_rep.get('axes'):
+                            # Per-window publication: the bytes counter
+                            # grows with the steps the census covers, the
+                            # per-step seconds gauge just refreshes.
+                            comms_census.publish_metrics(comms_rep,
+                                                         steps=n_window)
+                        wait_ms = 1e3 * (wait_s - last_wait_s) / \
+                            max(1, n_window)
+                        last_wait_s = wait_s
+                        host = dmetrics.publish(
+                            step_time_s=step_time,
+                            tokens_per_sec=tokens_seen / dt,
+                            steps=n_window, input_wait_ms=wait_ms)
+                        last_t = now
+                        logger.info(
+                            'step %d/%d loss=%.4f tokens/s=%.0f%s%s '
+                            'grad_norm=%.4f input_wait_ms=%.3f',
+                            step + 1, args.steps,
+                            host.get('loss', float('nan')),
+                            tokens_seen / dt,
+                            trainer.format_moe_stats(host),
+                            block_diffusion.format_stats(host),
+                            host.get('grad_norm', float('nan')), wait_ms)
+                    if 'first_boundary' not in phases.seconds:
+                        phases.mark('first_boundary')
+                        logger.info('setup phases: %s', phases.line(
+                            compiled_before, compiled_after))
         except SystemExit:
             raise
         except Exception:

@@ -19,7 +19,6 @@ from jax.sharding import Mesh
 
 from skypilot_tpu.parallel import sharding as sharding_lib
 from skypilot_tpu.train import block_diffusion
-from skypilot_tpu.utils import metrics as metrics_lib
 from skypilot_tpu.utils import tracing
 
 
@@ -66,61 +65,6 @@ def format_moe_stats(host: Dict[str, float]) -> str:
                 host['moe_rows'], host['moe_rows_worst']))
 
 
-class TrainMetricsPublisher:
-    """Training-side view of the shared metrics plane: step time,
-    throughput, loss, and grad norm land in the same registry the
-    serving layer exposes, so the dashboard and tests read one API
-    (utils/metrics.py) for every layer.
-
-    publish() pulls only host-side floats the caller already has (or
-    device scalars it is about to log anyway) — it adds no device
-    syncs of its own to the hot loop.
-    """
-
-    def __init__(self, registry: Optional[
-            'metrics_lib.MetricsRegistry'] = None) -> None:
-        reg = registry or metrics_lib.REGISTRY
-        self.step_seconds = reg.gauge(
-            'skyt_train_step_seconds',
-            'Wall time of the most recent training step')
-        self.tokens_per_sec = reg.gauge(
-            'skyt_train_tokens_per_sec',
-            'Training throughput over the run so far')
-        self.loss = reg.gauge(
-            'skyt_train_loss', 'Most recently logged training loss')
-        self.grad_norm = reg.gauge(
-            'skyt_train_grad_norm',
-            'Most recently logged global gradient norm')
-        self.steps = reg.counter(
-            'skyt_train_steps_total', 'Training steps completed')
-        self.mfu = reg.gauge(
-            'skyt_train_mfu',
-            'Model FLOPs utilization over the last logging window '
-            '(FLOPs from the compiled step\'s own cost_analysis when '
-            'the backend reports them; utils/profiling.py)')
-
-    def publish(self, metrics: Dict[str, Any],
-                step_time_s: Optional[float] = None,
-                tokens_per_sec: Optional[float] = None,
-                steps: int = 1,
-                mfu: Optional[float] = None) -> None:
-        """metrics: the train step's output dict ({'loss', 'grad_norm',
-        ...}); device scalars are pulled here (call at log boundaries,
-        not every step, if that transfer matters)."""
-        self.steps.inc(steps)
-        if 'loss' in metrics:
-            self.loss.set(float(jax.device_get(metrics['loss'])))
-        if 'grad_norm' in metrics:
-            self.grad_norm.set(
-                float(jax.device_get(metrics['grad_norm'])))
-        if step_time_s is not None:
-            self.step_seconds.set(step_time_s)
-        if tokens_per_sec is not None:
-            self.tokens_per_sec.set(tokens_per_sec)
-        if mfu is not None:
-            self.mfu.set(mfu)
-
-
 class DeferredMetrics:
     """One-step-deferred metrics pulls: the overlap half of the metrics
     plane (docs/performance.md).
@@ -141,11 +85,10 @@ class DeferredMetrics:
     bare device pulls inside sft.py loops.
     """
 
-    def __init__(self, publisher: 'TrainMetricsPublisher',
+    def __init__(self,
                  keys: Tuple[str, ...] = ('loss', 'grad_norm')
                  + MOE_STAT_KEYS + block_diffusion.BD_STAT_KEYS,
                  tracer: Optional['tracing.Tracer'] = None) -> None:
-        self._pub = publisher
         self._keys = keys
         self._prev: Optional[Dict[str, Any]] = None
         self._cur: Optional[Dict[str, Any]] = None
@@ -172,23 +115,23 @@ class DeferredMetrics:
     def publish(self, step_time_s: Optional[float] = None,
                 tokens_per_sec: Optional[float] = None,
                 steps: int = 1,
-                mfu: Optional[float] = None) -> Dict[str, float]:
-        """Pull step k-1's metrics (k still in flight) and publish them;
-        returns the host floats for logging. First call of a run (no
-        k-1 yet) pulls the current step's.
+                input_wait_ms: Optional[float] = None) -> Dict[str, float]:
+        """Pull step k-1's metrics (k still in flight) and return the
+        host floats for logging. First call of a run (no k-1 yet) pulls
+        the current step's. The pull stands in a device profile as the
+        host span `train.pull`: the host blocked on step k-1.
 
         Also emits a `train.steps` span over the logging window into
         the tracing plane (utils/tracing.py) carrying the deferred
-        step-(k-1) annotations — the training leg of the shared
-        timeline. Forced-sampled: train publishes at log boundaries
-        (tens of seconds apart), so head-sampling them away would save
-        nothing and lose the only train spans there are."""
+        step-(k-1) annotations and the window's mean input wait a step
+        — the training leg of the shared timeline. Forced-sampled:
+        train publishes at log boundaries (tens of seconds apart), so
+        head-sampling them away would save nothing and lose the only
+        train spans there are."""
         src = self._prev if self._prev is not None else self._cur
-        host = ({k: float(v) for k, v in
-                 jax.device_get(src).items()} if src else {})
-        self._pub.publish(host, step_time_s=step_time_s,
-                          tokens_per_sec=tokens_per_sec, steps=steps,
-                          mfu=mfu)
+        with jax.profiler.TraceAnnotation('train.pull'):
+            host = ({k: float(v) for k, v in
+                     jax.device_get(src).items()} if src else {})
         # The window advances whether or not tracing is on: enabling
         # SKYT_TRACE mid-run must produce a span covering ONE logging
         # window, not the whole run so far.
@@ -205,8 +148,8 @@ class DeferredMetrics:
                 attrs['step_time_s'] = step_time_s
             if tokens_per_sec is not None:
                 attrs['tokens_per_sec'] = tokens_per_sec
-            if mfu is not None:
-                attrs['mfu'] = round(mfu, 4)
+            if input_wait_ms is not None:
+                attrs['input_wait_ms'] = round(input_wait_ms, 3)
             (self._tracer or tracing.TRACER).record_span(
                 'train.steps', start, now, attributes=attrs,
                 sampled=True)
@@ -328,10 +271,12 @@ def make_train_step(model: nn.Module, tx, mesh: Mesh,
 
         stats = {}
         if bd is not None:
-            x_t, masked, level, stats = block_diffusion.step_noise(
-                batch['tokens'], jax.random.fold_in(
+            with jax.named_scope('bd_objective'):
+                key = jax.random.fold_in(
                     jax.random.PRNGKey(block_diffusion.NOISE_KEY),
-                    state.step), bd)
+                    state.step)
+            x_t, masked, level, stats = block_diffusion.step_noise(
+                batch['tokens'], key, bd)
 
         def loss_fn(params):
             if bd is not None:
@@ -343,7 +288,9 @@ def make_train_step(model: nn.Module, tx, mesh: Mesh,
                     {'params': params}, batch['tokens'],
                     segment_ids=batch.get('segment_ids'),
                     mutable=['intermediates'])
-                loss, n_tok = cross_entropy_loss(logits, batch['targets'])
+                with jax.named_scope('loss'):
+                    loss, n_tok = cross_entropy_loss(logits,
+                                                     batch['targets'])
             # Aux losses sown by the model (MoE load-balance/z-loss).
             for aux in jax.tree.leaves(
                     mutated.get('intermediates', {}).get(
@@ -355,8 +302,9 @@ def make_train_step(model: nn.Module, tx, mesh: Mesh,
 
         (loss, (n_tok, moe_stats)), grads = jax.value_and_grad(
             loss_fn, has_aux=True)(state.params)
-        new_state = state.apply_gradients(grads, tx)
-        gnorm = optax.global_norm(grads)
+        with jax.named_scope('optimizer'):
+            new_state = state.apply_gradients(grads, tx)
+            gnorm = optax.global_norm(grads)
         metrics = {'loss': loss, 'tokens': n_tok, 'grad_norm': gnorm}
         metrics.update(moe_stats)
         return new_state, metrics
@@ -383,9 +331,9 @@ def make_train_step(model: nn.Module, tx, mesh: Mesh,
             return _jitted(state, batch)
 
     def lowered(state, batch):
-        # AOT lowering under the same mesh/axis-rules context, for
-        # utils/profiling.train_step_flops (cost-analysis MFU).
-        # Lowering only — no backend compile, no mid-run stall.
+        # AOT lowering under the same mesh/axis-rules context, for the
+        # comms census at sft's first log boundary. Lowering only — no
+        # backend compile, no mid-run stall.
         with mesh, nn.logical_axis_rules(list(rules)):
             return _jitted.lower(state, batch)
 

@@ -15,6 +15,7 @@ whether the cache answered.
 """
 import os
 import threading
+import time
 from typing import Any, Dict
 
 ENV = 'JAX_COMPILATION_CACHE_DIR'
@@ -25,11 +26,21 @@ DEFAULT_DIR = os.path.join(
 
 _HIT = '/jax/compilation_cache/cache_hits'
 _MISS = '/jax/compilation_cache/cache_misses'
-_COMPILE = '/jax/core/compile/backend_compile_duration'
+# Seconds by stage, summed over every program this process made:
+# tracing to a jaxpr, lowering that to an MLIR module, and the backend's
+# compile (or its read from the persistent cache).
+_DURATIONS = {
+    '/jax/core/compile/jaxpr_trace_duration': 'trace_seconds',
+    '/jax/core/compile/jaxpr_to_mlir_module_duration': 'lower_seconds',
+    '/jax/core/compile/backend_compile_duration': 'compile_seconds',
+}
 
 _lock = threading.Lock()
-_stats = {'hits': 0, 'misses': 0, 'compile_seconds': 0.0}
+_stats = {'hits': 0, 'misses': 0, 'compile_seconds': 0.0,
+          'trace_seconds': 0.0, 'lower_seconds': 0.0}
 _listening = False
+# By thread, (end, seconds) of the traces counted so far, the newest last.
+_traced: Dict[int, list] = {}
 
 
 def _on_event(event: str, **_kwargs) -> None:
@@ -39,9 +50,22 @@ def _on_event(event: str, **_kwargs) -> None:
 
 
 def _on_duration(event: str, duration: float, **_kwargs) -> None:
-    if event == _COMPILE:
-        with _lock:
-            _stats['compile_seconds'] += duration
+    key = _DURATIONS.get(event)
+    if key is None:
+        return
+    with _lock:
+        if key == 'trace_seconds':
+            # A jitted function traced inside another's trace reports
+            # first, on the same thread (listeners run on the caller's),
+            # and the outer one's duration holds it: count an interval
+            # once. One entry a traced program is kept, as the jit
+            # caches keep the program itself.
+            now = time.perf_counter()
+            mine = _traced.setdefault(threading.get_ident(), [])
+            while mine and mine[-1][0] > now - duration:
+                _stats[key] -= mine.pop()[1]
+            mine.append((now, duration))
+        _stats[key] += duration
 
 
 def configure() -> str:
@@ -63,11 +87,13 @@ def configure() -> str:
 
 
 def snapshot() -> Dict[str, Any]:
-    """{'dir', 'hits', 'misses', 'compile_seconds'}: persistent-cache
-    reads that answered, entries written after a real compile, and the
-    seconds spent in backend compilation (cache reads included)."""
+    """{'dir', 'hits', 'misses', 'compile_seconds', 'trace_seconds',
+    'lower_seconds'}: persistent-cache reads that answered, entries
+    written after a real compile, and the seconds spent in backend
+    compilation (cache reads included), in tracing and in lowering."""
     with _lock:
         out: Dict[str, Any] = dict(_stats)
-    out['compile_seconds'] = round(out['compile_seconds'], 3)
+    for key in _DURATIONS.values():
+        out[key] = round(out[key], 3)
     out['dir'] = os.environ.get(ENV)
     return out

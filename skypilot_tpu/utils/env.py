@@ -538,8 +538,6 @@ _var('SKYT_WATCHDOG_CONFIRM', 'int', 2,
      'Consecutive confirming polls before a verdict escalates.')
 _var('SKYT_POSTMORTEM_DIR', 'str', '~/.skyt/postmortems',
      'Where crash bundles (py-stacks, env, verdicts) are written.')
-_var('SKYT_TRAIN_MFU', 'bool', True,
-     'Compute + log model FLOPs utilization in the sft step log.')
 
 
 # ---------------------------------------------------------- accessors

@@ -18,52 +18,27 @@ call `StepProfiler.on_step(i)` at the top of every step and `stop()`
 after the loop; both are no-ops unless SKYT_PROFILE_DIR is set, so the
 hook costs nothing in production runs.
 
-This module additionally owns (docs/observability.md "Fleet plane"):
+This module additionally owns :func:`capture_trace` — a bounded
+ON-DEMAND capture behind a process-wide single-flight lock, the backend
+of the infer server's ``POST /debug/profile`` (and, via the controller
+proxy, ``POST /fleet/profile``; docs/observability.md "Fleet plane").
+Works degraded on CPU: the host trace is still real data.
 
-  * :func:`capture_trace` — a bounded ON-DEMAND capture behind a
-    process-wide single-flight lock, the backend of the infer server's
-    ``POST /debug/profile`` (and, via the controller proxy,
-    ``POST /fleet/profile``). Works degraded on CPU: the host trace is
-    still real data;
-  * the MFU estimator — :func:`train_step_flops` reads FLOPs from the
-    step's own HLO ``cost_analysis()`` at the LOWERED stage (global,
-    pre-SPMD-partition, no backend compile) and falls back to the
-    caller's analytic 6ND-style count only when the backend cannot
-    answer, so the published ``skyt_train_mfu`` metric no longer
-    depends on hand-maintained formulas.
+What a step-window profile of sft holds (docs/observability.md "Device
+profiles"): the device's operations under the step's named scopes
+(forward and backward by the transform in their path, `optimizer`,
+`loss`), and on the host plane the step loop's `train.step` spans with
+their children and the prefetcher's `prefetch.*` spans, on one clock.
 """
 import os
 import threading
 import time
-from typing import Any, Callable, Dict, Optional, Tuple
+from typing import Any, Dict, Optional
 
 from skypilot_tpu.utils import log_utils
 from skypilot_tpu.utils import env
 
 logger = log_utils.init_logger(__name__)
-
-# bf16 peak FLOPs per chip (the MFU denominator): owned here so the
-# trainer's published MFU and the fleet cost report divide by the same
-# numbers.
-PEAK_FLOPS = {
-    'TPU v5 lite': 197e12,
-    'TPU v5': 459e12,
-    'TPU v4': 275e12,
-    'TPU v6 lite': 918e12,
-}
-
-
-def peak_flops(device) -> float:
-    """Peak bf16 FLOPs of one device. A device that is not in the
-    table is an error, not a default: a utilization against a made-up
-    peak is not a measurement."""
-    kind = device.device_kind
-    for prefix, flops in PEAK_FLOPS.items():
-        if kind.startswith(prefix):
-            return flops
-    raise ValueError(
-        f'no peak FLOP/s on record for device kind {kind!r} '
-        f'(known: {sorted(PEAK_FLOPS)})')
 
 
 class ProfilerBusy(RuntimeError):
@@ -116,69 +91,6 @@ def capture_trace(duration_ms: float,
                 'files': files[:50], 'n_files': len(files)}
     finally:
         _CAPTURE_LOCK.release()
-
-
-# ----------------------------------------------------- MFU estimation
-def cost_analysis_flops(stage) -> Optional[float]:
-    """FLOPs from a jax stage's ``cost_analysis()`` (a ``Lowered`` or
-    a compiled executable), or None when the backend does not report
-    them."""
-    try:
-        ca = stage.cost_analysis()
-        if not isinstance(ca, dict):
-            return None
-        flops = float(ca.get('flops', 0.0) or 0.0)
-        return flops if flops > 0 else None
-    except Exception as e:  # pylint: disable=broad-except
-        logger.debug('cost_analysis unavailable: %r', e)
-        return None
-
-
-# Back-compat alias (the original name; same function — any stage with
-# a cost_analysis() works).
-compiled_flops = cost_analysis_flops
-
-
-def train_step_flops(step_fn: Callable, *args,
-                     analytic: Optional[Any] = None,
-                     lowered: Optional[Any] = None
-                     ) -> 'Tuple[Optional[float], str]':
-    """FLOPs of one call of `step_fn(*args)` -> (flops, source).
-
-    Tries the HLO cost analysis first: `step_fn` must expose
-    ``.lower`` (jax.jit functions do; trainer.make_train_step attaches
-    one that re-enters its mesh/axis-rules context). Deliberately the
-    LOWERED stage's cost analysis, not the compiled executable's:
-    lowering costs no backend compile (no mid-run stall on large
-    models), and its count is GLOBAL and pre-optimization — the right
-    MFU numerator on both axes, since SPMD partitioning would report
-    per-device FLOPs against our global-peak denominator and remat
-    recompute must not inflate MFU. Falls back to `analytic` (a float
-    or zero-arg callable — the hand-maintained 6ND-style count) and
-    ultimately (None, 'unavailable').
-
-    ``lowered``: a precomputed ``step_fn.lower(*args)`` stage, so a
-    caller that also feeds the comms census (sft) lowers once for
-    both reads."""
-    if lowered is not None or getattr(step_fn, 'lower', None) \
-            is not None:
-        try:
-            if lowered is None:
-                lowered = step_fn.lower(*args)
-            flops = cost_analysis_flops(lowered)
-            if flops is not None:
-                return flops, 'hlo_cost_analysis'
-        except Exception as e:  # pylint: disable=broad-except
-            logger.warning('HLO cost analysis failed (%r); falling '
-                           'back to the analytic FLOPs count', e)
-    try:
-        if callable(analytic):
-            analytic = analytic()
-        if analytic:
-            return float(analytic), 'analytic'
-    except Exception as e:  # pylint: disable=broad-except
-        logger.warning('analytic FLOPs count failed: %r', e)
-    return None, 'unavailable'
 
 
 class StepProfiler:

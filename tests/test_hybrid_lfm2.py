@@ -447,5 +447,5 @@ def test_sft_trains_the_preset_and_reports_the_routing_counters():
     # 2 expert layers x 64 tokens x 4 slots, all held, none dropped
     assert text.count('moe_pairs=512/512') == 3
     # ... so each layer's loop works through its whole worst case
-    assert text.count('moe_dropped=0 moe_rows=512/512\n') == 3
+    assert text.count('moe_dropped=0 moe_rows=512/512 grad_norm=') == 3
     assert dispatch.snapshot()['moe_experts'] == 'ragged_dot'

@@ -218,17 +218,3 @@ def test_autoscaler_decision_counter():
     assert dec.value('steady') == 1
     assert dec.value('upscale') == 1
     assert reg.get('skyt_autoscaler_target_replicas').value() == 2
-
-
-def test_train_metrics_publisher():
-    import jax.numpy as jnp
-    from skypilot_tpu.train import trainer
-    reg = metrics_lib.MetricsRegistry()
-    pub = trainer.TrainMetricsPublisher(registry=reg)
-    pub.publish({'loss': jnp.float32(2.5), 'grad_norm': jnp.float32(0.5)},
-                step_time_s=0.1, tokens_per_sec=1000.0, steps=10)
-    assert reg.get('skyt_train_loss').value() == 2.5
-    assert reg.get('skyt_train_grad_norm').value() == 0.5
-    assert reg.get('skyt_train_step_seconds').value() == 0.1
-    assert reg.get('skyt_train_tokens_per_sec').value() == 1000.0
-    assert reg.get('skyt_train_steps_total').value() == 10
