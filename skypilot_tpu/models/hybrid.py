@@ -8,7 +8,10 @@ sliding window and a rotary table of its own; 'conv': the gated short
 convolution below) and its feed-forward ('dense': `LlamaMLP`;
 'experts': the dropless `moe.RoutedExperts`), and the stack is
 unrolled, each block under its own remat (which recomputes all of a
-block but its experts' selection). LFM2-MoE
+block but its experts' selection and, in a full-attention block, the
+flash forward's output and logsumexp; a window block's forward is the
+cheapest to run again, and its output kept too would not fit one chip
+at 16,384: `HybridModel` has the figures). LFM2-MoE
 (huggingface.co/LiquidAI/LFM2-24B-A2B, `model_type` `lfm2_moe`) is the
 first family written this way: 30 of its 40 layers have no attention
 at all. Mellum2
@@ -35,6 +38,8 @@ import jax.numpy as jnp
 
 from skypilot_tpu.models import llama as llama_lib
 from skypilot_tpu.models import moe as moe_lib
+from skypilot_tpu.ops import dispatch
+from skypilot_tpu.ops import flash_attention as flash_lib
 from skypilot_tpu.ops import rope
 
 OPERATORS = ('attention', 'window_attention', 'conv')
@@ -262,13 +267,36 @@ class HybridModel(nn.Module):
             yarn=cfg.yarn if op == 'attention' else None)
             for op in ('attention', 'window_attention') if op in operators}
         # Recompute everything but which experts each token chose
-        # (moe.route has why).
-        block = nn.remat(
-            HybridBlock, policy=jax.checkpoint_policies.save_only_these_names(
-                moe_lib.SELECTED)) if base.remat else HybridBlock
+        # (moe.route has why) and, in a full-attention block, the flash
+        # forward's output and logsumexp: its backward then runs no
+        # forward kernel again. A window block's forward is the cheapest
+        # to run again (5.1 ms a layer on a v5e at 16,384 against the
+        # full layer's 18.4) for the same bytes kept, and keeping them
+        # there too does not fit one chip (Mellum2's share at 16,384:
+        # "Used 18.20G of 15.75G hbm", the TPU compiler's report; the
+        # full blocks alone make each expert model's step smaller,
+        # PERF.md §6).
+        def rematted(*names):
+            return nn.remat(
+                HybridBlock,
+                policy=jax.checkpoint_policies.save_only_these_names(
+                    moe_lib.SELECTED, *names))
+        plain, keeping = ((rematted(), rematted(flash_lib.OUT, flash_lib.LSE))
+                          if base.remat else (HybridBlock, HybridBlock))
+        attention = [op for op, _ in cfg.layers if op != 'conv']
+        if attention:
+            kept = attention.count('attention') if base.remat else 0
+            dispatch.record_residuals_plan({
+                'kept': kept, 'blocks': len(attention),
+                'kinds': 'block_diffusion' if cfg.block_diffusion
+                else 'full',
+                # out [b, s, heads, head] and the logsumexp a row, f32
+                'bytes': kept * b * s * base.n_heads *
+                (base.head_dim * dtype.itemsize + 4)})
         routed = fullest = dropped = rows = worst = jnp.zeros(
             (), jnp.int32)
         for i, kind in enumerate(cfg.layers):
+            block = keeping if kind[0] == 'attention' else plain
             x, stats = block(cfg, kind, name=f'layer_{i}')(
                 x, *tables.get(kind[0], (None, None)), segment_ids)
             routed, fullest, dropped, rows, worst = (

@@ -10,8 +10,8 @@ Blockwise online-softmax attention (Flash-Attention-2 schedule):
 * backward: two kernels, both recomputing p = exp(s - L) blockwise from
   the saved residuals (q, k, v, out, L) — no O(S^2) materialization:
     - dq kernel: the forward's walk, accumulates dq += ds @ k in VMEM
-      scratch; delta = rowsum(dO*O) is made once per q tile, in the
-      kernel, as the column it is used as;
+      scratch; delta = rowsum(dO*O) and the column of L are made once
+      per q tile, in the kernel, as the columns they are used as;
     - dk/dv kernel: a k tile's q tiles in turn, computed in the
       transposed orientation (s^T = k q^T, [block_k, block_q]) so that
       no product contracts dimension 0 of an operand and L and delta
@@ -50,6 +50,12 @@ it and by the clean ones from it on. The list holds both runs, one
 after the other. The extents the tile rule gives divide L (or are the
 whole 2L), so a tile lies in one quadrant of the square.
 
+The residuals the backward reads of the forward, its output and L (one
+float32 a row, [b, hq, sq]), carry the names `OUT` and `LSE`: a block
+under remat whose policy saves them (models/hybrid.py, its
+full-attention blocks) keeps them from the first pass, and its backward
+runs no forward kernel again.
+
 Kernel conventions follow /opt/skills/guides/pallas_guide.md (block
 specs, scratch via pl.pallas_call scratch_shapes, MXU-aligned tiles).
 """
@@ -60,6 +66,7 @@ from typing import NamedTuple, Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -69,10 +76,16 @@ NEG_INF = -1e30
 
 # The forward writes the logsumexp as [b, hq, sq, LANES] with the value
 # replicated across the 128 lanes: it is made as a [block_q, 1] column,
-# the dq kernel reads it back as one, and Mosaic requires the last two
-# dims of every block to be (8k, 128)-tileable or equal to the array
-# dims. The dk/dv kernel wants rows and gets lane 0 as [b, hq, 1, sq].
+# and Mosaic requires the last two dims of every block to be
+# (8k, 128)-tileable or equal to the array dims. What the backward keeps
+# and reads is lane 0, a row [b, hq, 1, sq] to its kernels.
 LANES = 128
+
+# The names of the residuals a remat policy may save (the forward's
+# output and the compact logsumexp), as `moe.SELECTED` names the experts
+# chosen.
+OUT = 'flash_out'
+LSE = 'flash_lse'
 
 _NT = (((1,), (1,)), ((), ()))    # a @ b^T: contract the last dims
 _NN = (((1,), (0,)), ((), ()))    # a @ b
@@ -335,10 +348,10 @@ def _dq_kernel(*refs, scale: float, causal: bool, window: int,
                has_seg: bool, bd: tuple = ()):
     if has_seg:
         (visits_ref, q_ref, k_ref, v_ref, do_ref, o_ref, lse_ref,
-         q_seg_ref, k_seg_ref, dq_ref, dq_scr, delta_scr) = refs
+         q_seg_ref, k_seg_ref, dq_ref, dq_scr, delta_scr, lse_scr) = refs
     else:
         (visits_ref, q_ref, k_ref, v_ref, do_ref, o_ref, lse_ref,
-         dq_ref, dq_scr, delta_scr) = refs
+         dq_ref, dq_scr, delta_scr, lse_scr) = refs
     qi, ki, first, last, *kind = _decode(visits_ref[pl.program_id(2)])
 
     @pl.when(first)
@@ -348,6 +361,8 @@ def _dq_kernel(*refs, scale: float, causal: bool, window: int,
         delta_scr[:] = jnp.sum(
             do_ref[0, 0].astype(jnp.float32) *
             o_ref[0, 0].astype(jnp.float32), axis=1, keepdims=True)
+        # The tile's row of L, turned into the column it is used as.
+        lse_scr[:] = lse_ref[0, 0].T
 
     def _tile(masked):
         q = q_ref[0, 0]                   # [bq, d]
@@ -359,8 +374,7 @@ def _dq_kernel(*refs, scale: float, causal: bool, window: int,
                 s, qi * block_q, ki * block_k, causal, window,
                 q_seg_ref[0, 0] if has_seg else None,
                 k_seg_ref[0, 0] if has_seg else None, bd=bd)
-        lse = lse_ref[0, 0][:, :1]        # [bq, 1] (lane-replicated)
-        p = jnp.exp(s - lse)              # [bq, bk]
+        p = jnp.exp(s - lse_scr[:])       # [bq, bk] - [bq, 1]
         dp = jax.lax.dot_general(
             do_ref[0, 0], v_ref[0, 0], _NT,
             preferred_element_type=jnp.float32)  # [bq, bk]
@@ -636,11 +650,13 @@ def _fwd_rule(q, k, v, segment_ids, causal, block_q, block_k, window,
               bd=()):
     out, lse = _flash_fwd_impl(q, k, v, segment_ids, causal, block_q,
                                block_k, window, bd)
-    return out, (q, k, v, segment_ids, out, lse)
+    out = checkpoint_name(out, OUT)
+    return out, (q, k, v, segment_ids, out,
+                 checkpoint_name(lse[..., 0], LSE))
 
 
 def _bwd_rule(causal, block_q, block_k, window, bd, res, g):
-    q, k, v, segment_ids, out, lse = res
+    q, k, v, segment_ids, out, lse = res  # lse: [b, hq, sq]
     has_seg = segment_ids is not None
     plan = _plan(q, k, block_q, block_k, has_seg, causal, window,
                  ('dq', 'dkv'), bd)
@@ -658,6 +674,9 @@ def _bwd_rule(causal, block_q, block_k, window, bd, res, g):
     segs = [segment_ids.astype(jnp.int32)[:, None, :]] * 2 if has_seg \
         else []
 
+    # Row statistics cross HBM compact, as [b, hq, 1, sq] rows.
+    lse_row = lse[:, :, None, :]
+
     # dq: the forward's walk; q, dO, O and L stay for a q tile's k tiles.
     tiles = plan['dq']
     specs = _specs(tiles, d, group)
@@ -666,19 +685,18 @@ def _bwd_rule(causal, block_q, block_k, window, bd, res, g):
                           block_k=tiles.block_k, kinds=tiles.kinds,
                           **static),
         tiles, (b, hq),
-        [specs.q, specs.kv, specs.kv, specs.q, specs.q, specs.col] +
+        [specs.q, specs.kv, specs.kv, specs.q, specs.q, specs.row] +
         specs.segs[:len(segs)],
         specs.q, jax.ShapeDtypeStruct((b, hq, sq, d), q.dtype),
         [pltpu.VMEM((tiles.block_q, d), jnp.float32),    # dq
-         pltpu.VMEM((tiles.block_q, 1), jnp.float32)],   # delta
-        [qt, kt, vt, dot, ot, lse] + segs)
+         pltpu.VMEM((tiles.block_q, 1), jnp.float32),    # delta
+         pltpu.VMEM((tiles.block_q, 1), jnp.float32)],   # L
+        [qt, kt, vt, dot, ot, lse_row] + segs)
 
     # dk/dv per *query* head: the kernel walks a k tile's q tiles; the
-    # kv-head (GQA group) reduction is one XLA sum. Its row statistics
-    # cross HBM compact, as [b, hq, 1, sq] rows.
+    # kv-head (GQA group) reduction is one XLA sum.
     tiles = plan['dkv']
     specs = _specs(tiles, d, group)
-    lse_row = lse[..., 0][:, :, None, :]
     delta_row = (dot.astype(jnp.float32) *
                  ot.astype(jnp.float32)).sum(-1)[:, :, None, :]
     dkt, dvt = _call(

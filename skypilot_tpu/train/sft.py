@@ -93,6 +93,10 @@ def _log_kernel_plans(ops_dispatch) -> None:
     if bd_plan:
         logger.info('block diffusion plan: %s', ' '.join(
             f'{k}={v}' for k, v in bd_plan.items()))
+    kept = ops_dispatch.residuals_plan_snapshot()
+    if kept:
+        logger.info('flash residuals kept: {kept} of {blocks} attention '
+                    'blocks ({kinds}), {bytes} B'.format(**kept))
 
 
 def parse_mesh(spec: Optional[str], n_devices: int):

@@ -1,10 +1,33 @@
 """What the flash kernels' list of visited tiles (`flash_attention._walk`)
 must be, told from a dense mask by enumeration: shared by the tests of
 the causal, window and segment masks (test_ops_dispatch.py) and of the
-block-diffusion mask (test_hybrid_sdar.py)."""
+block-diffusion mask (test_hybrid_sdar.py). And how many times a program
+calls each flash kernel (`kernel_calls`): the tests of what a block
+under remat runs again (test_hybrid_lfm2.py, test_model_train.py)."""
+import collections
+
 import numpy as np
+from jax.extend import core as jax_core
 
 from skypilot_tpu.ops import flash_attention
+
+
+def kernel_calls(jaxpr, counts=None):
+    """Pallas calls of a jaxpr by kernel function (`_fwd_kernel`,
+    `_dq_kernel`, `_dkv_kernel`, ...), through every program inside it:
+    jit, remat's recomputation, custom_vjp, scan, shard_map."""
+    counts = collections.Counter() if counts is None else counts
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == 'pallas_call':
+            counts[eqn.params['jaxpr'].debug_info.func_name] += 1
+        for param in eqn.params.values():
+            for sub in (param if isinstance(param, (tuple, list))
+                        else (param,)):
+                if isinstance(sub, jax_core.ClosedJaxpr):
+                    kernel_calls(sub.jaxpr, counts)
+                elif isinstance(sub, jax_core.Jaxpr):
+                    kernel_calls(sub, counts)
+    return counts
 
 
 def check_walk(codes, dense, block_q, block_k, by_k=False,

@@ -385,6 +385,9 @@ def test_sft_trains_the_preset_and_prints_the_lines_the_driver_parses():
     text = buf.getvalue()
     assert 'moe routing plan: experts=16 held=0-15 k=4 tokens=128 ' \
         'buffer_rows=512 chunk_rows=512\n' in text
+    # the preset is not rematted: nothing is kept, nothing recomputed
+    assert 'flash residuals kept: 0 of 4 attention blocks (full), 0 B\n' \
+        in text
     paths = re.search(r'kernel dispatch paths: (\{.*?\}) '
                       r'\(pallas (\w+), flash backward (\w+)\)', text)
     assert paths.group(2, 3) == ('interpreted', 'pallas')

@@ -147,6 +147,31 @@ class TestModel:
                 np.asarray(a), np.asarray(b), rtol=1e-4, atol=1e-5),
             grads['full'][1], grads['dots'][1])
 
+    def test_the_full_policy_runs_each_flash_forward_twice(self):
+        """remat_policy 'full' saves no name, the flash residuals'
+        neither: each block's backward runs its flash forward again
+        (two forwards a block, one dq and one dk/dv), which keeps the
+        outputs of every layer out of memory (PERF.md §6)."""
+        import dataclasses
+
+        import flax
+        from flash_walk_helpers import kernel_calls
+
+        tokens = _batch(b=2, s=64)
+        cfg = dataclasses.replace(llama.CONFIGS['debug'], remat=True,
+                                  remat_policy='full', attn_impl='flash')
+        model = llama.LlamaModel(cfg)
+        params = flax.core.unfreeze(model.init(
+            jax.random.PRNGKey(3), tokens['tokens']))['params']
+
+        def loss_fn(p):
+            logits = model.apply({'params': p}, tokens['tokens'])
+            return trainer.cross_entropy_loss(logits, tokens['targets'])[0]
+        calls = kernel_calls(jax.make_jaxpr(jax.grad(loss_fn))(params).jaxpr)
+        # one scanned block: its forward, and again in the backward's body
+        assert calls == {'_fwd_kernel': 2, '_dq_kernel': 1,
+                         '_dkv_kernel': 1}
+
     def test_eval_step(self, debug_setup):
         cfg, model, mesh, tx, state = debug_setup
         ev = trainer.make_eval_step(model, mesh)

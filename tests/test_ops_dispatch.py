@@ -444,6 +444,55 @@ class TestTilePlan:
         ref = attention_ops.mha_reference(q, k, v, causal=True)
         assert jnp.max(jnp.abs(out - ref)) < 2e-5
 
+    # name, causal, window, segment ids, block-diffusion block
+    RESIDUAL_CASES = [('causal', True, 0, False, 0),
+                      ('window', True, 96, False, 0),
+                      ('block-diffusion', False, 0, False, 4),
+                      ('segments', True, 0, True, 0)]
+
+    @pytest.mark.parametrize('case', RESIDUAL_CASES,
+                             ids=[c[0] for c in RESIDUAL_CASES])
+    def test_the_backward_reads_the_compact_named_residuals(self, case):
+        """The forward rule hands the backward the output and the
+        logsumexp as one float32 a row ([b, hq, sq]: lane 0 of the
+        kernel's block), both named for a remat policy to keep; from
+        them dq (which reads the row and turns it into its column),
+        dk and dv are the reference's."""
+        _, causal, window, segmented, block = case
+        q, k, v = _qkv(2, 256, 256, 4, 2, 64, seed=7)
+        w = jax.random.normal(jax.random.PRNGKey(8), q.shape)
+        seg = _seg_ids(2, 256) if segmented else None
+        bd = (128, block) if block else ()
+
+        def forward(q_, k_, v_):
+            return flash_lib._fwd_rule(q_, k_, v_, seg, causal, None, None,
+                                       window, bd)
+
+        out, res = forward(q, k, v)
+        assert res[4] is out
+        assert res[5].shape == (2, 4, 256) and res[5].dtype == jnp.float32
+        named = str(jax.make_jaxpr(forward)(q, k, v))
+        assert f'name={flash_lib.OUT}' in named
+        assert f'name={flash_lib.LSE}' in named
+        if block:
+            scores = jnp.einsum('bqhd,bkhd->bhqk', q,
+                                jnp.repeat(k, 2, axis=2)) * 64 ** -0.5
+            pos = jnp.arange(256)
+            allowed = attention_ops.block_diffusion_allowed(
+                pos[:, None], pos[None, :], 128, block)
+            want = jax.nn.logsumexp(
+                jnp.where(allowed, scores, -jnp.inf), axis=-1)
+            assert jnp.max(jnp.abs(res[5] - want)) < 2e-5
+        dq, dk, dv, _ = flash_lib._bwd_rule(causal, None, None, window, bd,
+                                            res, w)
+        ref, ref_vjp = jax.vjp(lambda *a: attention_ops.mha_reference(
+            *a, causal=causal, segment_ids=seg, window=window,
+            block_diffusion=block), q, k, v)
+        assert jnp.max(jnp.abs(out - ref)) < 2e-5
+        for name, got, exp in zip(('dq', 'dk', 'dv'), (dq, dk, dv),
+                                  ref_vjp(w)):
+            assert jnp.max(jnp.abs(got - exp)) < 1e-4, name
+
     def test_plan_lands_on_span_and_snapshot(self, monkeypatch):
         from skypilot_tpu.utils import tracing
         monkeypatch.setenv('SKYT_TRACE', '1')
