@@ -56,7 +56,7 @@ def _bd_reference(q, k, v, block: int):
         lambda args: rows_of(*args, k, v),
         (q.reshape(b, sq // chunk, chunk, *q.shape[2:]).swapaxes(0, 1),
          jnp.arange(0, sq, chunk)))
-    return out.swapaxes(0, 1).reshape(q.shape)
+    return out.swapaxes(0, 1).reshape(*q.shape[:3], v.shape[-1])
 
 
 def mha_reference(q: jax.Array, k: jax.Array, v: jax.Array,
@@ -70,9 +70,10 @@ def mha_reference(q: jax.Array, k: jax.Array, v: jax.Array,
                   window_active=None,
                   logit_softcap: float = 0.0,
                   block_diffusion: int = 0) -> jax.Array:
-    """q: [B, Sq, Hq, D]; k,v: [B, Sk, Hkv, D]; Hq % Hkv == 0.
+    """q, k: [B, Sq|Sk, Hq|Hkv, D]; v: [B, Sk, Hkv, Dv]; Hq % Hkv == 0.
 
-    Returns [B, Sq, Hq, D]. Logits and softmax in f32.
+    Returns [B, Sq, Hq, Dv]. Logits and softmax in f32; the scale is
+    1/sqrt(D) unless given.
 
     q_positions: optional [B, Sq] global query positions for the causal
     mask (per-batch offsets — the KV-cache decode path); overrides
@@ -132,7 +133,7 @@ def mha_reference(q: jax.Array, k: jax.Array, v: jax.Array,
 
     probs = jax.nn.softmax(logits, axis=-1)
     out = jnp.einsum('bhgqk,bkhd->bqhgd', probs.astype(v.dtype), v)
-    return out.reshape(b, sq, hq, d)
+    return out.reshape(b, sq, hq, v.shape[-1])
 
 
 @functools.partial(jax.jit, static_argnames=('causal', 'impl', 'window',
@@ -174,7 +175,8 @@ def _attention(q: jax.Array, k: jax.Array, v: jax.Array,
                          softmax_scale is not None or
                          (window > 0 and window_active is not None))
     impl = _resolve_impl(q, k, impl, window, flash_unsupported,
-                         segment_ids is not None, block_diffusion > 0)
+                         segment_ids is not None, block_diffusion > 0,
+                         v.shape[-1])
 
     def xla():
         if block_diffusion > 0 and not flash_unsupported:
@@ -226,7 +228,8 @@ def _attention(q: jax.Array, k: jax.Array, v: jax.Array,
 
 
 def _resolve_impl(q, k, impl: str, window: int, flash_unsupported: bool,
-                  has_seg: bool, block_diffusion: bool = False) -> str:
+                  has_seg: bool, block_diffusion: bool = False,
+                  dv: Optional[int] = None) -> str:
     """The 'auto' gate: flash or XLA, from the shape and the features
     asked for. A static window is a flash call like any other; what
     flash cannot do (a soft cap, a scale of the caller's, a traced
@@ -234,11 +237,13 @@ def _resolve_impl(q, k, impl: str, window: int, flash_unsupported: bool,
     if impl != 'auto':
         return impl
     return ('flash' if not flash_unsupported and
-            _flash_ok(q, k, has_seg, window, block_diffusion) else 'xla')
+            _flash_ok(q, k, has_seg, window, block_diffusion, dv)
+            else 'xla')
 
 
 def _flash_ok(q: jax.Array, k: jax.Array, has_seg: bool = False,
-              window: int = 0, block_diffusion: bool = False) -> bool:
+              window: int = 0, block_diffusion: bool = False,
+              dv: Optional[int] = None) -> bool:
     """Auto-dispatch gate: shapes where the flash kernel is expected
     to WIN on TPU (tile-aligned seqs, MXU-friendly head dim, blocks
     that fit VMEM). Any shape outside this set still works — it takes
@@ -246,16 +251,17 @@ def _flash_ok(q: jax.Array, k: jax.Array, has_seg: bool = False,
     the shape-robust clamped blocks. has_seg matters: packed-sequence
     blocks must be 128-aligned or full-array, so a seq that clamps to
     a full-array block can blow the VMEM guard that a seg-less probe
-    would pass."""
+    would pass. dv: the values' width where it is not the queries'."""
     sq, sk, d = q.shape[1], k.shape[1], q.shape[3]
+    dv = dv or d
     if not (not dispatch.interpret_mode() and
             sq % 8 == 0 and sk % 8 == 0 and
-            d % 64 == 0 and d <= 512):
+            d % 64 == 0 and d <= 512 and dv % 64 == 0 and dv <= 512):
         return False
     return dispatch.flash_vmem_ok(
         dispatch.flash_blocks(sq, sk, d, q.dtype, has_seg, window,
-                              block_diffusion=block_diffusion), d,
-        jnp.dtype(q.dtype).itemsize, has_seg)
+                              block_diffusion=block_diffusion, dv=dv), d,
+        jnp.dtype(q.dtype).itemsize, has_seg, dv)
 
 
 def attention(q: jax.Array, k: jax.Array, v: jax.Array,

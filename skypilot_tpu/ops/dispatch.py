@@ -79,6 +79,9 @@ _moe_plan: Dict[str, Any] = {}
 # Which attention blocks of the model traced last keep their flash
 # residuals through remat (trace-time).
 _residuals_plan: Dict[str, Any] = {}
+# The chunked form of the Kimi Delta Attention layers of the model traced
+# last (trace-time).
+_kda_plan: Dict[str, Any] = {}
 # (form, a, b) of a grouped product -> the tiles it was traced with.
 _grouped_plan: Dict[Tuple[str, int, int], Tuple[int, int, int]] = {}
 
@@ -154,9 +157,12 @@ def clamp_flash_blocks(sq: int, sk: int, want_q: int, want_k: int,
 def flash_blocks(sq: int, sk: int, d: int, q_dtype, has_seg: bool,
                  window: int = 0,
                  want: Optional[Tuple[int, int]] = None,
-                 block_diffusion: bool = False
+                 block_diffusion: bool = False,
+                 dv: Optional[int] = None
                  ) -> Dict[str, Tuple[int, int]]:
-    """The tile rule: kernel ('fwd', 'dq', 'dkv') -> (block_q, block_k).
+    """The tile rule: kernel ('fwd', 'dq', 'dkv') -> (block_q, block_k),
+    for queries and keys of width d and values of width dv (d where not
+    given).
 
     With no request the extents come from the shape: the kernel's
     tuned maximum (`_FLASH_MAX_BLOCKS`), no wider than a sliding
@@ -184,7 +190,7 @@ def flash_blocks(sq: int, sk: int, d: int, q_dtype, has_seg: bool,
                 wq, wk = min(wq, cap), min(wk, cap)
             wq, wk = min(wq, sq), min(wk, sk)
             while (max(wq, wk) > LANES and flash_vmem_bytes(
-                    kernel, wq, wk, d, itemsize, has_seg)
+                    kernel, wq, wk, d, itemsize, has_seg, dv)
                     > VMEM_BUDGET_BYTES):
                 if wq >= wk:
                     wq //= 2
@@ -204,8 +210,10 @@ def flash_blocks(sq: int, sk: int, d: int, q_dtype, has_seg: bool,
 
 
 def flash_vmem_bytes(kernel: str, block_q: int, block_k: int, d: int,
-                     itemsize: int, has_seg: bool = False) -> int:
-    """VMEM one invocation of a flash kernel holds: every streamed
+                     itemsize: int, has_seg: bool = False,
+                     dv: Optional[int] = None) -> int:
+    """VMEM one invocation of a flash kernel holds (q and k of width d,
+    v, out, dO and dv of width dv, d where not given): every streamed
     block twice (the pipeline's double buffer), the float32 scratch
     (a [n, 1] column pads to 128 lanes, a [1, n] row to 8 sublanes),
     and the score-sized temporaries Mosaic keeps: two float32 and the
@@ -213,23 +221,28 @@ def flash_vmem_bytes(kernel: str, block_q: int, block_k: int, d: int,
     cast one in dq, two in dk/dv — read off the smallest
     `vmem_limit_bytes` each kernel still compiles under (v5e, jaxlib
     0.9.0: 10, 6 and 8 bytes an entry in bf16)."""
+    dv = dv or d
     q_blk = block_q * d * itemsize
     k_blk = block_k * d * itemsize
+    o_blk = block_q * dv * itemsize
+    v_blk = block_k * dv * itemsize
     col = block_q * LANES * 4           # [block_q, 1] or lane-replicated
     row = 8 * block_q * 4               # [1, block_q]
     seg = 2 * 8 * (block_q + block_k) * 4 if has_seg else 0
     tile = block_q * block_k
     if kernel == 'fwd':
-        io = 2 * (2 * q_blk + 2 * k_blk + col)        # q, o; k, v; lse
-        scratch = 2 * col + block_q * d * 4           # m, l; acc
+        io = 2 * (q_blk + o_blk + k_blk + v_blk + col)  # q, o; k, v; lse
+        scratch = 2 * col + block_q * dv * 4          # m, l; acc
         live = tile * (2 * 4 + itemsize)
     elif kernel == 'dq':
-        io = 2 * (4 * q_blk + 2 * k_blk + row)        # q, dO, o, dq; lse
+        io = 2 * (2 * q_blk + 2 * o_blk + k_blk + v_blk + row)
+        # q, dq; dO, o; k; v; lse
         scratch = 2 * col + block_q * d * 4           # delta, lse; dq
         live = tile * (4 + itemsize)
     elif kernel == 'dkv':
-        io = 2 * (2 * q_blk + 4 * k_blk + 2 * row)    # q, dO; k, v, dk, dv
-        scratch = 2 * block_k * d * 4
+        io = 2 * (q_blk + o_blk + 2 * k_blk + 2 * v_blk + 2 * row)
+        # q; dO; k, dk; v, dv
+        scratch = block_k * (d + dv) * 4
         live = tile * 2 * 4
     else:
         raise ValueError(f'unknown flash kernel {kernel!r}')
@@ -237,9 +250,9 @@ def flash_vmem_bytes(kernel: str, block_q: int, block_k: int, d: int,
 
 
 def flash_vmem_ok(plan: Dict[str, Tuple[int, int]], d: int, itemsize: int,
-                  has_seg: bool = False) -> bool:
+                  has_seg: bool = False, dv: Optional[int] = None) -> bool:
     """Whether each kernel of a tile plan fits the budget."""
-    return all(flash_vmem_bytes(kernel, bq, bk, d, itemsize, has_seg)
+    return all(flash_vmem_bytes(kernel, bq, bk, d, itemsize, has_seg, dv)
                <= VMEM_BUDGET_BYTES for kernel, (bq, bk) in plan.items())
 
 
@@ -401,6 +414,19 @@ def moe_chunk_rows(tokens: int, k: int, held: int, experts: int) -> int:
     return min(chunk, -(-tokens * min(k, held) // 8) * 8)
 
 
+KDA_SUB_CHUNK = 16
+
+
+def kda_chunk(seq: int) -> int:
+    """The chunk rule of Kimi Delta Attention's chunked form (ops/kda.py):
+    positions a chunk holds, from the length alone. 64, whose pairs a
+    chunk are products of a few tiles on the MXU and whose sequential
+    part is a step every 64 positions; a shorter row takes one chunk of
+    its length rounded up to whole sub-chunks of `KDA_SUB_CHUNK` (the
+    row is padded to whole chunks)."""
+    return min(64, -(-seq // KDA_SUB_CHUNK) * KDA_SUB_CHUNK)
+
+
 def _record_plan(store: Dict[str, Any], attribute: str,
                  plan: Dict[str, Any]) -> None:
     with _lock:
@@ -435,6 +461,20 @@ def record_residuals_plan(plan: Dict[str, Any]) -> None:
     kind, the bytes kept a step where they run on the flash rung) and
     stamp it on the current trace span as `ops.residuals_plan`."""
     _record_plan(_residuals_plan, 'ops.residuals_plan', plan)
+
+
+def record_kda_plan(plan: Dict[str, Any]) -> None:
+    """Remember the chunked form a model's Kimi Delta Attention layers
+    were traced with (layers, chunk, chunks a row, heads, key and value
+    widths, the bytes of one layer's chunk-start states) and stamp it on
+    the current trace span as `ops.kda_plan`."""
+    _record_plan(_kda_plan, 'ops.kda_plan', plan)
+
+
+def kda_plan_snapshot() -> Dict[str, Any]:
+    """The last traced model's chunked-form plan ({} if none)."""
+    with _lock:
+        return dict(_kda_plan)
 
 
 def residuals_plan_snapshot() -> Dict[str, Any]:
@@ -573,4 +613,5 @@ def reset_for_tests() -> None:
         _moe_plan.clear()
         _bd_plan.clear()
         _residuals_plan.clear()
+        _kda_plan.clear()
         _grouped_plan.clear()

@@ -461,7 +461,9 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array,
                     block_k: Optional[int] = None,
                     window: int = 0,
                     block_diffusion: int = 0) -> jax.Array:
-    """q: [B, Sq, Hq, D]; k, v: [B, Sk, Hkv, D] -> [B, Sq, Hq, D].
+    """q, k: [B, Sq|Sk, Hq|Hkv, D]; v: [B, Sk, Hkv, Dv] ->
+    [B, Sq, Hq, Dv]. The value width may differ from the query and key
+    width (latent attention: 192 and 128); the scale is 1/sqrt(D).
 
     With no block_q/block_k the tile rule (dispatch.flash_blocks)
     gives the forward, dq and dk/dv kernels each their extents from
@@ -515,9 +517,10 @@ class _Tiles(NamedTuple):
 
 
 def _plan(q, k, block_q, block_k, has_seg, causal, window, kernels,
-          bd=()):
+          bd=(), dv=None):
     """Shape checks, then kernel -> `_Tiles` for `kernels`
-    (docs/kernels.md): extents from the shape rule, or the requested
+    (docs/kernels.md; `dv` the values' width, q's where not given):
+    extents from the shape rule, or the requested
     blocks CLAMPED through the divisibility-safe selector — to a
     tile-aligned divisor of the seq dim, or to the full dim (always
     legal) — so any legal input shape lowers, decode shapes like
@@ -529,6 +532,7 @@ def _plan(q, k, block_q, block_k, has_seg, causal, window, kernels,
     (dispatch.record_flash_plan)."""
     sq, hq, d = q.shape[1:]
     sk, hkv = k.shape[1:3]
+    dv = dv or d
     if hq % hkv != 0:
         raise ValueError(
             f'q heads ({hq}) must be a multiple of kv heads ({hkv})')
@@ -536,12 +540,12 @@ def _plan(q, k, block_q, block_k, has_seg, causal, window, kernels,
     if block_q is not None or block_k is not None:
         want = (block_q or sq, block_k or sk)
     plan = dispatch.flash_blocks(sq, sk, d, q.dtype, has_seg, window, want,
-                                 block_diffusion=bool(bd))
+                                 block_diffusion=bool(bd), dv=dv)
     tiles = {}
     for kernel in kernels:
         bq, bk = plan[kernel]
         need = dispatch.flash_vmem_bytes(
-            kernel, bq, bk, d, jnp.dtype(q.dtype).itemsize, has_seg)
+            kernel, bq, bk, d, jnp.dtype(q.dtype).itemsize, has_seg, dv)
         if max(sq // bq, sk // bk) > MAX_TILES:
             raise ValueError(
                 f'flash {kernel} blocks {plan[kernel]} cut ({sq}, {sk}) '
@@ -551,7 +555,7 @@ def _plan(q, k, block_q, block_k, has_seg, causal, window, kernels,
         if not dispatch.interpret_mode():
             if need > dispatch.VMEM_BUDGET_BYTES:
                 raise ValueError(
-                    f'flash {kernel} blocks {plan[kernel]} x d={d} need '
+                    f'flash {kernel} blocks {plan[kernel]} x d={d}/{dv} need '
                     f'{need}B of VMEM, over the budget '
                     f'({dispatch.VMEM_BUDGET_BYTES}B) — refusing a '
                     'certain Mosaic compile failure')
@@ -564,16 +568,18 @@ def _plan(q, k, block_q, block_k, has_seg, causal, window, kernels,
         tiles[kernel] = _Tiles(
             bq, bk, dispatch.flash_vmem_limit(need), visits,
             (counts['masked'] < counts['visited'], counts['masked'] > 0))
+        widths = {'dqk': d, 'dv': dv} if dv != d else {}
         dispatch.record_flash_plan(
-            kernel, {'block_q': bq, 'block_k': bk, **counts})
+            kernel, {'block_q': bq, 'block_k': bk, **counts, **widths})
     return tiles
 
 
-def _specs(tiles, d, group):
-    """The block specs of a call's operands. Every index map reads the
-    step's tile from the prefetched list: one scalar load and a shift.
-    Consecutive steps of a row of tiles name the same block of the
-    row's operands and results, which stay resident."""
+def _specs(tiles, d, group, dv):
+    """The block specs of a call's operands, q and k of width d, v and
+    what is made from it (out, dO, dv) of width dv. Every index map
+    reads the step's tile from the prefetched list: one scalar load and
+    a shift. Consecutive steps of a row of tiles name the same block of
+    the row's operands and results, which stay resident."""
     bq, bk = tiles.block_q, tiles.block_k
 
     def spec(block, index):
@@ -582,10 +588,14 @@ def _specs(tiles, d, group):
 
     return types.SimpleNamespace(
         q=spec((1, 1, bq, d), lambda bi, hi, qi, ki: (bi, hi, qi, 0)),
-        kv=spec((1, 1, bk, d),
-                lambda bi, hi, qi, ki: (bi, hi // group, ki, 0)),
+        o=spec((1, 1, bq, dv), lambda bi, hi, qi, ki: (bi, hi, qi, 0)),
+        k=spec((1, 1, bk, d),
+               lambda bi, hi, qi, ki: (bi, hi // group, ki, 0)),
+        v=spec((1, 1, bk, dv),
+               lambda bi, hi, qi, ki: (bi, hi // group, ki, 0)),
         # dk and dv of a *query* head.
-        dkv=spec((1, 1, bk, d), lambda bi, hi, qi, ki: (bi, hi, ki, 0)),
+        dk=spec((1, 1, bk, d), lambda bi, hi, qi, ki: (bi, hi, ki, 0)),
+        dv=spec((1, 1, bk, dv), lambda bi, hi, qi, ki: (bi, hi, ki, 0)),
         col=spec((1, 1, bq, LANES), lambda bi, hi, qi, ki: (bi, hi, qi, 0)),
         row=spec((1, 1, 1, bq), lambda bi, hi, qi, ki: (bi, hi, 0, qi)),
         # [b, 1, s] so the seq extent rides the LANE axis of the block
@@ -618,15 +628,15 @@ def _flash_fwd_impl(q, k, v, segment_ids, causal, block_q, block_k,
                     window=0, bd=()):
     has_seg = segment_ids is not None
     tiles = _plan(q, k, block_q, block_k, has_seg, causal, window,
-                  ('fwd',), bd)['fwd']
+                  ('fwd',), bd, v.shape[-1])['fwd']
     b, sq, hq, d = q.shape
-    hkv = k.shape[2]
-    specs = _specs(tiles, d, hq // hkv)
+    hkv, dv = k.shape[2], v.shape[3]
+    specs = _specs(tiles, d, hq // hkv, dv)
 
     # Kernel layout: [B, H, S, D] (head-major so blocks are contiguous).
     operands = [q.transpose(0, 2, 1, 3), k.transpose(0, 2, 1, 3),
                 v.transpose(0, 2, 1, 3)]
-    in_specs = [specs.q, specs.kv, specs.kv]
+    in_specs = [specs.q, specs.k, specs.v]
     if has_seg:
         in_specs += specs.segs
         operands += [segment_ids.astype(jnp.int32)[:, None, :]] * 2
@@ -636,12 +646,12 @@ def _flash_fwd_impl(q, k, v, segment_ids, causal, block_q, block_k,
             _fwd_kernel, scale=d ** -0.5, causal=causal, window=window,
             block_q=tiles.block_q, block_k=tiles.block_k,
             kinds=tiles.kinds, has_seg=has_seg, bd=bd),
-        tiles, (b, hq), in_specs, [specs.q, specs.col],
-        [jax.ShapeDtypeStruct((b, hq, sq, d), q.dtype),
+        tiles, (b, hq), in_specs, [specs.o, specs.col],
+        [jax.ShapeDtypeStruct((b, hq, sq, dv), q.dtype),
          jax.ShapeDtypeStruct((b, hq, sq, LANES), jnp.float32)],
         [pltpu.VMEM((tiles.block_q, 1), jnp.float32),   # running max
          pltpu.VMEM((tiles.block_q, 1), jnp.float32),   # running sum
-         pltpu.VMEM((tiles.block_q, d), jnp.float32)],  # output accumulator
+         pltpu.VMEM((tiles.block_q, dv), jnp.float32)],  # output accumulator
         operands)
     return out.transpose(0, 2, 1, 3), lse
 
@@ -659,9 +669,10 @@ def _bwd_rule(causal, block_q, block_k, window, bd, res, g):
     q, k, v, segment_ids, out, lse = res  # lse: [b, hq, sq]
     has_seg = segment_ids is not None
     plan = _plan(q, k, block_q, block_k, has_seg, causal, window,
-                 ('dq', 'dkv'), bd)
+                 ('dq', 'dkv'), bd, v.shape[-1])
     b, sq, hq, d = q.shape
     sk, hkv = k.shape[1:3]
+    dv = v.shape[3]
     group = hq // hkv
     static = dict(scale=d ** -0.5, causal=causal, window=window,
                   has_seg=has_seg, bd=bd)
@@ -669,7 +680,7 @@ def _bwd_rule(causal, block_q, block_k, window, bd, res, g):
     qt = q.transpose(0, 2, 1, 3)
     kt = k.transpose(0, 2, 1, 3)
     vt = v.transpose(0, 2, 1, 3)
-    dot = g.transpose(0, 2, 1, 3)         # dO, [b, hq, sq, d]
+    dot = g.transpose(0, 2, 1, 3)         # dO, [b, hq, sq, dv]
     ot = out.transpose(0, 2, 1, 3)
     segs = [segment_ids.astype(jnp.int32)[:, None, :]] * 2 if has_seg \
         else []
@@ -679,13 +690,13 @@ def _bwd_rule(causal, block_q, block_k, window, bd, res, g):
 
     # dq: the forward's walk; q, dO, O and L stay for a q tile's k tiles.
     tiles = plan['dq']
-    specs = _specs(tiles, d, group)
+    specs = _specs(tiles, d, group, dv)
     dqt = _call(
         functools.partial(_dq_kernel, block_q=tiles.block_q,
                           block_k=tiles.block_k, kinds=tiles.kinds,
                           **static),
         tiles, (b, hq),
-        [specs.q, specs.kv, specs.kv, specs.q, specs.q, specs.row] +
+        [specs.q, specs.k, specs.v, specs.o, specs.o, specs.row] +
         specs.segs[:len(segs)],
         specs.q, jax.ShapeDtypeStruct((b, hq, sq, d), q.dtype),
         [pltpu.VMEM((tiles.block_q, d), jnp.float32),    # dq
@@ -696,7 +707,7 @@ def _bwd_rule(causal, block_q, block_k, window, bd, res, g):
     # dk/dv per *query* head: the kernel walks a k tile's q tiles; the
     # kv-head (GQA group) reduction is one XLA sum.
     tiles = plan['dkv']
-    specs = _specs(tiles, d, group)
+    specs = _specs(tiles, d, group, dv)
     delta_row = (dot.astype(jnp.float32) *
                  ot.astype(jnp.float32)).sum(-1)[:, :, None, :]
     dkt, dvt = _call(
@@ -704,18 +715,18 @@ def _bwd_rule(causal, block_q, block_k, window, bd, res, g):
                           block_k=tiles.block_k, kinds=tiles.kinds,
                           **static),
         tiles, (b, hq),
-        [specs.q, specs.kv, specs.kv, specs.q, specs.row, specs.row] +
+        [specs.q, specs.k, specs.v, specs.o, specs.row, specs.row] +
         specs.segs[:len(segs)],
-        [specs.dkv, specs.dkv],
+        [specs.dk, specs.dv],
         [jax.ShapeDtypeStruct((b, hq, sk, d), k.dtype),
-         jax.ShapeDtypeStruct((b, hq, sk, d), v.dtype)],
+         jax.ShapeDtypeStruct((b, hq, sk, dv), v.dtype)],
         [pltpu.VMEM((tiles.block_k, d), jnp.float32),
-         pltpu.VMEM((tiles.block_k, d), jnp.float32)],
+         pltpu.VMEM((tiles.block_k, dv), jnp.float32)],
         [qt, kt, vt, dot, lse_row, delta_row] + segs)
 
     if group > 1:
         dkt = dkt.reshape(b, hkv, group, sk, d).sum(2)
-        dvt = dvt.reshape(b, hkv, group, sk, d).sum(2)
+        dvt = dvt.reshape(b, hkv, group, sk, dv).sum(2)
 
     dq = dqt.transpose(0, 2, 1, 3)
     dk = dkt.transpose(0, 2, 1, 3)

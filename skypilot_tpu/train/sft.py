@@ -76,11 +76,13 @@ def _log_kernel_plans(ops_dispatch) -> None:
     if plans:
         # Per kernel: tile extents and, per head, tiles visited / masked
         # / skipped and the grid's steps (`bd_` plans add the tiles the
-        # allowed pairs would fill).
+        # allowed pairs would fill; a value width of its own adds both
+        # widths).
         logger.info('flash tile plan: %s', ', '.join(
             '{} {block_q}x{block_k} {visited}/{masked}/'
             '{skipped} steps {steps}'.format(k, **p) +
-            (f' needed {p["needed"]}' if 'needed' in p else '')
+            (f' needed {p["needed"]}' if 'needed' in p else '') +
+            (f' dqk={p["dqk"]} dv={p["dv"]}' if 'dv' in p else '')
             for k, p in plans.items()))
     moe_plan = ops_dispatch.moe_plan_snapshot()
     if moe_plan:
@@ -93,6 +95,10 @@ def _log_kernel_plans(ops_dispatch) -> None:
     if bd_plan:
         logger.info('block diffusion plan: %s', ' '.join(
             f'{k}={v}' for k, v in bd_plan.items()))
+    kda_plan = ops_dispatch.kda_plan_snapshot()
+    if kda_plan:
+        logger.info('kda plan: %s', ' '.join(
+            f'{k}={v}' for k, v in kda_plan.items()))
     kept = ops_dispatch.residuals_plan_snapshot()
     if kept:
         logger.info('flash residuals kept: {kept} of {blocks} attention '

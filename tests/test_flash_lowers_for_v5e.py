@@ -35,8 +35,8 @@ def one_chip():
     compilation_cache.reset_cache()
 
 
-# name, (batch, S, q heads, kv heads, head size), the mask, segment ids,
-# the steps a head of (forward, dq, dk/dv)
+# name, (batch, S, q heads, kv heads, head size or (q and k, v) widths),
+# the mask, segment ids, the steps a head of (forward, dq, dk/dv)
 CASES = [
     ('sft-swa-moe-16k-window', (1, 16384, 32, 4, 128),
      dict(causal=True, window=1024), False, (62, 31, 93)),
@@ -50,6 +50,9 @@ CASES = [
      (6, 3, 10)),
     ('causal-65536', (1, 65536, 4, 1, 128), dict(causal=True), False,
      (4160, 2080, 8256)),
+    # latent attention: q and k of 192, v of 128
+    ('sft-kda-mla-16k', (1, 16384, 32, 32, (192, 128)), dict(causal=True),
+     False, (272, 136, 528)),
 ]
 
 
@@ -57,10 +60,13 @@ CASES = [
 def test_forward_and_backward_compile_on_the_list_of_visited_tiles(
         case, one_chip, monkeypatch):
     _, (b, s, hq, hkv, d), mask, segmented, steps = case
+    d, dv = d if isinstance(d, tuple) else (d, d)
     monkeypatch.setattr(dispatch, 'interpret_mode', lambda: False)
     dispatch.reset_for_tests()
     q = jax.ShapeDtypeStruct((b, s, hq, d), jnp.bfloat16, sharding=one_chip)
     k = jax.ShapeDtypeStruct((b, s, hkv, d), jnp.bfloat16,
+                             sharding=one_chip)
+    v = jax.ShapeDtypeStruct((b, s, hkv, dv), jnp.bfloat16,
                              sharding=one_chip)
     seg = jax.ShapeDtypeStruct((b, s), jnp.int32, sharding=one_chip) \
         if segmented else None
@@ -70,7 +76,7 @@ def test_forward_and_backward_compile_on_the_list_of_visited_tiles(
             q_, k_, v_, segment_ids=seg_, **mask).astype(jnp.float32).sum()
 
     text = jax.jit(jax.grad(loss, (0, 1, 2))).lower(
-        q, k, k, seg).compile().as_text()
+        q, k, v, seg).compile().as_text()
     assert text.count('tpu_custom_call') >= 3
     plans = dispatch.flash_plan_snapshot()
     assert tuple(p['steps'] for p in plans.values()) == steps

@@ -310,6 +310,9 @@ def test_window_and_full_layers_record_their_own_rung_and_plan():
         hybrid.CONFIGS['debug-mellum2'], seq=64)
     model, _ = registry.build('debug-mellum2', 'flash')
     dispatch.reset_for_tests()
+    # rungs and plans are recorded at trace time: a `_attention` of these
+    # shapes traced earlier in this process would record nothing here
+    jax.clear_caches()
     jax.grad(lambda p: trainer.cross_entropy_loss(
         model.apply({'params': p}, tokens), targets)[0])(params)
     paths = dispatch.snapshot()
