@@ -545,7 +545,9 @@ class HybridModel(nn.Module):
                 'chunks': -(-s // chunk),
                 'heads': kc.heads, 'dk': kc.head_dim, 'dv': kc.head_dim,
                 'state_bytes': kda_ops.state_bytes(b, s, kc.heads,
-                                                   kc.head_dim, kc.head_dim)})
+                                                   kc.head_dim, kc.head_dim),
+                **kda_ops.plan(b, s, kc.heads // kda_ops.head_groups(
+                    kc.heads), kc.head_dim, kc.head_dim)})
         routed = fullest = dropped = rows = worst = jnp.zeros(
             (), jnp.int32)
         for i, kind in enumerate(cfg.layers):

@@ -81,3 +81,28 @@ def test_forward_and_backward_compile_on_the_list_of_visited_tiles(
     plans = dispatch.flash_plan_snapshot()
     assert tuple(p['steps'] for p in plans.values()) == steps
     assert all(p['steps'] == p['visited'] for p in plans.values())
+
+
+def test_the_kda_kernels_compile_at_the_cells_group_call(one_chip,
+                                                         monkeypatch):
+    """KDA's chunked form (ops/kda_kernel.py) at `sft-kda-mla-16k`'s
+    call: 1 x 16,384 positions, a group of 8 heads of 128, chunks of
+    64; forward and backward through the custom VJP, as the model takes
+    its gradient, each kernel one `tpu_custom_call`."""
+    from skypilot_tpu.ops import kda
+    from skypilot_tpu.ops import kda_kernel
+    monkeypatch.setattr(dispatch, 'interpret_mode', lambda: False)
+    dispatch.reset_for_tests()
+    b, s, h, d = 1, 16384, 8, 128
+    x = jax.ShapeDtypeStruct((b, s, h, d), jnp.float32, sharding=one_chip)
+    beta = jax.ShapeDtypeStruct((b, s, h), jnp.float32, sharding=one_chip)
+
+    def loss(*args):
+        return kda.chunk_kda(*args).sum()
+
+    text = jax.jit(jax.grad(loss, range(5))).lower(
+        x, x, x, x, beta).compile().as_text()
+    assert text.count('tpu_custom_call') == 2
+    assert dispatch.snapshot()['kda'] == 'pallas'
+    assert kda.plan(b, s, h, d, d) == {
+        'rung': 'pallas', 'steps': kda_kernel.grid_steps(b, s, h)}

@@ -217,7 +217,8 @@ def test_a_kda_layer_takes_no_segment_ids_and_latent_flash_keeps_its_widths():
                    jnp.zeros((1, 200), jnp.int32))
     assert dispatch.kda_plan_snapshot() == {
         'layers': 4, 'chunk': 64, 'chunks': 4, 'heads': 4, 'dk': 16,
-        'dv': 16, 'state_bytes': 4 * 4 * 16 * 16 * 4}
+        'dv': 16, 'state_bytes': 4 * 4 * 16 * 16 * 4, 'rung': 'jnp',
+        'steps': 0}
     assert dispatch.residuals_plan_snapshot()['blocks'] == 1
 
 
@@ -244,7 +245,7 @@ def test_sft_trains_the_preset_and_prints_the_lines_the_driver_parses(
         sft.logger.removeHandler(handler)
     text = buf.getvalue()
     assert 'kda plan: layers=4 chunk=64 chunks=1 heads=4 dk=16 dv=16 ' \
-        'state_bytes=8192\n' in text
+        'state_bytes=8192 rung=jnp steps=0\n' in text
     assert 'moe routing plan: experts=16 held=0-15 k=4 tokens=128 ' in text
     steps = STEP_RE.findall(text)
     assert [int(n) for n, _ in steps] == [1, 2, 3]
