@@ -38,7 +38,7 @@ def block_diffusion_allowed(q_idx, k_idx, half: int, block: int):
 _BD_XLA_SCORE_BYTES = 1 << 30
 
 
-def _bd_reference(q, k, v, block: int):
+def _bd_reference(q, k, v, block: int, softmax_scale=None):
     """The XLA rung under the block-diffusion mask: `mha_reference` a
     block of queries at a time where the float32 scores of the whole
     row would not fit (32 heads x 16,384^2 are 34 GB)."""
@@ -46,12 +46,14 @@ def _bd_reference(q, k, v, block: int):
     rows = max(1, _BD_XLA_SCORE_BYTES // (4 * b * hq * k.shape[1]))
     chunk = next(c for c in range(min(sq, rows), 0, -1) if sq % c == 0)
     if chunk == sq:
-        return mha_reference(q, k, v, causal=False, block_diffusion=block)
+        return mha_reference(q, k, v, causal=False, block_diffusion=block,
+                             softmax_scale=softmax_scale)
 
     @jax.checkpoint     # the backward makes a block's scores again
     def rows_of(q1, start, k, v):
         return mha_reference(q1, k, v, causal=False, q_offset=start,
-                             block_diffusion=block)
+                             block_diffusion=block,
+                             softmax_scale=softmax_scale)
     out = jax.lax.map(
         lambda args: rows_of(*args, k, v),
         (q.reshape(b, sq // chunk, chunk, *q.shape[2:]).swapaxes(0, 1),
@@ -163,16 +165,17 @@ def _attention(q: jax.Array, k: jax.Array, v: jax.Array,
     (`block_diffusion` > 0: the block length, over a row `[x_t | x_0]`;
     not causal, and no segment ids or window beside it), whose kernels
     walk the two runs of tiles a row of tiles has under it.
-    Soft-capped/rescaled attention (Gemma-2) always takes the XLA path
-    — the flash kernel does not implement them, and a silent
-    wrong-math fast path is worse than a slower correct one. So does
-    Gemma-2's per-layer traced window gate (window_active): the skip
-    predicate must be static-per-kernel."""
+    A scale of the caller's (`softmax_scale`: DeepSeek-V2's YaRN) is
+    the kernels' static `scale`, a call like any other. Soft-capped
+    attention (Gemma-2) always takes the XLA path — the flash kernel
+    does not implement it, and a silent wrong-math fast path is worse
+    than a slower correct one. So does Gemma-2's per-layer traced
+    window gate (window_active): the skip predicate must be
+    static-per-kernel."""
     if block_diffusion > 0 and (segment_ids is not None or window > 0):
         raise ValueError('the block-diffusion mask takes no segment ids '
                          'and no window beside it')
     flash_unsupported = (logit_softcap > 0.0 or
-                         softmax_scale is not None or
                          (window > 0 and window_active is not None))
     impl = _resolve_impl(q, k, impl, window, flash_unsupported,
                          segment_ids is not None, block_diffusion > 0,
@@ -180,7 +183,7 @@ def _attention(q: jax.Array, k: jax.Array, v: jax.Array,
 
     def xla():
         if block_diffusion > 0 and not flash_unsupported:
-            return _bd_reference(q, k, v, block_diffusion)
+            return _bd_reference(q, k, v, block_diffusion, softmax_scale)
         return mha_reference(q, k, v, causal=causal,
                              block_diffusion=block_diffusion,
                              segment_ids=segment_ids, window=window,
@@ -191,8 +194,7 @@ def _attention(q: jax.Array, k: jax.Array, v: jax.Array,
     if impl == 'flash':
         if flash_unsupported:
             offender = ('logit_softcap' if logit_softcap > 0.0 else
-                        'softmax_scale' if softmax_scale is not None
-                        else 'a traced window gate (window_active)')
+                        'a traced window gate (window_active)')
             raise ValueError(
                 f'flash attention does not support {offender}')
         from skypilot_tpu.ops import flash_attention as flash_lib
@@ -210,7 +212,7 @@ def _attention(q: jax.Array, k: jax.Array, v: jax.Array,
             # No blocks asked for: the shape rule plans the tiles.
             return flash_lib.flash_attention(
                 q, k, v, causal=causal, segment_ids=seg, window=window,
-                block_diffusion=block_diffusion)
+                block_diffusion=block_diffusion, scale=softmax_scale)
 
         def pallas():
             return sharding_lib.per_shard(kernel, in_axes, q_axes)(*operands)
@@ -220,7 +222,7 @@ def _attention(q: jax.Array, k: jax.Array, v: jax.Array,
             'flash_window_attention' if window > 0 else 'flash_attention',
             [('pallas', pallas), ('xla', xla)])
     # 'xla_native': XLA is the CORRECT path for this op (softcap /
-    # scale / traced window / auto-resolved shape), not ladder
+    # traced window / auto-resolved shape), not ladder
     # degradation — keep it distinguishable from the 'xla' floor so
     # operators (and chip_smoke.py's /stats check) don't learn to
     # ignore the real degradation signal.
@@ -232,8 +234,7 @@ def _resolve_impl(q, k, impl: str, window: int, flash_unsupported: bool,
                   dv: Optional[int] = None) -> str:
     """The 'auto' gate: flash or XLA, from the shape and the features
     asked for. A static window is a flash call like any other; what
-    flash cannot do (a soft cap, a scale of the caller's, a traced
-    window gate) is XLA's."""
+    flash cannot do (a soft cap, a traced window gate) is XLA's."""
     if impl != 'auto':
         return impl
     return ('flash' if not flash_unsupported and

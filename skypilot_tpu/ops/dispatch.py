@@ -82,6 +82,8 @@ _residuals_plan: Dict[str, Any] = {}
 # The chunked form of the Kimi Delta Attention layers of the model traced
 # last (trace-time).
 _kda_plan: Dict[str, Any] = {}
+# The latent attention layers of the model traced last (trace-time).
+_mla_plan: Dict[str, Any] = {}
 # (form, a, b) of a grouped product -> the tiles it was traced with.
 _grouped_plan: Dict[Tuple[str, int, int], Tuple[int, int, int]] = {}
 
@@ -471,6 +473,20 @@ def record_kda_plan(plan: Dict[str, Any]) -> None:
     _record_plan(_kda_plan, 'ops.kda_plan', plan)
 
 
+def record_mla_plan(plan: Dict[str, Any]) -> None:
+    """Remember the latent attention layers a model was traced with
+    (layers, heads, the query and key widths unrotated + rotated, the
+    value width, the latent's, the rotation and the softmax scale) and
+    stamp it on the current trace span as `ops.mla_plan`."""
+    _record_plan(_mla_plan, 'ops.mla_plan', plan)
+
+
+def mla_plan_snapshot() -> Dict[str, Any]:
+    """The last traced model's latent attention plan ({} if none)."""
+    with _lock:
+        return dict(_mla_plan)
+
+
 def kda_plan_snapshot() -> Dict[str, Any]:
     """The last traced model's chunked-form plan ({} if none)."""
     with _lock:
@@ -614,4 +630,5 @@ def reset_for_tests() -> None:
         _bd_plan.clear()
         _residuals_plan.clear()
         _kda_plan.clear()
+        _mla_plan.clear()
         _grouped_plan.clear()

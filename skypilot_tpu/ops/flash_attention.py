@@ -450,7 +450,8 @@ def flash_attention_fwd_lse(q: jax.Array, k: jax.Array, v: jax.Array,
     — wrap it in your own custom_vjp (parallel/ring_attention.py routes
     its backward through the einsum path).
     """
-    out, lse = _flash_fwd_impl(q, k, v, None, causal, None, None, 0)
+    out, lse = _flash_fwd_impl(q, k, v, None, causal, None, None, 0, (),
+                               q.shape[-1] ** -0.5)
     return out, lse[..., 0]
 
 
@@ -460,10 +461,13 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array,
                     block_q: Optional[int] = None,
                     block_k: Optional[int] = None,
                     window: int = 0,
-                    block_diffusion: int = 0) -> jax.Array:
+                    block_diffusion: int = 0,
+                    scale: Optional[float] = None) -> jax.Array:
     """q, k: [B, Sq|Sk, Hq|Hkv, D]; v: [B, Sk, Hkv, Dv] ->
     [B, Sq, Hq, Dv]. The value width may differ from the query and key
-    width (latent attention: 192 and 128); the scale is 1/sqrt(D).
+    width (latent attention: 192 and 128). The scores are q k^T times
+    `scale`, 1/sqrt(D) where none is given (DeepSeek-V2's YaRN gives its
+    own); the kernels take it as a static argument.
 
     With no block_q/block_k the tile rule (dispatch.flash_blocks)
     gives the forward, dq and dk/dv kernels each their extents from
@@ -496,14 +500,16 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array,
                 f'multiple of the block {block_diffusion}, no segment '
                 f'ids and no window; got Sq {q.shape[1]}, Sk {k.shape[1]}')
         bd, causal = (half, block_diffusion), False
+    scale = q.shape[-1] ** -0.5 if scale is None else float(scale)
     return _flash(q, k, v, segment_ids, causal, block_q, block_k,
-                  window, bd)
+                  window, bd, scale)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7, 8))
-def _flash(q, k, v, segment_ids, causal, block_q, block_k, window, bd=()):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7, 8, 9))
+def _flash(q, k, v, segment_ids, causal, block_q, block_k, window, bd,
+           scale):
     out, _ = _flash_fwd_impl(q, k, v, segment_ids, causal, block_q,
-                             block_k, window, bd)
+                             block_k, window, bd, scale)
     return out
 
 
@@ -625,7 +631,7 @@ def _call(body, tiles, heads, in_specs, out_specs, out_shape, scratch,
 
 
 def _flash_fwd_impl(q, k, v, segment_ids, causal, block_q, block_k,
-                    window=0, bd=()):
+                    window, bd, scale):
     has_seg = segment_ids is not None
     tiles = _plan(q, k, block_q, block_k, has_seg, causal, window,
                   ('fwd',), bd, v.shape[-1])['fwd']
@@ -643,7 +649,7 @@ def _flash_fwd_impl(q, k, v, segment_ids, causal, block_q, block_k,
 
     out, lse = _call(
         functools.partial(
-            _fwd_kernel, scale=d ** -0.5, causal=causal, window=window,
+            _fwd_kernel, scale=scale, causal=causal, window=window,
             block_q=tiles.block_q, block_k=tiles.block_k,
             kinds=tiles.kinds, has_seg=has_seg, bd=bd),
         tiles, (b, hq), in_specs, [specs.o, specs.col],
@@ -657,15 +663,15 @@ def _flash_fwd_impl(q, k, v, segment_ids, causal, block_q, block_k,
 
 
 def _fwd_rule(q, k, v, segment_ids, causal, block_q, block_k, window,
-              bd=()):
+              bd, scale):
     out, lse = _flash_fwd_impl(q, k, v, segment_ids, causal, block_q,
-                               block_k, window, bd)
+                               block_k, window, bd, scale)
     out = checkpoint_name(out, OUT)
     return out, (q, k, v, segment_ids, out,
                  checkpoint_name(lse[..., 0], LSE))
 
 
-def _bwd_rule(causal, block_q, block_k, window, bd, res, g):
+def _bwd_rule(causal, block_q, block_k, window, bd, scale, res, g):
     q, k, v, segment_ids, out, lse = res  # lse: [b, hq, sq]
     has_seg = segment_ids is not None
     plan = _plan(q, k, block_q, block_k, has_seg, causal, window,
@@ -674,7 +680,7 @@ def _bwd_rule(causal, block_q, block_k, window, bd, res, g):
     sk, hkv = k.shape[1:3]
     dv = v.shape[3]
     group = hq // hkv
-    static = dict(scale=d ** -0.5, causal=causal, window=window,
+    static = dict(scale=scale, causal=causal, window=window,
                   has_seg=has_seg, bd=bd)
 
     qt = q.transpose(0, 2, 1, 3)

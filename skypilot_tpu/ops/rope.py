@@ -1,7 +1,8 @@
 """Rotary position embeddings (RoPE), including the Llama-3.1 frequency
-scaling and YaRN. Pure function of (positions, head_dim); computed in f32
-and applied via the split-half rotation (the HF/Llama convention, not
-interleaved).
+scaling and YaRN (HF's, and DeepSeek-V2's with its softmax scale). Pure
+function of (positions, head_dim); computed in f32 and applied via the
+split-half rotation (the HF/Llama convention), or to adjacent pairs
+(`apply_rope_pairs`, DeepSeek-V2's layout).
 """
 import dataclasses
 import functools
@@ -44,6 +45,39 @@ class Yarn:
         return (max(math.floor(pair(self.beta_fast)), 0),
                 min(math.ceil(pair(self.beta_slow)), head_dim - 1))
 
+    @property
+    def softmax_factor(self) -> float:
+        """What the attention's softmax scale is multiplied by: nothing,
+        where the factor stands on cos and sin."""
+        return 1.0
+
+
+def yarn_mscale(factor: float, mscale: float) -> float:
+    """DeepSeek's `yarn_get_mscale`: 0.1 mscale ln(factor) + 1 (1 where
+    the table is not stretched)."""
+    return 1.0 if factor <= 1 else 0.1 * mscale * math.log(factor) + 1.0
+
+
+@dataclasses.dataclass(frozen=True)
+class DeepSeekYarn(Yarn):
+    """YaRN as DeepSeek-V2's modeling code computes it (config.json's
+    `rope_scaling` of `type` yarn with `mscale` and `mscale_all_dim`):
+    the frequencies are `Yarn`'s ramp, cos and sin carry
+    yarn_mscale(factor, mscale) / yarn_mscale(factor, mscale_all_dim),
+    and the softmax scale yarn_mscale(factor, mscale_all_dim)^2, which
+    the attention applies (Yarn's `attention_factor` is not read)."""
+    mscale: float = 1.0
+    mscale_all_dim: float = 0.0
+
+    @property
+    def scale(self) -> float:
+        return yarn_mscale(self.factor, self.mscale) / \
+            yarn_mscale(self.factor, self.mscale_all_dim)
+
+    @property
+    def softmax_factor(self) -> float:
+        return yarn_mscale(self.factor, self.mscale_all_dim) ** 2
+
 
 def inv_freqs(head_dim: int, theta: float,
               yarn: Optional[Yarn] = None) -> jax.Array:
@@ -67,7 +101,7 @@ def rope_freqs(positions: jax.Array, head_dim: int,
                use_llama31_scaling: bool = False,
                yarn: Optional[Yarn] = None):
     """Return (cos, sin) of shape positions.shape + (head_dim//2,).
-    Under `yarn` both carry its attention factor."""
+    Under `yarn` both carry its `scale`."""
     freqs = inv_freqs(head_dim, theta, yarn)
     if use_llama31_scaling:
         # Llama-3.1 long-context NTK-by-parts scaling (factor 8, original
@@ -100,6 +134,18 @@ def apply_rope(x: jax.Array, cos: jax.Array, sin: jax.Array) -> jax.Array:
     s = sin[..., None, :]
     out = jnp.concatenate([x1 * c - x2 * s, x2 * c + x1 * s], axis=-1)
     return out.astype(dtype)
+
+
+def apply_rope_pairs(x: jax.Array, cos: jax.Array,
+                     sin: jax.Array) -> jax.Array:
+    """The rotation of adjacent pairs (2j, 2j + 1), pair j by angle j
+    (DeepSeek-V2's published layout): x de-interleaved into its even and
+    odd members, then `apply_rope`. The result holds the pairs' first
+    members in one half and their second in the other, as DeepSeek-V2's
+    modeling code leaves them; where q and k both take it, their dot
+    products are those of the pairs rotated in place."""
+    return apply_rope(jnp.concatenate([x[..., 0::2], x[..., 1::2]], -1),
+                      cos, sin)
 
 
 def positions_from_segment_ids(

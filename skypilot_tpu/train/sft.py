@@ -99,6 +99,10 @@ def _log_kernel_plans(ops_dispatch) -> None:
     if kda_plan:
         logger.info('kda plan: %s', ' '.join(
             f'{k}={v}' for k, v in kda_plan.items()))
+    mla_plan = ops_dispatch.mla_plan_snapshot()
+    if mla_plan:
+        logger.info('latent attention plan: %s', ' '.join(
+            f'{k}={v}' for k, v in mla_plan.items()))
     kept = ops_dispatch.residuals_plan_snapshot()
     if kept:
         logger.info('flash residuals kept: {kept} of {blocks} attention '

@@ -51,6 +51,10 @@ BUFFER_LEAVES = ('expert_bias',)
 # would fill.
 MOE_STAT_KEYS = ('moe_pairs_held', 'moe_pairs', 'moe_fullest_over_mean',
                  'moe_pairs_dropped', 'moe_rows', 'moe_rows_worst')
+# Where the expert layers have a balance loss (DeepSeek-V2's), its sum
+# over the layers, which the step's loss includes.
+MOE_AUX_KEY = 'moe_aux'
+
 
 
 def format_moe_stats(host: Dict[str, float]) -> str:
@@ -62,7 +66,9 @@ def format_moe_stats(host: Dict[str, float]) -> str:
             'moe_dropped={:.0f} moe_rows={:.0f}/{:.0f}'.format(
                 host['moe_pairs_held'], host['moe_pairs'],
                 host['moe_fullest_over_mean'], host['moe_pairs_dropped'],
-                host['moe_rows'], host['moe_rows_worst']))
+                host['moe_rows'], host['moe_rows_worst']) +
+            (' moe_aux={:.6f}'.format(host[MOE_AUX_KEY])
+             if MOE_AUX_KEY in host else ''))
 
 
 class DeferredMetrics:
@@ -87,7 +93,8 @@ class DeferredMetrics:
 
     def __init__(self,
                  keys: Tuple[str, ...] = ('loss', 'grad_norm')
-                 + MOE_STAT_KEYS + block_diffusion.BD_STAT_KEYS,
+                 + MOE_STAT_KEYS + (MOE_AUX_KEY,)
+                 + block_diffusion.BD_STAT_KEYS,
                  tracer: Optional['tracing.Tracer'] = None) -> None:
         self._keys = keys
         self._prev: Optional[Dict[str, Any]] = None

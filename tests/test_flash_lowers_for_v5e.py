@@ -53,6 +53,9 @@ CASES = [
     # latent attention: q and k of 192, v of 128
     ('sft-kda-mla-16k', (1, 16384, 32, 32, (192, 128)), dict(causal=True),
      False, (272, 136, 528)),
+    # DeepSeek-V2's latent attention at its YaRN softmax scale
+    ('sft-mla-moe-16k', (1, 16384, 16, 16, (192, 128)),
+     dict(causal=True, scale=0.1147214), False, (272, 136, 528)),
 ]
 
 

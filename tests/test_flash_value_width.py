@@ -12,11 +12,13 @@ from skypilot_tpu.ops import dispatch
 from skypilot_tpu.ops import flash_attention
 
 
-def _plain(q, k, v, causal, window):
-    """softmax(q k^T / sqrt(dqk)) v per head, the mask by positions."""
+def _plain(q, k, v, causal, window, scale=None):
+    """softmax(q k^T * scale) v per head, scale 1/sqrt(dqk) where none
+    is given, the mask by positions."""
     group = q.shape[2] // k.shape[2]
     k, v = jnp.repeat(k, group, axis=2), jnp.repeat(v, group, axis=2)
-    s = jnp.einsum('bqhd,bkhd->bhqk', q, k) / jnp.sqrt(q.shape[-1])
+    s = jnp.einsum('bqhd,bkhd->bhqk', q, k) * (
+        q.shape[-1] ** -0.5 if scale is None else scale)
     pos = jnp.arange(q.shape[1])
     allowed = jnp.ones((q.shape[1],) * 2, bool)
     if causal:
@@ -66,6 +68,70 @@ def test_forward_dq_dk_dv_match_the_xla_rung_and_a_plain_softmax(case):
         for name, a, c in zip(('dq', 'dk', 'dv'), grads[0], other):
             assert a.shape == c.shape
             np.testing.assert_allclose(a, c, atol=5e-5, err_msg=name)
+
+
+def test_a_callers_scale_at_the_latent_widths_forward_and_backward():
+    """DeepSeek-V2's latent attention: q and k of 192, v of 128, the
+    scale 1/sqrt(192) times YaRN's mscale^2 = 0.1147214; the kernels
+    against the XLA rung at that scale and a plain softmax, and the
+    dispatcher taking the flash rung for it."""
+    b, s, h, d, dv, scale = 1, 128, 2, 192, 128, 0.1147214
+    ks = jax.random.split(jax.random.PRNGKey(7), 4)
+    q = jax.random.normal(ks[0], (b, s, h, d))
+    k = jax.random.normal(ks[1], (b, s, h, d))
+    v = jax.random.normal(ks[2], (b, s, h, dv))
+    w = jax.random.normal(ks[3], (b, s, h, dv))
+
+    def flash(q, k, v):
+        return flash_attention.flash_attention(q, k, v, block_q=64,
+                                               block_k=64, scale=scale)
+
+    def xla(q, k, v):
+        return attention_ops.mha_reference(q, k, v, softmax_scale=scale)
+
+    def plain(q, k, v):
+        return _plain(q, k, v, True, 0, scale)
+
+    def dispatched(q, k, v):
+        return attention_ops.attention(q, k, v, impl='flash',
+                                       softmax_scale=scale)
+    fns = (flash, xla, plain, dispatched)
+    dispatch.reset_for_tests()
+    outs = [jax.jit(fn)(q, k, v) for fn in fns]
+    grads = [jax.jit(jax.grad(lambda q, k, v, fn=fn: jnp.sum(
+        fn(q, k, v) * w), (0, 1, 2)))(q, k, v) for fn in fns]
+    for other in outs[1:]:
+        np.testing.assert_allclose(outs[0], other, atol=2e-5)
+    for other in grads[1:]:
+        for name, a, c in zip(('dq', 'dk', 'dv'), grads[0], other):
+            np.testing.assert_allclose(a, c, atol=5e-5, err_msg=name)
+    # the scale matters: the default one is another function
+    assert float(jnp.abs(jax.jit(flash_attention.flash_attention)(
+        q, k, v) - outs[0]).max()) > 1e-2
+    assert dispatch.snapshot() == {'flash_attention': 'pallas'}
+
+
+@pytest.mark.parametrize('given', [None, 0.1147214],
+                         ids=['default', 'the_callers'])
+def test_every_kernel_takes_the_scale_as_a_static_argument(given,
+                                                           monkeypatch):
+    """Without a scale the three kernels are given 1/sqrt(D), the float
+    they were always given, so a call lowers to the kernels it lowered
+    to; with one, that float."""
+    seen = []
+    real = flash_attention._call
+
+    def spy(body, *args):
+        seen.append((body.func.__name__, body.keywords['scale']))
+        return real(body, *args)
+    monkeypatch.setattr(flash_attention, '_call', spy)
+    q = jax.random.normal(jax.random.PRNGKey(0), (1, 64, 2, 96))
+    jax.jit(jax.grad(lambda q: flash_attention.flash_attention(
+        q, q, q, scale=given).sum()))(q)
+    want = 96 ** -0.5 if given is None else given
+    assert {name for name, _ in seen} == {'_fwd_kernel', '_dq_kernel',
+                                          '_dkv_kernel'}
+    assert all(type(scale) is float and scale == want for _, scale in seen)
 
 
 def test_the_tile_rule_and_vmem_count_read_both_widths():

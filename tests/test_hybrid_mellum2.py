@@ -171,7 +171,7 @@ def test_the_four_shares_of_a_layer_add_up_to_the_whole_layer():
             cfg.experts, held=(lo, lo + 4)))
         ps = dict(p, **{k: p[k][lo:lo + 4]
                         for k in ('w_gate', 'w_up', 'w_down')})
-        out, stats = jax.jit(share.apply)({'params': ps}, x)
+        out, stats, _ = jax.jit(share.apply)({'params': ps}, x)
         np.testing.assert_allclose(out, plain(ps, (lo, lo + 4)), atol=1e-5)
         assert int(stats[2]) == 0                      # nothing dropped
         total, routed = total + out, routed + int(stats[0])

@@ -194,7 +194,7 @@ def test_the_eight_shares_of_a_layer_add_up_to_the_whole_layer():
             cfg.experts, held=(lo, lo + 2)))
         ps = dict(p, **{k: p[k][lo:lo + 2]
                         for k in ('w_gate', 'w_up', 'w_down')})
-        out, stats = jax.jit(share.apply)({'params': ps}, x)
+        out, stats, _ = jax.jit(share.apply)({'params': ps}, x)
         np.testing.assert_allclose(out, plain(ps, (lo, lo + 2)), atol=1e-5)
         assert int(stats[2]) == 0                      # nothing dropped
         total, routed = total + out, routed + int(stats[0])

@@ -466,7 +466,7 @@ class TestTilePlan:
 
         def forward(q_, k_, v_):
             return flash_lib._fwd_rule(q_, k_, v_, seg, causal, None, None,
-                                       window, bd)
+                                       window, bd, 64 ** -0.5)
 
         out, res = forward(q, k, v)
         assert res[4] is out
@@ -484,7 +484,7 @@ class TestTilePlan:
                 jnp.where(allowed, scores, -jnp.inf), axis=-1)
             assert jnp.max(jnp.abs(res[5] - want)) < 2e-5
         dq, dk, dv, _ = flash_lib._bwd_rule(causal, None, None, window, bd,
-                                            res, w)
+                                            64 ** -0.5, res, w)
         ref, ref_vjp = jax.vjp(lambda *a: attention_ops.mha_reference(
             *a, causal=causal, segment_ids=seg, window=window,
             block_diffusion=block), q, k, v)

@@ -463,7 +463,7 @@ class TestExpertLayerLowers:
                  'use_expert_bias': True, 'experts_held': [4, 8]}
 
         def program(p, x):
-            (out, stats), sown = layer.apply({'params': p}, x,
+            (out, stats, _), sown = layer.apply({'params': p}, x,
                                              mutable=['intermediates'])
             return jnp.sum(out.astype(jnp.float32) * probe), (
                 out, stats, sown['intermediates']['selected'][0])
